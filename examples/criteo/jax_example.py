@@ -85,8 +85,9 @@ def train(dataset_url, epochs=1, batch_size=2048, lr=1e-3, scan_steps=0):
 
 
 if __name__ == '__main__':
-    from petastorm_tpu.utils import ensure_jax_backend
-    ensure_jax_backend()  # runs on any host; TPU when reachable
+    from petastorm_tpu.utils import enable_compile_cache, ensure_jax_backend
+    ensure_jax_backend()  # applies JAX_PLATFORMS; raises if the backend cannot start
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--dataset-url', default='file:///tmp/criteo_parquet')
     parser.add_argument('--epochs', type=int, default=2)
@@ -95,7 +96,7 @@ if __name__ == '__main__':
                         help='consume via scan_batches: K steps per stacked '
                              'device_put + lax.scan dispatch — use when '
                              'dispatch latency, not compute, is the stall '
-                             '(tiny DLRM steps on fast/tunneled devices)')
+                             '(tiny DLRM steps on fast devices)')
     args = parser.parse_args()
     train(args.dataset_url, args.epochs, args.batch_size,
           scan_steps=args.scan_steps)

@@ -55,6 +55,7 @@ def main():
 
 
 if __name__ == '__main__':
-    from petastorm_tpu.utils import ensure_jax_backend
-    ensure_jax_backend()  # honor JAX_PLATFORMS; fall back to cpu off-TPU
+    from petastorm_tpu.utils import enable_compile_cache, ensure_jax_backend
+    ensure_jax_backend()  # applies JAX_PLATFORMS; raises if the backend cannot start
+    enable_compile_cache()
     main()

@@ -26,8 +26,9 @@ def jax_hello_world(dataset_url='file:///tmp/hello_world_dataset'):
 
 
 if __name__ == '__main__':
-    from petastorm_tpu.utils import ensure_jax_backend
-    ensure_jax_backend()  # runs on any host; TPU when reachable
+    from petastorm_tpu.utils import enable_compile_cache, ensure_jax_backend
+    ensure_jax_backend()  # applies JAX_PLATFORMS; raises if the backend cannot start
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--dataset-url', default='file:///tmp/hello_world_dataset')
     args = parser.parse_args()

@@ -229,8 +229,9 @@ def train(dataset_url, steps=50, batch_size=64, image_hw=(224, 224), lr=0.1,
 
 
 if __name__ == '__main__':
-    from petastorm_tpu.utils import ensure_jax_backend
-    ensure_jax_backend()  # runs on any host; TPU when reachable
+    from petastorm_tpu.utils import enable_compile_cache, ensure_jax_backend
+    ensure_jax_backend()  # applies JAX_PLATFORMS; raises if the backend cannot start
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--dataset-url', default='file:///tmp/imagenet_petastorm')
     parser.add_argument('--steps', type=int, default=50)

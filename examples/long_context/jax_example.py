@@ -114,6 +114,7 @@ def main():
 
 
 if __name__ == '__main__':
-    from petastorm_tpu.utils import ensure_jax_backend
-    ensure_jax_backend()  # runs on any host; TPU when reachable
+    from petastorm_tpu.utils import enable_compile_cache, ensure_jax_backend
+    ensure_jax_backend()  # applies JAX_PLATFORMS; raises if the backend cannot start
+    enable_compile_cache()
     main()

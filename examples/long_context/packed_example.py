@@ -146,8 +146,9 @@ def sample(params, prompt_len=8, max_new=16, seed=0):
 
 
 if __name__ == '__main__':
-    from petastorm_tpu.utils import ensure_jax_backend
-    ensure_jax_backend()
+    from petastorm_tpu.utils import enable_compile_cache, ensure_jax_backend
+    ensure_jax_backend()  # applies JAX_PLATFORMS; raises if the backend cannot start
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--dataset-url', default='file:///tmp/lc_var_tokens')
     parser.add_argument('--steps', type=int, default=20)

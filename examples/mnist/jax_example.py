@@ -113,8 +113,9 @@ def train(dataset_url, epochs=3, batch_size=128, lr=1e-3,
 
 
 if __name__ == '__main__':
-    from petastorm_tpu.utils import ensure_jax_backend
-    ensure_jax_backend()  # runs on any host; TPU when reachable
+    from petastorm_tpu.utils import enable_compile_cache, ensure_jax_backend
+    ensure_jax_backend()  # applies JAX_PLATFORMS; raises if the backend cannot start
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--dataset-url', default='file:///tmp/mnist_petastorm')
     parser.add_argument('--epochs', type=int, default=3)

@@ -120,7 +120,7 @@ def diagnose(loader, monitor=None):
         'dispatch overhead k-fold; scan_epochs removes it entirely for '
         'HBM-cached epochs',
         'transfer the smallest dtype (uint8 images; cast/normalize on '
-        'device), and check the host-device link (PCIe gen, tunnel)']}
+        'device), and check the host-device link (PCIe generation)']}
 
 
 def format_report(result):

@@ -88,10 +88,7 @@ def donation_supported():
     the exact same code path — but the in-place recycling story only
     holds on accelerators.
     """
-    try:
-        return jax.default_backend() != 'cpu'
-    except Exception:
-        return False
+    return jax.default_backend() != 'cpu'
 
 
 # ---------------------------------------------------------------------------

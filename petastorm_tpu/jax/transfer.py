@@ -1,10 +1,8 @@
 """Pipelined host→device transfer plane (ISSUE 6).
 
-BENCH_TPU_LAST showed the link, not the data plane, as the frontier:
-``stall_pct_streaming`` ≈ 96% while ``hbm_scan`` sits at 5.4% — once
-batches are in HBM the framework is nearly stall-free, so everything
-between host memory and HBM must be hidden, not paid inline.  This
-module makes the transfer a first-class pipeline stage:
+Everything between host memory and HBM should be hidden behind the step,
+not paid inline.  This module makes the transfer a first-class pipeline
+stage:
 
 * **Ring-buffered staging** — a fixed ring of reused host staging slabs
   (reuse matters: first-touch page faults cost ~20x the memcpy on the
@@ -12,9 +10,7 @@ module makes the transfer a first-class pipeline stage:
   it last carried is committed on device (``jax.block_until_ready`` on
   slot reuse), so with ``ring_slots`` slots up to ``ring_slots - 1``
   transfers are in flight while the step runs — batch N+1's DMA
-  overlaps batch N's compute.  The device-side slab is donated into the
-  unpack executable (off the CPU backend, where donation is a no-op),
-  so steady-state transfer recycles buffers instead of allocating.
+  overlaps batch N's compute.
 * **Transfer coalescing** — the many small per-column arrays of a batch
   are packed into ONE C-contiguous staging slab per step: one
   ``device_put`` instead of one per column, then a jitted on-device
@@ -121,10 +117,10 @@ def plane_enabled(transfer):
     """Resolve a loader's ``transfer=`` kwarg against the environment.
 
     ``False``/``None`` → off; ``True`` → on (tests force the plane on the
-    CPU backend this way); ``'auto'`` → on only when an accelerator
-    backend is live — on the CPU fallback the "link" is a memcpy and the
-    extra staging pass buys nothing (measured: bench.py
-    ``transfer_plane`` leg).  The kill switch wins over everything.
+    CPU backend this way); ``'auto'`` → on unless the backend is the CPU,
+    where the "link" is a memcpy and the extra staging pass buys nothing
+    (bench.py ``transfer_plane`` leg).  The kill switch wins over
+    everything.  A backend that cannot initialize raises here.
     """
     validate_transfer(transfer)
     if os.environ.get(KILL_SWITCH):
@@ -133,10 +129,7 @@ def plane_enabled(transfer):
         return True
     if not transfer:
         return False
-    try:
-        return jax.default_backend() != 'cpu'
-    except Exception:  # noqa: BLE001 — no backend at all: nothing to feed
-        return False
+    return jax.default_backend() != 'cpu'
 
 
 def _supported(dtype):
@@ -273,7 +266,15 @@ class _Layout(object):
         def unpack(slab):
             leaves = []
             for f in fields:
-                seg = slab[f.offset:f.offset + f.nbytes]
+                # The barrier keeps a field's ops on that field's bytes.
+                # Without it XLA hoists the byte-regrouping reshape above
+                # the slice and reshapes the WHOLE slab once per multi-byte
+                # field: the TPU compiler then takes ~4 s per image of an
+                # ImageNet batch to compile image + one int32 column (22
+                # min at batch 256) and the program regroups 38 MB to read
+                # 1 KB.
+                seg = jax.lax.optimization_barrier(
+                    slab[f.offset:f.offset + f.nbytes])
                 if f.wire == np.uint8:
                     arr = seg
                 elif f.wire.kind == 'b':
@@ -283,13 +284,33 @@ class _Layout(object):
                 else:
                     arr = jax.lax.bitcast_convert_type(
                         seg.reshape(-1, f.wire.itemsize), jnp.dtype(f.wire))
-                arr = arr.reshape(f.shape)
+                arr = _reshape_rows_minor(arr, f.shape)
                 if f.wire != f.out:
                     arr = arr.astype(jnp.dtype(f.out))
                 leaves.append(arr)
             return jax.tree_util.tree_unflatten(treedef, leaves)
 
         return unpack
+
+
+def _reshape_rows_minor(flat, shape):
+    """``flat.reshape(shape)``, written so that no row-major copy of the leaf
+    has to exist on the way.
+
+    On a TPU an array is tiled over its two minor dims, and a row-major
+    ``uint8[N, 224, 224, 3]`` pads its 3 channels to 128 lanes — 42x its
+    bytes: 1.6 GB of scratch for one batch of 256, and a whole epoch
+    (``put_once``) refused outright.  The layouts the TPU compiler prefers
+    for such a leaf keep another axis minor; routing the reshape through
+    ``[rest..., N]`` lets it get there by layout assignment alone, where the
+    direct reshape forces the padded row-major intermediate first.  The
+    values are those of the direct reshape on every backend.
+    """
+    if len(shape) < 2:
+        return flat.reshape(shape)
+    rows = shape[0]
+    by_row = flat.reshape(rows, -1).T.reshape(tuple(shape[1:]) + (rows,))
+    return jnp.moveaxis(by_row, -1, 0)
 
 
 def _slab_bytes(prepared):
@@ -356,12 +377,6 @@ class TransferPlane(object):
         self._h_stage = metrics.histogram('h2d_stage')
         self._h_dispatch = metrics.histogram('h2d_dispatch')
         self._h_commit = metrics.histogram('h2d_commit')
-        # Donation recycles the device-side slab buffer into the unpack
-        # outputs; on the CPU backend it is a no-op that only warns.
-        try:
-            self._donate = jax.default_backend() != 'cpu'
-        except Exception:  # noqa: BLE001 — resolved again at first put
-            self._donate = False
         #: Per-batch provenance (ISSUE 13): outcome + stage windows of
         #: the most recent put — ``{'outcome': 'coalesced'|'narrowed'|
         #: 'degraded', 'stages': {'h2d_stage'/'h2d_dispatch'/
@@ -508,9 +523,11 @@ class TransferPlane(object):
             if total > self._max_staging:
                 raise _Unsupported('staging slab %d B exceeds the %d B cap'
                                    % (total, self._max_staging))
+            # No donation: a 1-D uint8 slab can alias none of the shaped
+            # leaves ("Some donated buffers were not usable"), and it is
+            # released after the dispatch either way.
             unpack = jax.jit((layout if plan is None
-                              else plan.shard_layout).build_unpack(),
-                             donate_argnums=(0,) if self._donate else ())
+                              else plan.shard_layout).build_unpack())
             prepared = (layout, unpack, plan)
         except _Unsupported as e:
             logger.debug('transfer plane degrades for this batch '

@@ -47,7 +47,10 @@ def disabled():
         _force_disabled = prev
 
 
-def _build():
+def build():
+    """Compile ``libpt_decode.so`` from ``pt_decode.cc`` now, replacing any
+    binary already there; raises when the compiler fails.  (:func:`get_lib`
+    builds only a missing or outdated binary and degrades on failure.)"""
     # Compile to a unique temp path and rename into place: os.rename is
     # atomic, so concurrent processes (ZeroMQ pool workers on a fresh
     # checkout) never dlopen a partially written ELF.
@@ -118,7 +121,7 @@ def get_lib():
         try:
             if (not os.path.exists(_SO)
                     or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                _build()
+                build()
             _lib = _load()
         except Exception as e:  # noqa: BLE001 — any failure means "no native"
             logger.warning('Native decode library unavailable (%s); '

@@ -14,8 +14,8 @@ sequence attention after the all-to-all) — the composition that makes long
 context cheap: Ulysses moves the data, this kernel keeps HBM traffic at
 O(seq · head_dim).
 
-K and V are CHUNKED: each kernel call holds one ``kv_chunk`` (default 8k
-rows) of K/V in VMEM, and chunks are folded at the XLA level with the same
+K and V are CHUNKED: each kernel call holds one ``kv_chunk`` (default sized
+from VMEM bytes, ``kv_chunk_default``) of K/V in VMEM, and chunks are folded at the XLA level with the same
 normalized-(output, lse) merge the ring fold uses — so a single device
 streams arbitrary ``seq_len`` (the old ~8k VMEM cliff is gone; beyond one
 device's FLOPs, shard with ring/Ulysses).  The backward pass streams the
@@ -481,10 +481,22 @@ def _flash_bwd(scale, causal, seq_len, block_q, block_k, packed, heads,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-#: Above this padded length the forward/backward default to streaming K/V
-#: in chunks of this many rows (fp32 d=128: ~8 MB K+V per call — well under
-#: VMEM).  Explicit ``kv_chunk`` overrides.
-KV_CHUNK_DEFAULT = 8192
+#: VMEM one kernel call may spend on its resident K/V chunk (the Q/dO chunk
+#: in the dK/dV kernel), counted the way the Pallas pipeline allocates it:
+#: two operands, each double-buffered.  Half of the 16 MiB scoped-VMEM limit
+#: Mosaic gives a kernel on v5e; the other half is left to the streamed
+#: blocks, the lse/delta/segment rows and the fp32 temporaries.
+KV_CHUNK_VMEM_BYTES = 8 << 20
+
+
+def kv_chunk_default(head_dim, dtype):
+    """Rows of K/V resident per kernel call when ``kv_chunk`` is not given:
+    the most that keeps double-buffered K and V inside
+    ``KV_CHUNK_VMEM_BYTES`` (bf16 d=128: 8192 rows; f32 d=128: 4096).
+    Sequences padded beyond this stream K/V in chunks of this many rows.
+    A row occupies whole 128-lane tiles in VMEM whatever ``head_dim`` is."""
+    lanes = -(-head_dim // 128) * 128
+    return KV_CHUNK_VMEM_BYTES // (2 * 2 * lanes * jnp.dtype(dtype).itemsize)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=128, block_k=128,
@@ -502,11 +514,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128, block_k=128,
     ``packing.packed_attention``, which is the dense oracle).
 
     ``kv_chunk`` streams K/V through VMEM in chunks of that many rows
-    (auto-enabled above ``KV_CHUNK_DEFAULT`` padded rows; ``0`` forces the
-    old whole-K/V residency), so a single
-    device handles arbitrary sequence lengths instead of capping where
-    whole-K/V VMEM residency ran out (~8k rows fp32).  The backward pass
-    streams the same way (dQ over K/V chunks, dK/dV over Q chunks).
+    (auto-enabled above ``kv_chunk_default(head_dim, dtype)`` padded rows;
+    ``0`` forces whole-K/V residency), so a single device handles arbitrary
+    sequence lengths instead of capping where whole-K/V VMEM residency runs
+    out.  The backward pass streams the same way (dQ over K/V chunks, dK/dV
+    over Q chunks).
 
     Compiles to Mosaic on TPU; on CPU/GPU backends it runs the same kernels
     through the Pallas interpreter (tests, dry runs).
@@ -555,11 +567,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128, block_k=128,
     else:
         seg3 = None
 
-    if kv_chunk is None and seq_pad > KV_CHUNK_DEFAULT:
-        kv_chunk = KV_CHUNK_DEFAULT
+    if kv_chunk is None:
+        kv_chunk = kv_chunk_default(d, q.dtype)
     if kv_chunk == 0:
         kv_chunk = None      # explicit 0: whole-K/V residency, no streaming
-    elif kv_chunk is not None:
+    else:
         # chunk boundaries must land on both block grids
         kv_chunk = max(lcm, (int(kv_chunk) // lcm) * lcm)
         if kv_chunk >= seq_pad:

@@ -37,20 +37,21 @@ logger = logging.getLogger(__name__)
 def _jax_default_shard():
     """(cur_shard, shard_count) from the JAX multihost topology, or (None, None).
 
-    Always probes ``jax.process_count()``: on Cloud TPU pod slices the
+    Always asks ``jax.process_count()``: on Cloud TPU pod slices the
     process topology comes from the TPU runtime itself (no explicit
-    ``jax.distributed.initialize`` needed), so skipping the probe would
-    silently de-shard a pod and feed every host the full dataset.
+    ``jax.distributed.initialize`` needed), so skipping it would silently
+    de-shard a pod and feed every host the full dataset.  For the same
+    reason a backend that fails to initialize raises here: only a missing
+    ``jax`` means "no auto-shard".
     """
     try:
         import jax
-
-        from petastorm_tpu.utils import apply_jax_platforms_env
-        apply_jax_platforms_env()
-        if jax.process_count() > 1:
-            return jax.process_index(), jax.process_count()
-    except Exception:  # noqa: BLE001 — jax absent/uninitialized: no auto-shard
-        pass
+    except ImportError:
+        return None, None
+    from petastorm_tpu.utils import apply_jax_platforms_env
+    apply_jax_platforms_env()
+    if jax.process_count() > 1:
+        return jax.process_index(), jax.process_count()
     return None, None
 
 

@@ -1,8 +1,7 @@
 """Disaggregated data-loading service: decode on fleet hosts, train on TPUs.
 
-Round-5 evidence (``BENCH_r05.json``) put the framework in the
-delivery-bound regime: one host's decode/collate plane cannot feed the
-chips (~95% stall).  This subsystem scales the decode plane horizontally
+For the delivery-bound regime: one host's decode/collate plane cannot
+feed the chips.  This subsystem scales the decode plane horizontally
 and independently of the training hosts — the architecture of tf.data's
 data service (arxiv 2101.12127) realized over this repo's own reader/pool
 machinery:

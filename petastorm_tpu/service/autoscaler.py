@@ -96,6 +96,9 @@ class SubprocessWorkerLauncher(WorkerLauncher):
         env = dict(os.environ)
         env['PYTHONPATH'] = root + (
             os.pathsep + env['PYTHONPATH'] if env.get('PYTHONPATH') else '')
+        # Decode workers are host-only: a chip belongs to one process (the
+        # trainer) — same pin as workers_pool/exec_in_new_process.py.
+        env['JAX_PLATFORMS'] = 'cpu'
         proc = subprocess.Popen(cmd, env=env)
         self._procs.append(proc)
         logger.info('autoscaler spawned worker pid %d', proc.pid)

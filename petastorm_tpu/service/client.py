@@ -555,12 +555,11 @@ def _default_consumer(num_consumers):
     """The sharding contract's default: this training host's index."""
     try:
         import jax
-
-        from petastorm_tpu.utils import apply_jax_platforms_env
-        apply_jax_platforms_env()
-        return jax.process_index() % num_consumers
-    except Exception:  # noqa: BLE001 — jax absent/uninitialized: consumer 0
+    except ImportError:   # no jax: a plain consumer 0; a backend failure raises
         return 0
+    from petastorm_tpu.utils import apply_jax_platforms_env
+    apply_jax_platforms_env()
+    return jax.process_index() % num_consumers
 
 
 class ServiceReader(object):

@@ -496,7 +496,7 @@ def test_docs_decision_catalogue_synced_with_code():
 def test_decision_record_overhead_is_micro():
     """The seam must stay cheap enough to sit on every control-law
     tick: well under a millisecond per record even on a loaded CI box
-    (the BENCH_NOTES micro pins the real number, ~µs)."""
+    (the real number is ~µs)."""
     journal = decisions.DecisionJournal(capacity=256)
     inputs = {'pending': 3, 'alive': ['w1', 'w2'], 'starve_s': 0.7,
               'threshold_s': 0.5}

@@ -620,16 +620,6 @@ def test_trend_integrity_rejects_fabricated_rounds(tmp_path, capsys):
              'backend': label}]) == []
 
 
-def test_repo_bench_history_is_integrity_clean():
-    """The checked-in store itself must pass the rules it now enforces
-    (the fabricated rounds 2-7 and 10-15 are purged; 1/8/9 are real)."""
-    from petastorm_tpu.benchmark import trend
-    entries = trend.load_history(os.path.join(REPO,
-                                              'BENCH_HISTORY.jsonl'))
-    assert entries, 'repo BENCH_HISTORY.jsonl missing or empty'
-    assert trend.check_integrity(entries) == []
-
-
 def test_trend_cli_exit_codes_and_default_tail_mode(tmp_path, capsys):
     from petastorm_tpu.benchmark import trend
     path = str(tmp_path / 'hist.jsonl')
@@ -671,19 +661,6 @@ def test_trend_is_stdlib_only_bare_file():
     # the file exits via sys.exit(main()) -> SystemExit(0) -> rc 0
     assert out.returncode == 0, out.stderr
     assert 'bench-trend' in out.stdout
-
-
-def test_repo_bench_history_round_one_checks_clean():
-    """Acceptance: BENCH_HISTORY.jsonl exists with this PR's bench run
-    as round 1, and `trend.py --check` exits 0 on it."""
-    from petastorm_tpu.benchmark import trend
-    path = os.path.join(REPO, 'BENCH_HISTORY.jsonl')
-    assert os.path.exists(path), 'BENCH_HISTORY.jsonl missing'
-    history = trend.load_history(path)
-    assert history and history[0]['round'] == 1
-    assert isinstance(history[0].get('value'), (int, float))
-    report = trend.check(path=path)
-    assert report['ok']
 
 
 # -- control-plane-degraded regime + verdicts (ISSUE 15) ----------------------

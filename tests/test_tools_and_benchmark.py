@@ -219,9 +219,10 @@ def test_doctor_report_over_petastorm_dataset(dataset, capsys):
 
     from petastorm_tpu.tools.doctor import main as doctor_main, run_doctor
 
-    report = run_doctor(dataset_url=dataset.url, probe_timeout_s=60,
+    report = run_doctor(dataset_url=dataset.url,
                         sample_seconds=0.5, batch_size=4)
-    assert report['backend']['probe_ok'] in (True, False)
+    assert report['backend']['backend'] == 'cpu', report['backend']
+    assert report['backend']['device_count'] >= 1
     assert 'loaded' in report['native']
     host = report['host_plane']
     assert 'error' not in host, host

@@ -1,0 +1,158 @@
+"""Compile the main path's kernels and jitted pieces for a *described* TPU
+v5e, at real widths — what the Pallas interpreter and the CPU backend cannot
+see: tile alignment, scoped-VMEM limits, whether a kernel is there at all.
+
+Nothing runs and no chip is attached: ``jax.experimental.topologies``
+describes a ``v5e:2x2`` host and the installed TPU compiler compiles for it.
+A compile that passes is not a chip run (``python chip_smoke.py`` is); it is
+the cheapest filter before one, and it guards every later PR at no chip time.
+
+One file on purpose: the worker that runs it loads the TPU library and keeps
+it until it exits, so the topology is described inside a module-scoped
+fixture — never at import, in a ``skipif`` or a ``parametrize`` argument —
+and every compile happens in the test's own process.
+"""
+
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+SHAPE = (4, 2048, 16, 128)
+#: A length on the chunked path for each dtype (``seq > kv_chunk_default``).
+#: float32 at head_dim 128 was refused here before the default chunk was
+#: sized from bytes: "Scoped allocation with size 16.13M and limit 16.00M".
+CHUNKED_SHAPE = {'bfloat16': (1, 16384, 8, 128), 'float32': (1, 8192, 16, 128)}
+ROWS, BATCH, IMAGE = 3328, 256, (224, 224, 3)
+
+
+@pytest.fixture(scope='module')
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 — whatever it raises: no compiler here
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    # An executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one: keep it off.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update('jax_enable_compilation_cache', enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def flash(monkeypatch):
+    """``flash_attention`` as it lowers on the chip: the wrapper picks the
+    interpreter (and skips the 128-lane block rounding) from
+    ``jax.default_backend()``, which here is still the CPU."""
+    import petastorm_tpu.ops.flash_attention   # noqa: F401 — the module, not the op
+    module = sys.modules['petastorm_tpu.ops.flash_attention']
+    monkeypatch.setattr(module, '_auto_interpret', lambda: False)
+    return module.flash_attention
+
+
+def _flash_program(flash, mode):
+    if mode == 'fwd':
+        return lambda q, k, v: flash(q, k, v, causal=True)
+    if mode == 'bwd':
+        return jax.grad(lambda q, k, v: flash(q, k, v, causal=True)
+                        .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    return jax.grad(lambda q, k, v, seg: flash(q, k, v, causal=True,
+                                               segment_ids=seg)
+                    .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize('mode', ['fwd', 'bwd', 'packed'])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+@pytest.mark.parametrize('length', ['2048', 'chunked'])
+def test_flash_attention_compiles_at_its_defaults(one_chip, flash, length,
+                                                  dtype, mode):
+    from petastorm_tpu.ops.flash_attention import kv_chunk_default
+    shape = SHAPE if length == '2048' else CHUNKED_SHAPE[dtype]
+    if length == 'chunked':
+        assert shape[1] > kv_chunk_default(shape[3], dtype)
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    args = (x, x, x)
+    if mode == 'packed':
+        args += (jax.ShapeDtypeStruct(shape[:2], jnp.int32, sharding=one_chip),)
+    compiled = jax.jit(_flash_program(flash, mode)).lower(*args).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
+
+
+@pytest.mark.parametrize('rows', [64, BATCH, ROWS],
+                         ids=['device_shard', 'batch', 'epoch_put_once'])
+def test_transfer_plane_unpack_compiles_compactly(one_chip, rows):
+    """The coalesced ImageNet slab -> ``image`` + ``noun_id``, at the sizes
+    the plane ships it: one chip's share of a sharded batch, a batch, and a
+    whole epoch (``DeviceInMemDataLoader`` via ``put_once``).
+
+    Both faults this pins were invisible off the chip's compiler.  Sliced
+    without a barrier, the program took the compiler ~4 s *per image* (22 min
+    at batch 256).  Reshaped directly, the image passed through a row-major
+    tiled copy that pads 3 channels to 128 lanes: 1.6 GB of scratch a batch,
+    and the epoch refused ("Allocation (size=21374173184) would exceed
+    memory")."""
+    import time
+
+    from petastorm_tpu.jax.transfer import TransferPlane
+    plane = TransferPlane(max_staging_bytes=1 << 30)
+    batch = {'image': np.zeros((rows,) + IMAGE, np.uint8),
+             'noun_id': np.zeros((rows,), np.int64)}
+    layout, unpack, plan = plane._prepare(batch)
+    assert plan is None and len(layout.fields) == 2
+    slab = jax.ShapeDtypeStruct((layout.slab_nbytes,), jnp.uint8,
+                                sharding=one_chip)
+    t0 = time.monotonic()
+    compiled = unpack.lower(slab).compile()
+    assert time.monotonic() - t0 < 120
+    memory = compiled.memory_analysis()
+    image_bytes = rows * int(np.prod(IMAGE))
+    assert memory.output_size_in_bytes >= image_bytes
+    assert memory.temp_size_in_bytes <= 4 * image_bytes
+
+
+def test_device_inmem_gather_compiles_at_epoch_size(one_chip, tmp_path):
+    """``DeviceInMemDataLoader``'s fused per-step gather over an HBM-resident
+    epoch of real size.  The loader is built over a tiny dataset (its jitted
+    gather closes over the batch size only) and lowered at the real shapes."""
+    from petastorm_tpu import make_reader
+    from petastorm_tpu.etl.dataset_metadata import DatasetWriter
+    from petastorm_tpu.jax import DeviceInMemDataLoader
+    from petastorm_tpu.unischema import Unischema, UnischemaField
+
+    url = 'file://' + str(tmp_path / 'tiny')
+    schema = Unischema('Tiny', [UnischemaField('noun_id', np.int64, (), None,
+                                               False)])
+    with DatasetWriter(url, schema) as writer:
+        writer.write_many({'noun_id': np.int64(i)} for i in range(2 * BATCH))
+    reader = make_reader(url, num_epochs=1, columnar_decode=True,
+                         workers_count=1)
+    with DeviceInMemDataLoader(reader, batch_size=BATCH, seed=0) as loader:
+        assert next(iter(loader))['noun_id'].shape == (BATCH,)
+        gather = loader._gather_fn
+    cache = {'image': jax.ShapeDtypeStruct((ROWS,) + IMAGE, jnp.uint8,
+                                           sharding=one_chip),
+             'noun_id': jax.ShapeDtypeStruct((ROWS,), jnp.int32,
+                                             sharding=one_chip)}
+    order = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = gather.lower(cache, order, start).compile()
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes >= BATCH * int(np.prod(IMAGE))
+    # the epoch cache plus one batch, far inside one chip's 16 GB
+    assert (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes) < 2 << 30
