@@ -84,17 +84,17 @@ class ArrowReaderWorker(ParquetWorkerBase):
             if not pred_fields:
                 raise ValueError('Predicate fields %s not present in files'
                                  % sorted(predicate.get_fields()))
-            pred_table = pf.read_row_group(piece.row_group, columns=pred_fields)
+            pred_table = self._read_row_group(pf, piece, pred_fields)
             cols = {n: pred_table.column(n).to_pylist() for n in pred_fields}
             mask = np.array([
                 predicate.do_include({n: cols[n][i] for n in pred_fields})
                 for i in range(pred_table.num_rows)], dtype=bool)
             if not mask.any():
                 return None
-            table = pf.read_row_group(piece.row_group, columns=wanted)
+            table = self._read_row_group(pf, piece, wanted)
             table = table.filter(pa.array(mask))
         else:
-            table = pf.read_row_group(piece.row_group, columns=wanted)
+            table = self._read_row_group(pf, piece, wanted)
 
         # Inject hive partition values as constant columns when requested.
         for key, value in piece.partition_values:

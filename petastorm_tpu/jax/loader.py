@@ -30,7 +30,6 @@ execution model instead of translated from them:
 
 import logging
 import os
-import time
 from collections import deque
 from contextlib import contextmanager
 
@@ -38,6 +37,7 @@ import numpy as np
 
 import jax
 
+from petastorm_tpu.jax.transfer import _DONE, DispatchPump
 from petastorm_tpu.parallel.mesh import global_batch_from_local
 
 logger = logging.getLogger(__name__)
@@ -162,23 +162,36 @@ class DataLoader(object):
         #: telemetry registry (ISSUE 5): ``stats`` is a view over its
         #: counters, and each stage additionally feeds a log2-bucket
         #: latency histogram (``diagnostics`` reports the p50/p99s).
-        from petastorm_tpu.telemetry import MetricsRegistry, flight
+        from petastorm_tpu.telemetry import (MetricsRegistry, Stages, flight,
+                                             process_registry)
         # Always-on flight recorder for the trainer process (ISSUE 7):
         # the stage histograms below snapshot into its bounded ring so a
         # postmortem sees the minutes before a hang, not final totals.
         flight.enable(label='trainer')
+        self._gc_watched = False
         self.metrics = MetricsRegistry('loader')
+        # One snapshot of ``metrics`` (what a benchmark takes its window
+        # deltas of) also shows what the reader's pool timed, as
+        # ``reader_*``, and what happened to the whole process (garbage
+        # collections, the flight thread waking late), as ``process_*``.
+        self.metrics.attach(
+            'reader_', lambda: getattr(reader, 'metrics', None))
+        self.metrics.attach('process_', process_registry())
         self._m_batches = self.metrics.counter('batches')
-        self._m_stage = {
-            stage: (self.metrics.counter(stage + '_s'),
-                    self.metrics.histogram(stage))
-            for stage in ('host_batch', 'transform', 'device_put')}
+        #: THE way a stage is timed here (``telemetry.Stages``): one pair
+        #: of clock readings feeds the ``<stage>_s`` counter, the
+        #: ``<stage>`` histogram, the ``pt/<stage>`` profiler span (a
+        #: nested part's is ``ptp/<stage>``), the ``trace_recorder`` span
+        #: and the provenance window.
+        self._stage = Stages(self.metrics, trace_recorder)
+        for stage in ('host_batch', 'transform', 'device_put'):
+            self._stage.instruments(stage)
         #: ``device_put`` above times only the async DISPATCH; this
         #: histogram samples TRUE transfer completion (a periodic
         #: ``block_until_ready``, plus every ring-slot reuse wait when
         #: the transfer plane is on) so ``diagnostics`` reports both
         #: dispatch and commit p50/p99.
-        self._m_commit = self.metrics.histogram('h2d_commit')
+        self.metrics.histogram('h2d_commit')
         self._commit_probe = 0
         # Per-batch provenance plane (ISSUE 13): every delivered batch
         # seals ONE record — the merge of its chunks' producer records
@@ -230,14 +243,6 @@ class DataLoader(object):
             if pool is not None and hasattr(pool, 'trace_recorder'):
                 pool.trace_recorder = trace_recorder
 
-    def _observe(self, stage, t0, t1):
-        """One stage sample: wall-time counter + latency histogram (the
-        tail-exemplar refs attach at provenance-seal time, see
-        :meth:`_seal_provenance`)."""
-        counter, hist = self._m_stage[stage]
-        counter.inc(t1 - t0)
-        hist.observe(t1 - t0)
-
     def _seal_provenance(self, stages, transfer=None, residency=None):
         """Merge the reader records drained since the last batch with
         this batch's consumer-side stage windows, seal into the journal,
@@ -275,7 +280,7 @@ class DataLoader(object):
                                      ('h2d_dispatch', 'device_put')):
             window = record['stages'].get(stage_name)
             if window is not None:
-                self._m_stage[hist_key][1].note_exemplar(
+                self.metrics.histogram(hist_key).note_exemplar(
                     window[1] - window[0], ref)
         if self._slo is not None:
             self._slo.check(record)
@@ -293,9 +298,9 @@ class DataLoader(object):
     def stats(self):
         """Aggregate per-stage seconds + batch count — the historical
         dict surface, now a view over ``self.metrics``."""
-        return {'host_batch_s': self._m_stage['host_batch'][0].value,
-                'transform_s': self._m_stage['transform'][0].value,
-                'device_put_s': self._m_stage['device_put'][0].value,
+        return {'host_batch_s': self.metrics.counter('host_batch_s').value,
+                'transform_s': self.metrics.counter('transform_s').value,
+                'device_put_s': self.metrics.counter('device_put_s').value,
                 'batches': int(self._m_batches.value)}
 
     # -- iteration -----------------------------------------------------------
@@ -303,9 +308,9 @@ class DataLoader(object):
     def _transfer_plane(self):
         """The loader's transfer plane, or None when disabled (kill
         switch, ``transfer=False``, or ``'auto'`` on the CPU backend).
-        Built once; shares the loader's registry and trace recorder so
-        its ``h2d_*`` histograms and ``h2d/*`` spans land on the same
-        surfaces as every other stage."""
+        Built once; shares the loader's registry and trace recorder, so
+        its ``h2d_*`` stages land on the same surfaces as every other
+        stage."""
         from petastorm_tpu.jax import transfer
         if not transfer.plane_enabled(self._transfer):
             return None
@@ -326,12 +331,9 @@ class DataLoader(object):
         self._commit_probe += 1
         if (self._commit_probe - 1) % every:
             return
-        t0 = time.monotonic()
-        jax.block_until_ready(dev)
-        t1 = time.monotonic()
-        self._m_commit.observe(t1 - t0)
-        if self._trace is not None:
-            self._trace.event('h2d/commit', t0, t1, kind='sample')
+        with self._stage('h2d_commit', span='ptp/h2d_commit',
+                         event='h2d/commit', kind='sample'):
+            jax.block_until_ready(dev)
 
     def __iter__(self):
         plane = self._transfer_plane()
@@ -345,94 +347,89 @@ class DataLoader(object):
             return self._iter_pumped(plane)
         return self._iter_inline()
 
+    def _waited(self, get):
+        """Yield ``get()``'s results until it gives ``_DONE``, each call
+        one ``next_wait`` sample under a ``ptc/next_wait`` profiler span:
+        how long the consuming thread was blocked in the loader for its
+        next batch, measured by the loader (the program's own stall share,
+        beside a harness's stopwatch around ``next()``).  ``ptc/``, not
+        ``pt/``: on the pumped path it covers the pump's spans in time."""
+        while True:
+            with self._stage('next_wait', span='ptc/next_wait') as wait:
+                item = get()
+                if item is _DONE:
+                    wait.keep = False
+                    return
+            yield item
+
+    def _ship(self, host_batch, plane=None):
+        """Transform → device → provenance seal → count, for one pulled
+        host batch: on the dispatch pump's thread through ``plane``, or
+        inline (``plane=None``).  Same stages, values and accounting on
+        both, so the ``pt/*`` spans sit on whichever thread does the
+        work."""
+        n = int(self._m_batches.value) + 1
+        stages = {}
+        if self._last_pull_window is not None:
+            # _timed_pulls runs on this same thread right before, so the
+            # stash is this batch's pull.
+            stages['host_batch'] = list(self._last_pull_window)
+        if self._transform_fn is not None:
+            with self._stage('transform', event='transform',
+                             batch=n) as transform:
+                host_batch = self._transform_fn(host_batch)
+            stages['transform'] = transform.window
+        # device_put_s covers the whole put: on the plane, stage +
+        # dispatch + any ring commit wait, each a stage of its own inside.
+        with self._stage('device_put', batch=n) as put:
+            dev = None
+            if plane is not None:
+                dev = plane.put(
+                    _filter_numeric(host_batch, self._warned_fields))
+            degraded = dev is None
+            if degraded:   # no plane, or the structure degrades
+                dev = self._to_device(host_batch)
+                # Only the inline put records the recorder's generic
+                # 'device_put' SPAN: a plane-handled batch already emitted
+                # h2d/stage + h2d/dispatch (+ h2d/commit) inside this
+                # window, and a wrapper span here would fold staging time
+                # into the 'h2d' link component — h2d >= h2d_stage by
+                # construction — so stall attribution could never name
+                # staging as top.
+                put.event = 'device_put'
+        if self.provenance is not None:
+            if degraded:
+                stages['h2d_dispatch'] = put.window
+                outcome = 'inline' if plane is None else 'degraded'
+            else:
+                stages.update(plane.last_put['stages'])
+                outcome = plane.last_put['outcome']
+            self._seal_provenance(stages, transfer=outcome)
+        self._m_batches.inc()
+        return dev
+
     def _iter_pumped(self, plane):
         """Transfer-plane iteration: a background dispatch thread pulls
         host batches, transforms, and ring-transfers them, so host
         staging, the H2D link, and the device step overlap as three
         pipeline stages.  Batch order, values, accounting surfaces and
         the exact-resume contract are identical to the inline path."""
-        from jax.profiler import TraceAnnotation
-
-        from petastorm_tpu.jax.transfer import _DONE, DispatchPump
-
         restored = []
         if self._resume_state and self._resume_state.get('pending'):
             restored = [self._to_device(b)
                         for b in self._resume_state['pending']]
             self._resume_state = dict(self._resume_state, pending=[])
 
-        def annotated_pulls(gen):
-            # Same pt/* jax.profiler spans as the inline path (SURVEY
-            # §5.1) — they land on the dispatch thread's track, which is
-            # exactly where this pipeline stage now runs.
-            while True:
-                with TraceAnnotation('pt/host_batch'):
-                    try:
-                        item = next(gen)
-                    except StopIteration:
-                        return
-                yield item
-
-        def ship(host_batch):
-            t1 = time.monotonic()
-            if self._transform_fn is not None:
-                with TraceAnnotation('pt/transform'):
-                    host_batch = self._transform_fn(host_batch)
-            t2 = time.monotonic()
-            with TraceAnnotation('pt/device_put'):
-                dev = plane.put(
-                    _filter_numeric(host_batch, self._warned_fields))
-                degraded = dev is None
-                if degraded:   # structure degrades: the existing path
-                    dev = self._to_device(host_batch)
-            t3 = time.monotonic()
-            if self.provenance is not None:
-                last = (plane.last_put if not degraded else None) or {}
-                stages = dict(last.get('stages') or {})
-                stages['transform'] = [t1, t2]
-                if degraded:
-                    stages['h2d_dispatch'] = [t2, t3]
-                if self._last_pull_window is not None:
-                    # _timed_pulls runs on this same (pump) thread right
-                    # before ship(), so the stash is this batch's pull.
-                    stages['host_batch'] = list(self._last_pull_window)
-                self._seal_provenance(
-                    stages, transfer=('degraded' if degraded
-                                      else last.get('outcome')))
-            self._observe('transform', t1, t2)
-            # Counter/histogram continuity: device_put_s covers the whole
-            # put (stage + dispatch + any ring commit wait) on this path.
-            self._observe('device_put', t2, t3)
-            self._m_batches.inc()
-            if self._trace is not None:
-                n = int(self._m_batches.value)
-                if self._transform_fn is not None:
-                    self._trace.event('transform', t1, t2, batch=n)
-                if degraded:
-                    # Only the inline fallback records the generic
-                    # 'device_put' SPAN: a plane-handled batch already
-                    # emitted h2d/stage + h2d/dispatch (+ h2d/commit)
-                    # inside this window, and a wrapper span here would
-                    # fold staging time into the 'h2d' link component —
-                    # h2d >= h2d_stage by construction — so stall
-                    # attribution could never name staging as top.
-                    self._trace.event('device_put', t2, t3, batch=n)
-            return dev
-
         pump = DispatchPump(
-            annotated_pulls(self._timed_pulls(self._echoed_host_batches())),
-            ship, self._prefetch)
+            self._timed_pulls(self._echoed_host_batches()),
+            lambda host_batch: self._ship(host_batch, plane), self._prefetch)
         for dev in restored:
             pump.pending.append(dev)
         self._pending = pump.pending
         self._pump = pump
         pump.start()
         try:
-            while True:
-                item = pump.get()
-                if item is _DONE:
-                    break
-                yield item
+            yield from self._waited(pump.get)
         finally:
             # Keep self._pump referencing this (now stopping) pump:
             # __exit__'s plane-close guard must still see a thread that
@@ -449,55 +446,30 @@ class DataLoader(object):
                 plane.drain()
 
     def _iter_inline(self):
-        # TraceAnnotation spans make the data pipeline visible in
-        # ``jax.profiler`` device traces (SURVEY.md §5.1): when a step
-        # stalls, the trace shows whether the time went to the decode
-        # plane (pt/host_batch), the user hook (pt/transform), or the H2D
-        # dispatch (pt/device_put).  Overhead is negligible when no trace
-        # is active.
-        from jax.profiler import TraceAnnotation
-
-        self._pending = deque()
+        """Everything on the consuming thread: pull, transform and put
+        until ``prefetch`` batches are ahead, then hand out the oldest.
+        The ``pt/*`` spans make the data pipeline visible in
+        ``jax.profiler`` device traces (SURVEY.md §5.1): when a step
+        stalls, the trace shows whether the time went to the decode plane
+        (pt/host_batch), the user hook (pt/transform), or the H2D dispatch
+        (pt/device_put).  Overhead is negligible when no trace is
+        active."""
+        pending = self._pending = deque()
         if self._resume_state and self._resume_state.get('pending'):
             for host_batch in self._resume_state['pending']:
-                self._pending.append(self._to_device(host_batch))
-            self._resume_state = dict(self._resume_state, pending=[])
-        pending = self._pending
-        batches = self._echoed_host_batches()
-        while True:
-            t0 = time.monotonic()
-            try:
-                with TraceAnnotation('pt/host_batch'):
-                    host_batch = next(batches)
-            except StopIteration:
-                break
-            t1 = time.monotonic()
-            if self._transform_fn is not None:
-                with TraceAnnotation('pt/transform'):
-                    host_batch = self._transform_fn(host_batch)
-            t2 = time.monotonic()
-            with TraceAnnotation('pt/device_put'):
                 pending.append(self._to_device(host_batch))
-            t3 = time.monotonic()
-            if self.provenance is not None:
-                self._seal_provenance(
-                    {'host_batch': [t0, t1], 'transform': [t1, t2],
-                     'h2d_dispatch': [t2, t3]}, transfer='inline')
-            self._observe('host_batch', t0, t1)
-            self._observe('transform', t1, t2)
-            self._observe('device_put', t2, t3)
-            self._m_batches.inc()
-            if self._trace is not None:
-                n = int(self._m_batches.value)
-                self._trace.event('host_batch', t0, t1, batch=n)
-                if self._transform_fn is not None:
-                    self._trace.event('transform', t1, t2, batch=n)
-                self._trace.event('device_put', t2, t3, batch=n)
-            self._sample_commit(pending[-1])
-            if len(pending) > self._prefetch:
-                yield pending.popleft()
-        while pending:
-            yield pending.popleft()
+            self._resume_state = dict(self._resume_state, pending=[])
+        pulls = self._timed_pulls(self._echoed_host_batches())
+
+        def get():
+            for host_batch in pulls:
+                pending.append(self._ship(host_batch))
+                self._sample_commit(pending[-1])
+                if len(pending) > self._prefetch:
+                    return pending.popleft()
+            return pending.popleft() if pending else _DONE
+
+        yield from self._waited(get)
 
     def _host_batches(self):
         gen = (self._columnar_batches() if self._batched_input
@@ -608,8 +580,8 @@ class DataLoader(object):
         reader_metrics = getattr(self.reader, 'metrics', None)
         decode_hist = (reader_metrics.histogram('decode')
                        if reader_metrics is not None else None)
-        host_hist = self._m_stage['host_batch'][1]
-        put_hist = self._m_stage['device_put'][1]
+        host_hist = self.metrics.histogram('host_batch')
+        put_hist = self.metrics.histogram('device_put')
 
         def ticked():
             for batch in gen:
@@ -859,20 +831,14 @@ class DataLoader(object):
         # is none here), so the bottleneck advisor and the doctor can
         # diagnose a host-boundary consumer too.
         for host_batch in self._timed_pulls(self._echoed_host_batches()):
-            t1 = time.monotonic()
-            t2 = None
+            stages = {}
+            if self._last_pull_window is not None:
+                stages['host_batch'] = list(self._last_pull_window)
             if self._transform_fn is not None:
-                host_batch = self._transform_fn(host_batch)
-                t2 = time.monotonic()
-                self._observe('transform', t1, t2)
-                if self._trace is not None:
-                    self._trace.event('transform', t1, t2)
+                with self._stage('transform', event='transform') as transform:
+                    host_batch = self._transform_fn(host_batch)
+                stages['transform'] = transform.window
             if self.provenance is not None:
-                stages = {}
-                if self._last_pull_window is not None:
-                    stages['host_batch'] = list(self._last_pull_window)
-                if t2 is not None:
-                    stages['transform'] = [t1, t2]
                 self._seal_provenance(stages)
             self._m_batches.inc()
             yield host_batch
@@ -883,19 +849,15 @@ class DataLoader(object):
         that owns pull accounting for every host-boundary consumer
         (``iter_host_batches``, ``scan_batches``)."""
         while True:
-            t0 = time.monotonic()
             try:
-                host_batch = next(gen)
+                with self._stage('host_batch', event='host_batch') as pull:
+                    host_batch = next(gen)
             except StopIteration:
                 return
-            t1 = time.monotonic()
             # Provenance: the pull window of the batch about to be
-            # consumed (read by ship() / the host-boundary consumers on
+            # consumed (read by _ship() / the host-boundary consumers on
             # the same thread).
-            self._last_pull_window = (t0, t1)
-            self._observe('host_batch', t0, t1)
-            if self._trace is not None:
-                self._trace.event('host_batch', t0, t1)
+            self._last_pull_window = pull.window
             yield host_batch
 
     # -- fused multi-step consumption ----------------------------------------
@@ -950,42 +912,36 @@ class DataLoader(object):
         plane = self._transfer_plane() if self._sharding is None else None
 
         def put_stacked(chunk, transformed=False):
-            # Same per-stage stats accounting as __iter__ (transform /
-            # stack+upload), so the bottleneck advisor can diagnose a
+            # Same per-stage accounting as __iter__ (transform / stack +
+            # upload), so the bottleneck advisor can diagnose a
             # scan_batches-consumed loader too.
-            t0 = time.monotonic()
+            k = len(chunk)
             if self._transform_fn is not None and not transformed:
-                chunk = [self._transform_fn(b) for b in chunk]
-            t1 = time.monotonic()
-            stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *chunk)
-            numeric = _filter_numeric(stacked, self._warned_fields)
-            out = None
-            if self._sharding is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-                spec = PartitionSpec(None, *self._sharding.spec)
-                out = global_batch_from_local(
-                    numeric, NamedSharding(self._sharding.mesh, spec))
-            elif plane is not None:
-                out = plane.put(numeric)   # None: degrade to inline below
-            planed = out is not None and plane is not None \
-                and self._sharding is None
-            if out is None:
-                if self._device is not None:
-                    out = jax.device_put(numeric, self._device)
-                else:
-                    out = jax.device_put(numeric)
-            t2 = time.monotonic()
-            self._observe('transform', t0, t1)
-            self._observe('device_put', t1, t2)
-            if self._trace is not None:
-                if self._transform_fn is not None and not transformed:
-                    self._trace.event('transform', t0, t1, chunk=len(chunk))
+                with self._stage('transform', event='transform', chunk=k):
+                    chunk = [self._transform_fn(b) for b in chunk]
+            with self._stage('device_put', chunk=k) as put:
+                stacked = jax.tree_util.tree_map(
+                    lambda *xs: np.stack(xs), *chunk)
+                numeric = _filter_numeric(stacked, self._warned_fields)
+                out = None
+                if self._sharding is not None:
+                    from jax.sharding import NamedSharding, PartitionSpec
+                    spec = PartitionSpec(None, *self._sharding.spec)
+                    out = global_batch_from_local(
+                        numeric, NamedSharding(self._sharding.mesh, spec))
+                elif plane is not None:
+                    out = plane.put(numeric)   # None: degrade to inline
+                planed = out is not None and plane is not None
+                if out is None:
+                    if self._device is not None:
+                        out = jax.device_put(numeric, self._device)
+                    else:
+                        out = jax.device_put(numeric)
                 if not planed:
                     # Plane-handled chunks already emitted h2d/* spans in
                     # this window; a wrapper 'device_put' span would fold
-                    # staging into the link component (see ship()).
-                    self._trace.event('device_put', t1, t2,
-                                      chunk=len(chunk))
+                    # staging into the link component (see _ship()).
+                    put.event = 'device_put'
             if not planed:
                 self._sample_commit(out, every=4)
             return out
@@ -1140,9 +1096,20 @@ class DataLoader(object):
         return out
 
     def __enter__(self):
+        if not self._gc_watched:
+            # From here to __exit__ every garbage collection of the
+            # process is timed (process_gc_*): one that stops the
+            # training thread is a stall no stage of the loader explains.
+            from petastorm_tpu.telemetry import flight
+            self._gc_watched = True
+            flight.watch_gc()
         return self
 
     def __exit__(self, exc_type, exc_value, tb):
+        if self._gc_watched:
+            from petastorm_tpu.telemetry import flight
+            self._gc_watched = False
+            flight.unwatch_gc()
         pump = self._pump
         if pump is not None:
             # Ask the dispatch thread out first; a pull blocked inside
@@ -1978,7 +1945,7 @@ class ResidentDataLoader(InMemDataLoader):
             else:
                 batches = self._streamed_epoch(cache, n, plan, tier,
                                                order_dev, starts, skip)
-            for j, batch in batches:
+            for j, batch in self._waited(lambda: next(batches, _DONE)):
                 self._m_batches.inc()
                 # Account BEFORE the yield (same contract as
                 # DeviceInMemDataLoader): a state_dict() taken while the
@@ -2008,24 +1975,20 @@ class ResidentDataLoader(InMemDataLoader):
         """Slice, narrow, place, widen one batch — the streamed delivery.
         Identical values to a warm gather over the same rows: both
         deliver ``widen(narrow(rows))``."""
-        t0 = time.monotonic()
-        host_rows = {name: np.asarray(v)[idx] for name, v in cache.items()}
-        wire = plan.narrow(host_rows) if plan is not None else host_rows
-        t1 = time.monotonic()
-        wire_dev = self._put_wire(wire)
-        batch = plan.widen(wire_dev) if plan is not None else wire_dev
-        t2 = time.monotonic()
-        self._observe('host_batch', t0, t1)
-        self._observe('device_put', t1, t2)
+        with self._stage('host_batch', event='host_batch') as host:
+            host_rows = {name: np.asarray(v)[idx]
+                         for name, v in cache.items()}
+            wire = plan.narrow(host_rows) if plan is not None else host_rows
+        with self._stage('device_put', event='device_put') as put:
+            wire_dev = self._put_wire(wire)
+            batch = plan.widen(wire_dev) if plan is not None else wire_dev
         self._res_counters.host_batches.inc()
-        return wire_dev, batch, [t0, t1], [t1, t2]
+        return wire_dev, batch, host.window, put.window
 
     def _streamed_epoch(self, cache, n, plan, tier, order_dev, starts, skip):
         """One epoch through the dispatch ring: a DispatchPump background
         thread slices/narrows/places while the consumer steps, and each
         delivered batch is admitted into the tier."""
-        from petastorm_tpu.jax.transfer import _DONE, DispatchPump
-
         order_np = np.asarray(order_dev)
         bs = self.batch_size
 
@@ -2051,11 +2014,7 @@ class ResidentDataLoader(InMemDataLoader):
         self._pump = pump
         pump.start()
         try:
-            while True:
-                item = pump.get()
-                if item is _DONE:
-                    return
-                yield item
+            yield from iter(pump.get, _DONE)
         finally:
             pump.stop(join_timeout_s=0.2)
 
@@ -2068,21 +2027,29 @@ class ResidentDataLoader(InMemDataLoader):
         for j, start in enumerate(starts):
             if j < skip:
                 continue
-            if tier.serving_ok():
-                if start + bs <= n:
-                    batch = tier.gather(order_dev, start)
-                else:  # ragged tail (drop_last=False)
-                    batch = tier.gather_tail(order_dev, start)
-                outcome = 'hit'
-            else:
-                if order_np is None:
-                    order_np = np.asarray(order_dev)
-                idx = order_np[start:min(start + bs, n)]
-                _, batch, _, _ = self._stream_one(cache, n, plan, idx)
-                outcome = 'bypass'
-                self._res_counters.bypass.inc()
-            if self.provenance is not None:
-                self._seal_provenance({}, residency=outcome)
+            # One warm batch on the consuming thread: the tier's checks,
+            # the gather's dispatch (``resident_gather``), the provenance
+            # seal.  What is not the gather is bookkeeping.
+            with self._stage('resident_serve',
+                             event='resident_serve') as serve:
+                if tier.serving_ok():
+                    with self._stage('resident_gather',
+                                     span='ptp/resident_gather'):
+                        if start + bs <= n:
+                            batch = tier.gather(order_dev, start)
+                        else:  # ragged tail (drop_last=False)
+                            batch = tier.gather_tail(order_dev, start)
+                    outcome = 'hit'
+                else:
+                    serve.keep = False   # streamed: host_batch + device_put
+                    if order_np is None:
+                        order_np = np.asarray(order_dev)
+                    idx = order_np[start:min(start + bs, n)]
+                    _, batch, _, _ = self._stream_one(cache, n, plan, idx)
+                    outcome = 'bypass'
+                    self._res_counters.bypass.inc()
+                if self.provenance is not None:
+                    self._seal_provenance({}, residency=outcome)
             yield j, batch
 
     def state_dict(self):

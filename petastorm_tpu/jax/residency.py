@@ -159,10 +159,11 @@ class WirePlan(object):
         if self._widen_fn is None:
             outs = {name: jnp.dtype(f.out) for name, f in self.fields.items()}
 
-            def _widen(tree):
+            @jax.named_scope('pt/residency_widen')
+            def pt_residency_widen(tree):
                 return {name: tree[name].astype(outs[name]) for name in tree}
 
-            self._widen_fn = jax.jit(_widen)
+            self._widen_fn = jax.jit(pt_residency_widen)
         return self._widen_fn(wire_dev)
 
 
@@ -459,12 +460,13 @@ class ResidencyTier(object):
     def _write(self, slot, rows, wire_dev):
         fn = self._write_fns.get(rows)
         if fn is None:
-            def _update(slabs, batch, start):
+            @jax.named_scope('pt/residency_update')
+            def pt_residency_update(slabs, batch, start):
                 return {name: jax.lax.dynamic_update_slice_in_dim(
                             slabs[name], batch[name], start, axis=0)
                         for name in slabs}
             donate = (0,) if self._donate else ()
-            fn = jax.jit(_update, donate_argnums=donate)
+            fn = jax.jit(pt_residency_update, donate_argnums=donate)
             self._write_fns[rows] = fn
         self._slabs = fn(self._slabs, wire_dev, slot)
 
@@ -507,14 +509,21 @@ class ResidencyTier(object):
             outs = {name: jnp.dtype(f.out)
                     for name, f in self._plan.fields.items()}
 
-            def _gather(slabs, slot_map, order, start):
-                idx = jax.lax.dynamic_slice_in_dim(order, start, bs)
-                slots = jnp.take(slot_map, idx)
-                return {name: jnp.take(slabs[name], slots,
-                                       axis=0).astype(outs[name])
-                        for name in slabs}
+            # Named for the device trace: the program is
+            # ``jit_pt_residency_gather`` in ``XLA Modules`` and its
+            # operations carry the two scopes, where an anonymous ``copy``
+            # stood before.
+            def pt_residency_gather(slabs, slot_map, order, start):
+                with jax.named_scope('pt/residency_gather'):
+                    idx = jax.lax.dynamic_slice_in_dim(order, start, bs)
+                    slots = jnp.take(slot_map, idx)
+                    rows = {name: jnp.take(slabs[name], slots, axis=0)
+                            for name in slabs}
+                with jax.named_scope('pt/residency_widen'):
+                    return {name: rows[name].astype(outs[name])
+                            for name in rows}
 
-            self._gather_fn = jax.jit(_gather)
+            self._gather_fn = jax.jit(pt_residency_gather)
         self._c.hits.inc()
         return self._gather_fn(self._slabs, self._slot_map(), order_dev, start)
 

@@ -51,21 +51,24 @@ sharding not leading-axis /            ``global_batch_from_local`` as today
 multi-host
 =====================================  =====================================
 
-Telemetry (ISSUE 5 plane): every transfer records ``h2d/stage`` (host
-pack), ``h2d/dispatch`` (async put + unpack dispatch) and ``h2d/commit``
+Telemetry: every transfer times three stages through the one stage
+primitive (``telemetry.Stages``): ``h2d_stage`` (host pack),
+``h2d_dispatch`` (async put + unpack dispatch) and ``h2d_commit``
 (observed wait for true transfer completion: ring-slot reuse waits, plus
-a periodic 1-in-32 full sample) spans into the loader's
-``TraceRecorder``, and the same stages into ``h2d_stage`` /
-``h2d_dispatch`` / ``h2d_commit`` histograms on the loader's metrics
-registry — ``attribute_stalls`` can now split staging-copy time from
-link time (components ``h2d_stage`` vs ``h2d``).
+a periodic 1-in-32 full sample, which alone also feeds
+``h2d_commit_sampled``).  Each is a ``ptp/h2d_*`` profiler span inside the
+loader's ``pt/device_put``, a histogram and a seconds counter on the
+loader's metrics registry, and an ``h2d/stage`` / ``h2d/dispatch`` /
+``h2d/commit`` span of the loader's ``TraceRecorder`` —
+``attribute_stalls`` can split staging-copy time from link time
+(components ``h2d_stage`` vs ``h2d``).  The jitted unpack is
+``jit_pt_h2d_unpack`` in the device trace.
 """
 
 import logging
 import os
 import threading
 from petastorm_tpu.utils.locks import make_condition
-import time
 from collections import deque
 
 import numpy as np
@@ -263,7 +266,8 @@ class _Layout(object):
         fields = list(self.fields)
         treedef = self.treedef
 
-        def unpack(slab):
+        @jax.named_scope('pt/h2d_unpack')
+        def pt_h2d_unpack(slab):
             leaves = []
             for f in fields:
                 # The barrier keeps a field's ops on that field's bytes.
@@ -290,7 +294,7 @@ class _Layout(object):
                 leaves.append(arr)
             return jax.tree_util.tree_unflatten(treedef, leaves)
 
-        return unpack
+        return pt_h2d_unpack
 
 
 def _reshape_rows_minor(flat, shape):
@@ -365,18 +369,22 @@ class TransferPlane(object):
         self._max_staging = (MAX_STAGING_BYTES if max_staging_bytes is None
                              else int(max_staging_bytes))
         self._prepared = {}   # signature -> (layout, unpack, plan) | None
-        self._trace = trace_recorder
+        from petastorm_tpu.telemetry import MetricsRegistry, Stages
         if metrics is None:
-            from petastorm_tpu.telemetry import MetricsRegistry
             metrics = MetricsRegistry('transfer')
         self.metrics = metrics
+        # parts of the loader's pt/device_put, so ptp/ (see Stages)
+        self._stage = Stages(metrics, trace_recorder, prefix='ptp/')
         self._m_batches = metrics.counter('h2d_batches')
         self._m_degraded = metrics.counter('h2d_degraded')
         self._m_wire = metrics.counter('h2d_bytes_wire')
         self._m_logical = metrics.counter('h2d_bytes_logical')
-        self._h_stage = metrics.histogram('h2d_stage')
-        self._h_dispatch = metrics.histogram('h2d_dispatch')
-        self._h_commit = metrics.histogram('h2d_commit')
+        for stage in ('h2d_stage', 'h2d_dispatch', 'h2d_commit'):
+            self._stage.instruments(stage)
+        #: ``h2d_commit`` mixes the residual wait at ring-slot reuse (near 0
+        #: when the ring keeps up) with the 1-in-32 full sample; this one
+        #: holds the full samples alone: the true dispatch-to-ready time.
+        self._h_sampled = metrics.histogram('h2d_commit_sampled')
         #: Per-batch provenance (ISSUE 13): outcome + stage windows of
         #: the most recent put — ``{'outcome': 'coalesced'|'narrowed'|
         #: 'degraded', 'stages': {'h2d_stage'/'h2d_dispatch'/
@@ -399,11 +407,10 @@ class TransferPlane(object):
         commit_window = self._wait_slot(slot)
         slab = self._slot_slab(slot, _slab_bytes(prepared))
         batch = self._staged_put(prepared, tree, slab)
-        if commit_window is not None and self.last_put is not None:
+        if commit_window is not None:
             # The ring-slot reuse barrier is observed link time of this
             # put's wall — part of its causal chain.
-            self.last_put.setdefault('stages', {})['h2d_commit'] = \
-                list(commit_window)
+            self.last_put['stages']['h2d_commit'] = commit_window
         self._inflight[slot] = batch
         return batch
 
@@ -423,23 +430,39 @@ class TransferPlane(object):
         """Pack → dispatch → on-device unpack + accounting — the shared
         core of ``put`` (ring slab) and ``put_once`` (transient slab)."""
         layout, unpack, plan = prepared
-        t0 = time.monotonic()
         if plan is None:
-            layout.pack(tree, slab)
-            t1 = time.monotonic()
-            dev_slab = (jax.device_put(slab, self._device)
-                        if self._device is not None else jax.device_put(slab))
-            batch = unpack(dev_slab)
+            with self._stage('h2d_stage', event='h2d/stage') as staged:
+                layout.pack(tree, slab)
+            with self._stage('h2d_dispatch',
+                             event='h2d/dispatch') as dispatched:
+                dev_slab = (jax.device_put(slab, self._device)
+                            if self._device is not None
+                            else jax.device_put(slab))
+                batch = unpack(dev_slab)
             wire = layout.slab_nbytes
         else:
-            t1, batch = self._put_sharded(layout, unpack, plan, tree, slab)
+            staged, dispatched, batch = self._put_sharded(
+                layout, unpack, plan, tree, slab)
             # One device_put PER DEVICE: a replicated mesh axis ships the
             # same segment to every replica, and those bytes are on the
             # link too.
             wire = plan.shard_layout.slab_nbytes * len(plan.devices)
-        t2 = time.monotonic()
-        self._account(layout, batch, wire, t0, t1, t2,
-                      sample_commit=sample_commit)
+        self._m_batches.inc()
+        self._m_wire.inc(wire)
+        self._m_logical.inc(layout.logical_nbytes)
+        self.last_put = {
+            'outcome': 'narrowed' if layout.narrowed else 'coalesced',
+            'stages': {'h2d_stage': staged.window,
+                       'h2d_dispatch': dispatched.window}}
+        if sample_commit \
+                and int(self._m_batches.value) % _COMMIT_SAMPLE_EVERY == 1:
+            # Periodic FULL commit sample: dispatch → device-ready wall
+            # time of the batch just put (the ring wait in _wait_slot
+            # only ever sees the residual after a full lap of overlap).
+            with self._stage('h2d_commit', event='h2d/commit',
+                             kind='sample') as sampled:
+                jax.block_until_ready(batch)
+            self._h_sampled.observe(sampled.seconds)
         return batch
 
     def drain(self):
@@ -466,45 +489,17 @@ class TransferPlane(object):
         batch = self._inflight[slot]
         if batch is None:
             return None
-        t0 = time.monotonic()
-        jax.block_until_ready(batch)
-        t1 = time.monotonic()
+        with self._stage('h2d_commit', event='h2d/commit',
+                         kind='ring') as waited:
+            jax.block_until_ready(batch)
         self._inflight[slot] = None
-        self._h_commit.observe(t1 - t0)
-        if self._trace is not None:
-            self._trace.event('h2d/commit', t0, t1, kind='ring')
-        return (t0, t1)
+        return waited.window
 
     def _slot_slab(self, slot, nbytes):
         slab = self._slabs[slot]
         if slab is None or slab.nbytes < nbytes:
             slab = self._slabs[slot] = np.empty(nbytes, np.uint8)
         return slab[:nbytes]
-
-    def _account(self, layout, batch, wire_bytes, t0, t1, t2,
-                 sample_commit=True):
-        self._m_batches.inc()
-        self._m_wire.inc(wire_bytes)
-        self._m_logical.inc(layout.logical_nbytes)
-        self._h_stage.observe(t1 - t0)
-        self._h_dispatch.observe(t2 - t1)
-        self.last_put = {
-            'outcome': 'narrowed' if layout.narrowed else 'coalesced',
-            'stages': {'h2d_stage': [t0, t1], 'h2d_dispatch': [t1, t2]}}
-        if self._trace is not None:
-            self._trace.event('h2d/stage', t0, t1)
-            self._trace.event('h2d/dispatch', t1, t2)
-        if sample_commit \
-                and int(self._m_batches.value) % _COMMIT_SAMPLE_EVERY == 1:
-            # Periodic FULL commit sample: dispatch → device-ready wall
-            # time of the batch just put (the ring wait in _wait_slot
-            # only ever sees the residual after a full lap of overlap).
-            t3 = time.monotonic()
-            jax.block_until_ready(batch)
-            t4 = time.monotonic()
-            self._h_commit.observe(t4 - t3)
-            if self._trace is not None:
-                self._trace.event('h2d/commit', t3, t4, kind='sample')
 
     # -- layout / plan cache -------------------------------------------------
 
@@ -595,25 +590,27 @@ class TransferPlane(object):
         overlap), unpack on-device per shard, and reassemble each leaf
         as one global array."""
         nbytes = plan.shard_layout.slab_nbytes
-        for start, stop in plan.uniq:
-            seg = slab[plan.seg_offsets[(start, stop)]:]
-            plan.shard_layout.pack(
-                jax.tree_util.tree_map(
-                    lambda v: np.asarray(v)[start:stop], tree),
-                seg[:nbytes])
-        t1 = time.monotonic()
-        shards = {}
-        for dev in plan.devices:   # all dispatches before any unpack
-            off = plan.seg_offsets[plan.ranges[dev]]
-            shards[dev] = jax.device_put(slab[off:off + nbytes], dev)
-        per_dev = [jax.tree_util.tree_leaves(unpack(shards[dev]))
-                   for dev in plan.devices]
-        out_leaves = []
-        for li, field in enumerate(layout.fields):
-            out_leaves.append(jax.make_array_from_single_device_arrays(
-                field.shape, self._sharding,
-                [per_dev[di][li] for di in range(len(plan.devices))]))
-        return t1, jax.tree_util.tree_unflatten(layout.treedef, out_leaves)
+        with self._stage('h2d_stage', event='h2d/stage') as staged:
+            for start, stop in plan.uniq:
+                seg = slab[plan.seg_offsets[(start, stop)]:]
+                plan.shard_layout.pack(
+                    jax.tree_util.tree_map(
+                        lambda v: np.asarray(v)[start:stop], tree),
+                    seg[:nbytes])
+        with self._stage('h2d_dispatch', event='h2d/dispatch') as dispatched:
+            shards = {}
+            for dev in plan.devices:   # all dispatches before any unpack
+                off = plan.seg_offsets[plan.ranges[dev]]
+                shards[dev] = jax.device_put(slab[off:off + nbytes], dev)
+            per_dev = [jax.tree_util.tree_leaves(unpack(shards[dev]))
+                       for dev in plan.devices]
+            out_leaves = []
+            for li, field in enumerate(layout.fields):
+                out_leaves.append(jax.make_array_from_single_device_arrays(
+                    field.shape, self._sharding,
+                    [per_dev[di][li] for di in range(len(plan.devices))]))
+        return staged, dispatched, jax.tree_util.tree_unflatten(
+            layout.treedef, out_leaves)
 
 
 _DONE = object()

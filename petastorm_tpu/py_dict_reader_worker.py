@@ -192,10 +192,21 @@ class PyDictReaderWorker(ParquetWorkerBase):
             return None
         return ts.resize_targets.get(name)
 
+    def _codec_decode(self, column):
+        """The stage that times one codec column of a row group (once a
+        column, never once a cell), counting its cells and encoded bytes:
+        ``codec_decode_s`` over ``codec_cells`` is the decode time of one
+        image out of ``native/``."""
+        stage = self._stage('codec_decode')
+        metrics = self._stages.metrics
+        metrics.counter('codec_cells').inc(len(column))
+        metrics.counter('codec_bytes').inc(column.nbytes)
+        return stage
+
     def _decode_columns(self, pf, piece, names):
         if not names:
             return {}
-        table = pf.read_row_group(piece.row_group, columns=list(names))
+        table = self._read_row_group(pf, piece, list(names))
         out = {}
         for name in names:
             f = self._a.schema.fields.get(name) or self._a.schema_view.fields.get(name)
@@ -214,9 +225,11 @@ class PyDictReaderWorker(ParquetWorkerBase):
                     dst = np.empty((len(column),) + tuple(target) + channels,
                                    dtype=f.numpy_dtype)
                     try:
-                        if not codec.decode_batch_into_resized(f, column, dst):
-                            for i, cell in enumerate(column.to_pylist()):
-                                codec.decode_resized_into(f, cell, dst[i])
+                        with self._codec_decode(column):
+                            if not codec.decode_batch_into_resized(f, column,
+                                                                   dst):
+                                for i, cell in enumerate(column.to_pylist()):
+                                    codec.decode_resized_into(f, cell, dst[i])
                     except Exception as e:
                         raise DecodeFieldError(
                             'Failed to decode+resize field %r: %s'
@@ -245,20 +258,22 @@ class PyDictReaderWorker(ParquetWorkerBase):
                 try:
                     # The arrow column goes to the native plane as-is: cell
                     # pointers aim into arrow buffers, skipping the per-cell
-                    # bytes copies a to_pylist materialization would pay.
-                    if batch_decode is not None and batch_decode(f, column, dst):
-                        out[name] = dst  # whole column decoded in one native call
-                        continue
-                    for i, c in enumerate(column.to_pylist()):
-                        codec.decode_into(f, c, dst[i])
+                    # bytes copies a to_pylist materialization would pay
+                    # (one native call for the whole column where it can).
+                    with self._codec_decode(column):
+                        if batch_decode is None \
+                                or not batch_decode(f, column, dst):
+                            for i, c in enumerate(column.to_pylist()):
+                                codec.decode_into(f, c, dst[i])
                 except Exception as e:
                     raise DecodeFieldError('Failed to decode field %r: %s' % (name, e)) from e
                 out[name] = dst
                 continue
-            cells = column.to_pylist()
             decode = codec.decode
             try:  # hoisted per-column error context; the loop stays lean
-                decoded = [decode(f, c) if c is not None else None for c in cells]
+                with self._codec_decode(column):
+                    decoded = [decode(f, c) if c is not None else None
+                               for c in column.to_pylist()]
             except Exception as e:
                 raise DecodeFieldError('Failed to decode field %r: %s' % (name, e)) from e
             out[name] = _stack_cells_np(decoded)
@@ -285,7 +300,7 @@ class PyDictReaderWorker(ParquetWorkerBase):
             first_pass = sorted(predicate_fields & set(self._a.schema.fields))
             if not first_pass:
                 raise ValueError('Predicate fields %s not in schema' % sorted(predicate_fields))
-            table = pf.read_row_group(piece.row_group, columns=first_pass)
+            table = self._read_row_group(pf, piece, first_pass)
             columns = {name: table.column(name).to_pylist() for name in first_pass}
             decoded_pred = [
                 {name: self._decode_cell(name, columns[name][i]) for name in first_pass}
@@ -297,7 +312,7 @@ class PyDictReaderWorker(ParquetWorkerBase):
             remaining = sorted(wanted - predicate_fields)
             rows = [dict(v) for v, keep in zip(decoded_pred, mask) if keep]
             if remaining:
-                rest = pf.read_row_group(piece.row_group, columns=remaining)
+                rest = self._read_row_group(pf, piece, remaining)
                 rest_cols = {name: rest.column(name).to_pylist() for name in remaining}
                 kept = 0
                 for i, keep in enumerate(mask):
@@ -311,7 +326,7 @@ class PyDictReaderWorker(ParquetWorkerBase):
                 rows = [{k: v for k, v in r.items() if k not in extra} for r in rows]
         else:
             columns = sorted(wanted)
-            table = pf.read_row_group(piece.row_group, columns=columns)
+            table = self._read_row_group(pf, piece, columns)
             cols = {name: table.column(name).to_pylist() for name in columns}
             rows = [
                 {name: self._decode_cell(name, cols[name][i]) for name in columns}

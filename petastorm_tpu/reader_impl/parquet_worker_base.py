@@ -20,6 +20,7 @@ import pyarrow as pa
 import pyarrow.parquet as pq
 
 from petastorm_tpu.errors import PoisonedRowGroupError
+from petastorm_tpu.telemetry import MetricsRegistry, Stages
 from petastorm_tpu.workers_pool.worker_base import WorkerBase
 
 logger = logging.getLogger(__name__)
@@ -76,6 +77,23 @@ class ParquetWorkerBase(WorkerBase):
         #: decode work, not waiting (docs/performance.md tells operators to
         #: use it to distinguish decode-bound from I/O-bound).
         self.retry_sleep_s = 0.0
+        self._stages = None
+
+    def _stage(self, name):
+        """One timed stage of this worker (``telemetry.Stages``), into the
+        owning pool's registry: ``rowgroup_read`` (Parquet read +
+        decompress), ``codec_decode`` (one codec column of a row group).
+        The profiler spans are ``ptw/<name>``, outside ``pt/``
+        (``telemetry.Stages`` says why)."""
+        if self._stages is None:
+            self._stages = Stages(
+                self.metrics if self.metrics is not None
+                else MetricsRegistry('reader_worker'), prefix='ptw/')
+        return self._stages(name)
+
+    def _read_row_group(self, pf, piece, columns):
+        with self._stage('rowgroup_read'):
+            return pf.read_row_group(piece.row_group, columns=columns)
 
     def _parquet_file(self, path):
         entry = self._open_files.get(path)

@@ -39,14 +39,15 @@ from petastorm_tpu.telemetry import flight  # noqa: F401
 from petastorm_tpu.telemetry import health  # noqa: F401
 from petastorm_tpu.telemetry import provenance  # noqa: F401
 from petastorm_tpu.telemetry.registry import (  # noqa: F401
-    MetricsRegistry, hist_quantile, merge_snapshots, snapshot_all,
-    snapshot_delta, summarize_hist)
+    MetricsRegistry, hist_quantile, merge_snapshots, process_registry,
+    snapshot_all, snapshot_delta, summarize_hist)
 from petastorm_tpu.telemetry.spans import (  # noqa: F401
-    SpanBuffer, attribute_stalls, current_buffer, measure_clock_offset,
-    merge_into_recorder)
+    SpanBuffer, Stages, attribute_stalls, current_buffer,
+    measure_clock_offset, merge_into_recorder)
 
 __all__ = ['MetricsRegistry', 'merge_snapshots', 'hist_quantile',
            'snapshot_all', 'snapshot_delta', 'summarize_hist',
+           'process_registry', 'Stages',
            'SpanBuffer', 'current_buffer', 'merge_into_recorder',
            'measure_clock_offset', 'attribute_stalls', 'dump_state',
            'decisions', 'flight', 'health', 'provenance']

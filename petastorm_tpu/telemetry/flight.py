@@ -39,6 +39,7 @@ scope.
 """
 
 import fcntl
+import gc
 import os
 import re
 import threading
@@ -46,12 +47,15 @@ from petastorm_tpu.utils.locks import make_lock
 import time
 
 from petastorm_tpu.telemetry import decisions, provenance
-from petastorm_tpu.telemetry.registry import merge_snapshots, snapshot_all
-from petastorm_tpu.telemetry.spans import current_buffer
+from petastorm_tpu.telemetry.registry import (merge_snapshots,
+                                              process_registry, snapshot_all)
+from petastorm_tpu.telemetry.spans import (Stages, current_buffer,
+                                           profiler_span)
 from petastorm_tpu.utils import ipc
 
 __all__ = ['FlightRecorder', 'window_frames', 'enable', 'get', 'disable',
-           'dump_current', 'default_persist_path', 'sweep_dumps']
+           'dump_current', 'default_persist_path', 'sweep_dumps',
+           'watch_gc', 'unwatch_gc']
 
 
 def window_frames(frames, seconds=None):
@@ -128,24 +132,29 @@ class FlightRecorder(object):  # ptlint: disable=pickle-unsafe-attrs — per-pro
         self._ticks = 0
         self._started_monotonic = time.monotonic()
         self._started_unix = time.time()
+        self._stage = Stages(process_registry())
 
     # -- recording -----------------------------------------------------------
 
     def tick(self):
         """Record one frame.  Contained: a diagnostic must never take the
-        process it is diagnosing down with it."""
-        try:
-            frame = self._build_frame()
-        except Exception:  # noqa: BLE001 — diagnostics are best-effort
-            return None
-        with self._lock:
-            self._frames.append(frame)
-            del self._frames[:-self.max_frames]
-            self._ticks += 1
-            ticks = self._ticks
-        self._last_tick = time.monotonic()
-        if self.persist_path and ticks % self.persist_every == 0:
-            self.persist(reason='periodic')
+        process it is diagnosing down with it.  A stage of its own
+        (``flight_tick`` in the process registry, ``pt/flight_tick`` in a
+        profile), the periodic persist included: a tick merges every
+        registry and at times writes JSON, under the GIL."""
+        with self._stage('flight_tick'):
+            try:
+                frame = self._build_frame()
+            except Exception:  # noqa: BLE001 — diagnostics are best-effort
+                return None
+            with self._lock:
+                self._frames.append(frame)
+                del self._frames[:-self.max_frames]
+                self._ticks += 1
+                ticks = self._ticks
+            self._last_tick = time.monotonic()
+            if self.persist_path and ticks % self.persist_every == 0:
+                self.persist(reason='periodic')
         return frame
 
     def maybe_tick(self):
@@ -202,8 +211,17 @@ class FlightRecorder(object):  # ptlint: disable=pickle-unsafe-attrs — per-pro
         return self
 
     def _run(self):
+        # The thread only sleeps, so it wakes later than asked exactly when
+        # the process, or the GIL, stood still: ``tick_late`` is the witness
+        # that tells "everything paused" from "one thread waited".
+        late_s, late = self._stage.instruments('tick_late')
+        asleep = time.monotonic()
         while not self._stop.wait(self.interval_s):
+            seconds = max(0.0, time.monotonic() - asleep - self.interval_s)
+            late_s.inc(seconds)
+            late.observe(seconds)
             self.tick()
+            asleep = time.monotonic()
 
     def stop(self):
         self._stop.set()
@@ -395,6 +413,74 @@ def sweep_dumps(directory=None, min_age_s=DEFAULT_SWEEP_MIN_AGE_S):
         except OSError:
             result['kept'] += 1
     return result
+
+
+# -- garbage collections -------------------------------------------------------
+
+class _GcWatch(object):  # ptlint: disable=pickle-unsafe-attrs — one per process, holding that process's hook in gc.callbacks; never pickled
+    """The ``gc.callbacks`` hook behind :func:`watch_gc`: every collection
+    timed into the process registry from 'start' to 'stop', under a
+    ``pt/gc`` profiler span on the thread it runs on.  The hook takes no
+    lock: a collection starts wherever an allocation happens, also inside
+    a registry's own lock, and its instruments are the registry's unlocked
+    ones (collections never overlap, so they have one writer at a time)."""
+
+    def __init__(self):
+        self._lock = make_lock('telemetry.flight._GcWatch._lock')
+        self._watchers = 0
+        self._open = None     # (t0, profiler span) of the running collection
+        registry = process_registry()
+        self._collections = registry.counter('gc_collections')
+        self._pause_s = registry.counter('gc_pause_s')
+        self._pause = registry.histogram('gc_pause')
+
+    def _on_gc(self, phase, info):
+        if phase == 'start':
+            # A span only beyond the youngest generation: those are the
+            # collections that can take long enough to own an idle gap of
+            # the device, a tenth of all (the trace reduction pays for
+            # every pt/* span); the counters count every collection.
+            span = profiler_span('pt/gc') if info['generation'] else None
+            if span is not None:
+                span.__enter__()
+            self._open = (time.monotonic(), span)
+        elif self._open is not None:
+            (t0, span), self._open = self._open, None
+            seconds = time.monotonic() - t0
+            if span is not None:
+                span.__exit__(None, None, None)
+            self._collections.inc()
+            self._pause_s.inc(seconds)
+            self._pause.observe(seconds)
+
+    def watch(self):
+        with self._lock:
+            self._watchers += 1
+            if self._watchers == 1:
+                gc.callbacks.append(self._on_gc)
+
+    def unwatch(self):
+        with self._lock:
+            if self._watchers == 0:
+                return
+            self._watchers -= 1
+            if self._watchers == 0:
+                gc.callbacks.remove(self._on_gc)
+
+
+_GC_WATCH = _GcWatch()
+
+
+def watch_gc():
+    """Time every garbage collection of this process from now on (a loader
+    that is entered calls this; between collections nothing is paid).
+    Counting: the hook leaves ``gc.callbacks`` with the last
+    :func:`unwatch_gc`."""
+    _GC_WATCH.watch()
+
+
+def unwatch_gc():
+    _GC_WATCH.unwatch()
 
 
 # -- process singleton --------------------------------------------------------

@@ -23,13 +23,14 @@ channels are how the halves reunite.
 """
 
 import bisect
+import contextlib
 import math
 from petastorm_tpu.utils.locks import make_lock
 import weakref
 
 __all__ = ['MetricsRegistry', 'Counter', 'Gauge', 'Histogram',
            'merge_snapshots', 'hist_quantile', 'snapshot_all', 'ms',
-           'summarize_hist', 'snapshot_delta']
+           'summarize_hist', 'snapshot_delta', 'process_registry']
 
 
 def ms(seconds):
@@ -147,6 +148,7 @@ class MetricsRegistry(object):
         self._counters = {}
         self._gauges = {}
         self._histograms = {}
+        self._attached = []
         _LIVE.add(self)
 
     # Registries cross the ProcessPool boundary inside PlaneCache-holding
@@ -154,7 +156,8 @@ class MetricsRegistry(object):
     # child — the copies then diverge and reunite through the snapshot
     # merge channels, like every other per-process counter.
     def __getstate__(self):
-        return {'namespace': self.namespace, 'snapshot': self.snapshot()}
+        return {'namespace': self.namespace,
+                'snapshot': self.snapshot(attached=False)}
 
     def __setstate__(self, state):
         self.__init__(state['namespace'])
@@ -178,17 +181,44 @@ class MetricsRegistry(object):
 
     # -- snapshot / merge ----------------------------------------------------
 
-    def snapshot(self):
+    def attach(self, prefix, registry):
+        """Show another registry's instruments in this one's
+        :meth:`snapshot` as ``<prefix><name>``.  By reference: nothing is
+        copied until a snapshot is taken, and the other registry stays the
+        source of truth (its owner keeps observing into it).  ``registry``
+        may be a zero-argument callable that returns the registry or None
+        (a reader builds a new pool, and with it a new registry, on
+        ``reset()``).  The loader attaches its reader's pool as ``reader_``
+        and the process's (:func:`process_registry`) as ``process_``, so one
+        window delta of ``loader.metrics`` covers all three."""
+        self._attached.append((prefix, registry))
+
+    def snapshot(self, attached=True):
         """Plain-dict copy of every instrument — picklable, JSON-able,
-        and addition-mergeable (`merge_snapshots`)."""
+        and addition-mergeable (`merge_snapshots`) — with the attached
+        registries' under their prefixes.  ``attached=False`` gives this
+        registry's own alone: what a process-wide rollup
+        (:func:`snapshot_all`, the scrape endpoint) wants, since it meets
+        the attached registries under their own names anyway."""
         with self._lock:
-            return {
+            snap = {
                 'namespace': self.namespace,
                 'counters': {k: c.value for k, c in self._counters.items()},
                 'gauges': {k: g.value for k, g in self._gauges.items()},
                 'histograms': {
                     k: _hist_dict(h) for k, h in self._histograms.items()},
             }
+            others = list(self._attached) if attached else ()
+        for prefix, registry in others:   # outside the lock: theirs is taken
+            if callable(registry):
+                registry = registry()
+            if registry is None:
+                continue
+            other = registry.snapshot(attached=False)
+            for table in ('counters', 'gauges', 'histograms'):
+                snap[table].update((prefix + k, v)
+                                   for k, v in other[table].items())
+        return snap
 
     def merge(self, snapshot):
         """Add a snapshot's counts into this registry (counters and
@@ -217,8 +247,9 @@ class MetricsRegistry(object):
     def as_dict(self):
         """Flat ``name -> value`` view (counters + gauges), plus
         ``<hist>_p50_ms`` / ``<hist>_p99_ms`` / ``<hist>_count`` per
-        histogram — the shape the diagnostics dicts are built from."""
-        snap = self.snapshot()
+        histogram — the shape the diagnostics dicts are built from.
+        This registry's own instruments only."""
+        snap = self.snapshot(attached=False)
         out = dict(snap['counters'])
         out.update(snap['gauges'])
         for name, hist in snap['histograms'].items():
@@ -230,7 +261,7 @@ class MetricsRegistry(object):
     def render_prometheus(self):
         """Text exposition format (one scrape target per process); the
         namespace becomes the metric prefix."""
-        snap = self.snapshot()
+        snap = self.snapshot(attached=False)
         prefix = 'petastorm_tpu_'
         if snap['namespace']:
             prefix += _sanitize(snap['namespace']) + '_'
@@ -403,4 +434,35 @@ def snapshot_delta(new, old):
 
 def snapshot_all():
     """Snapshots of every live registry in this process (crash dumps)."""
-    return [r.snapshot() for r in list(_LIVE)]
+    return [r.snapshot(attached=False) for r in list(_LIVE)]
+
+
+def _process_instruments():
+    registry = MetricsRegistry('process')
+    # A collection's callback runs wherever an allocation happens, also on
+    # a thread that holds this registry's lock (``snapshot`` builds dicts
+    # under it): its instruments take none.  Collections never overlap.
+    unlocked = contextlib.nullcontext()
+    for name in ('gc_pause_s', 'gc_collections'):
+        registry._counters[name] = Counter(unlocked)
+    registry._histograms['gc_pause'] = Histogram(unlocked)
+    for name in ('flight_tick', 'tick_late'):
+        registry.counter(name + '_s')
+        registry.histogram(name)
+    return registry
+
+
+_PROCESS = _process_instruments()
+
+
+def process_registry():
+    """The one registry of what happens to the process as a whole, not to
+    a loader or a pool: garbage collections that stop every thread
+    (``gc_pause_s``, ``gc_pause``, ``gc_collections``; ``flight.watch_gc``)
+    and the flight recorder's own thread (``flight_tick``, how long a tick
+    took; ``tick_late``, how much later than its interval the thread woke,
+    which a thread that only sleeps does exactly when the process or the
+    GIL stood still; each a histogram with its ``_s`` counter of seconds).
+    Every instrument exists from the start, so a window with no collection
+    reads 0 and not nothing."""
+    return _PROCESS

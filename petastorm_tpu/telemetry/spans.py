@@ -27,14 +27,116 @@ client can align every worker's spans without talking clocks to each
 worker directly.
 """
 
+import contextlib
 import os
+import sys
 import threading
 from petastorm_tpu.utils.locks import make_lock
 import time
 from collections import deque
 
 __all__ = ['SpanBuffer', 'current_buffer', 'merge_into_recorder',
-           'measure_clock_offset', 'attribute_stalls', 'STALL_COMPONENTS']
+           'measure_clock_offset', 'attribute_stalls', 'STALL_COMPONENTS',
+           'Stages', 'profiler_span']
+
+
+def profiler_span(name):
+    """A ``jax.profiler.TraceAnnotation(name)``: a span on this thread's
+    line of the profiler's trace, on the device trace's clock, and nothing
+    but a flag test while no trace is being taken.  A process that never
+    imported jax cannot be taking one, so it gets a null context and is
+    not made to import jax for this (reader pool children)."""
+    jax = sys.modules.get('jax')
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
+class Stages(object):
+    """The one way a stage of the data path is timed.
+
+    ``with stages('host_batch', event='host_batch') as stage: ...`` holds a
+    profiler span ``pt/host_batch`` open for the block and, from ONE pair
+    of ``time.monotonic()`` readings taken inside it, feeds the
+    ``host_batch_s`` counter and the ``host_batch`` histogram of
+    ``metrics`` and, where a ``TraceRecorder`` is attached and ``event``
+    names one, that recorder's span.  ``stage.window`` is then the
+    ``[t0, t1]`` that a provenance record wants.  So the two span systems
+    (the profiler's, which the benchmark reads; the recorder's, see
+    ``docs/observability.md``) and the registry cannot drift apart.
+
+    Instruments are made on a stage's first use.  A block left by an
+    exception, or with ``stage.keep = False``, leaves the profiler span
+    and nothing else (the pull that met the end of the stream is no
+    sample).
+
+    ``pt/`` is for the spans that may own an idle gap of the device: the
+    top-level stages of the thread that feeds it, and the pauses of the
+    whole process.  ``benchmarks/trace_reduce.py`` gives each gap to the
+    ``pt/*`` span that covers most of it, and walks every ``pt/*`` span for
+    every gap, so a span that could never rightly own one is named outside
+    the prefix (``span=``, or another ``prefix``): a part nested in a stage
+    (``ptp/h2d_stage`` inside ``pt/device_put``: the stage covers whatever
+    its part covers), the consumer's wait (``ptc/next_wait``: it covers the
+    pump's spans in time), a reader worker's (``ptw/codec_decode``: seconds
+    long on ten threads, it covers every gap without having caused it).
+    """
+
+    def __init__(self, metrics, recorder=None, prefix='pt/'):
+        self.metrics = metrics
+        self.recorder = recorder
+        self.prefix = prefix
+        self._instruments = {}
+
+    def instruments(self, name):
+        """``(<name>_s counter, <name> histogram)`` of a stage."""
+        found = self._instruments.get(name)
+        if found is None:
+            found = self._instruments[name] = (
+                self.metrics.counter(name + '_s'),
+                self.metrics.histogram(name))
+        return found
+
+    def __call__(self, name, span=None, event=None, **event_args):
+        return _Stage(self, name, span or self.prefix + name, event,
+                      event_args)
+
+
+class _Stage(object):
+    __slots__ = ('_stages', 'name', 'span', 'event', 'event_args', 'keep',
+                 'window', '_profiler_span')
+
+    def __init__(self, stages, name, span, event, event_args):
+        self._stages = stages
+        self.name = name
+        self.span = span
+        self.event = event
+        self.event_args = event_args
+        self.keep = True
+        self.window = None
+
+    @property
+    def seconds(self):
+        return self.window[1] - self.window[0]
+
+    def __enter__(self):
+        self._profiler_span = profiler_span(self.span)
+        self._profiler_span.__enter__()
+        self.window = [time.monotonic(), None]
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t0, t1 = self.window[0], time.monotonic()
+        self._profiler_span.__exit__(exc_type, exc, tb)
+        if exc_type is None and self.keep:
+            self.window[1] = t1
+            counter, hist = self._stages.instruments(self.name)
+            counter.inc(t1 - t0)
+            hist.observe(t1 - t0)
+            recorder = self._stages.recorder
+            if recorder is not None and self.event is not None:
+                recorder.event(self.event, t0, t1, **self.event_args)
+        return False
 
 
 class SpanBuffer(object):

@@ -41,6 +41,7 @@ class DummyPool(object):
     def start(self, worker_class, worker_setup_args=None, ventilator=None,
               reorder=None):
         self._worker = worker_class(0, self._publish, worker_setup_args)
+        self._worker.metrics = self.metrics
         self._ventilator = ventilator
         self._reorder = reorder
         self._position = None

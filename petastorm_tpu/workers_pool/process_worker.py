@@ -157,6 +157,7 @@ def worker_main(setup_payload, worker_id):
     import time
 
     worker = worker_class(worker_id, publish, worker_args)
+    worker.metrics = metrics   # what the worker times rides every ack too
     # The reader workers carry their cache in the setup-args dataclass
     # (`worker._a.cache`); when it is a PlaneCache, its fill telemetry
     # lives on per-instance surfaces (plane registry + plane span

@@ -82,6 +82,7 @@ class ThreadPool(object):  # ptlint: disable=pickle-unsafe-attrs — in-process 
         self._started_at = time.monotonic()
         for worker_id in range(self.workers_count):
             worker = worker_class(worker_id, self._publish, worker_setup_args)
+            worker.metrics = self.metrics
             self._workers.append(worker)
             thread = threading.Thread(target=self._worker_loop, args=(worker,),
                                       name='reader-worker-%d' % worker_id, daemon=True)

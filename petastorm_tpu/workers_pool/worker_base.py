@@ -15,6 +15,10 @@ class WorkerBase(object):
         self.worker_id = worker_id
         self.publish_func = publish_func
         self.args = args
+        #: The owning pool's ``MetricsRegistry``: the pool sets it right
+        #: after construction, so what a worker times (row-group read,
+        #: codec decode) lands beside the pool's own counters.
+        self.metrics = None
 
     def process(self, *args, **kwargs):
         raise NotImplementedError()
