@@ -1870,7 +1870,8 @@ class ResidentDataLoader(InMemDataLoader):
                 'hits': int(c.hits.value),
                 'bypass': int(c.bypass.value),
                 'thrash': int(c.thrash.value),
-                'host_batches': int(c.host_batches.value)}
+                'host_batches': int(c.host_batches.value),
+                'rowcopy_fields': int(c.rowcopy_fields.value)}
 
     def drop_resident_tier(self):
         """Release the resident tier now (e.g. to reclaim HBM for a model

@@ -23,6 +23,23 @@ Three composing pieces (ISSUE 17):
   Once every dataset row is resident, warm epochs are served by a single
   jitted gather+widen and fetch **zero** host batches.
 
+How rows are stored (ISSUE 27), and why.  A field's slab is never
+``(capacity,) + row_shape``: the TPU's own layout for an NHWC uint8 array
+keeps the *batch* axis in the lanes, so a slab of that shape interleaves
+its rows byte by byte, and ``take`` had to re-lay the whole slab (1.5 GB
+at 9,984 ImageNet rows: 7.4 ms a step on the v5e, and growing with the
+tier) before it could pick 256 of them.  Flat ``(capacity, elems)``
+storage is not enough for a wide row either: ``take`` over rows of more
+than 32,768 elements is split by the TPU compiler into column slices of
+the *whole* slab, and because eight rows share every ``(8, 128)`` tile no
+single row can be copied alone.  So a field of up to 32,768 elements a
+row lies flat and is read with ``take``; a wider one lies as ``(capacity,
+ceil(elems / 128), 128)``, every row whole tiles of its own, and is read
+by one HBM-to-HBM DMA a row.  Both then reach ``row_shape`` through the
+transfer plane's ``_reshape_rows_minor``, the way a streamed batch gets
+to the device's layout.  A warm step moves the bytes of one batch and
+nothing that grows with ``capacity``.
+
 Degrade matrix (mirrors the transfer plane's conventions):
 
 * ``PETASTORM_TPU_NO_RESIDENCY=1`` — kill switch; the loader streams
@@ -49,7 +66,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from petastorm_tpu.jax.transfer import _supported, wire_dtype_for
+from petastorm_tpu.jax.transfer import (_reshape_rows_minor, _supported,
+                                        wire_dtype_for)
 from petastorm_tpu.telemetry import decisions as _decisions
 
 #: Kill switch: set to any non-empty value to disable the resident tier.
@@ -72,7 +90,28 @@ GAUGE_NAMES = (
     'residency_rows',
     'residency_bytes',
     'residency_budget_bytes',
+    'residency_rowcopy_fields',
 )
+
+#: A field whose row has more elements than this is read one copy a row;
+#: up to it, with ``take``.  Compiled for the v5e, ``take`` over a
+#: row-major ``(capacity, elems)`` slab needs no scratch up to 32,768
+#: elements a row, in uint8, bfloat16 and int32 alike, and from 32,896 on
+#: it slices the whole slab by columns into temporaries that grow with
+#: ``capacity`` (330 MB at 20,000 uint8 rows, 660 MB at 40,000).
+_TAKE_MAX_ROW_ELEMS = 32768
+
+#: The lanes of a TPU tile.  A 2-D slab is tiled ``(8, 128)`` over (row,
+#: element), so eight rows (thirty-two of uint8) share every tile and one
+#: row cannot be copied without the others: a loop of ``dynamic_slice``
+#: over ``u8[9984, 150528]`` took 4.1 ms a batch of 256 on the v5e.  A
+#: wide row is therefore stored as ``(ceil(elems / 128), 128)``, tiles of
+#: its own and contiguous, padded with at most 127 elements.
+_LANES = 128
+
+#: Row copies the gather keeps in flight.  8 and 32 read the same on the
+#: v5e: 0.05 ms for 256 rows of 150,528 bytes.
+_COPIES_IN_FLIGHT = 8
 
 
 def killed():
@@ -274,6 +313,7 @@ class ResidencyCounters(object):
         self.rows = metrics.gauge('residency_rows')
         self.bytes = metrics.gauge('residency_bytes')
         self.budget = metrics.gauge('residency_budget_bytes')
+        self.rowcopy_fields = metrics.gauge('residency_rowcopy_fields')
 
 
 def ensure_counters(metrics):
@@ -285,21 +325,115 @@ def ensure_counters(metrics):
 # The residency LRU tier
 # ---------------------------------------------------------------------------
 
+def _auto_interpret():
+    return jax.default_backend() != 'tpu'
+
+
+def _slab_row_shape(field):
+    """How one row of ``field`` lies in its slab: a scalar as itself, a
+    narrow row flat, a wide row as whole ``(8, 128)`` tiles of its own."""
+    if not field.row_shape:
+        return ()
+    elems = int(np.prod(field.row_shape, dtype=np.int64))
+    if elems <= _TAKE_MAX_ROW_ELEMS:
+        return (elems,)
+    return (-(-elems // _LANES), _LANES)
+
+
+def _to_slab_rows(batch, slab_row):
+    """``(n,) + row_shape`` -> ``(n,) + slab_row``: the inverse of
+    ``_reshape_rows_minor`` (through ``[rest..., n]``, so that no padded
+    row-major copy of the batch has to exist), then the lanes' padding."""
+    n = batch.shape[0]
+    if batch.ndim > 2:
+        batch = jnp.moveaxis(batch, 0, -1).reshape(-1, n).T
+    if len(slab_row) == 2:
+        pad = slab_row[0] * _LANES - batch.shape[1]
+        if pad:
+            batch = jnp.pad(batch, ((0, 0), (0, pad)))
+        batch = batch.reshape((n,) + slab_row)
+    return batch
+
+
+def _from_slab_rows(rows, row_shape):
+    """``(n,) + slab_row`` -> ``(n,) + row_shape``, the way the transfer
+    plane's unpack gets there from flat bytes."""
+    n = rows.shape[0]
+    if rows.ndim == 3:
+        elems = int(np.prod(row_shape, dtype=np.int64))
+        rows = rows.reshape(n, -1)[:, :elems]
+    if rows.shape[1:] == row_shape:
+        return rows
+    return _reshape_rows_minor(rows, (n,) + row_shape)
+
+
+def _copy_rows(slab, slots):
+    """``slab[slots]`` for a slab of wide rows, one DMA a row from HBM to
+    HBM with ``_COPIES_IN_FLIGHT`` of them in flight: the slots are
+    prefetched as scalars and no byte passes through the core."""
+    # Imported where a wide field is first traced: Pallas takes 0.9 s to
+    # import, a third of ``import petastorm_tpu.jax``, and a loader with no
+    # tier or with narrow fields never gets here.
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = slots.shape[0]
+    in_flight = min(_COPIES_IN_FLIGHT, n)
+    # ``take`` clamps a slot that is out of range; a DMA would follow it
+    slots = jnp.clip(slots, 0, slab.shape[0] - 1)
+
+    def kernel(slots_ref, slab_ref, out_ref, sems):
+        def copy(i):
+            return pltpu.make_async_copy(slab_ref.at[slots_ref[i]],
+                                         out_ref.at[i],
+                                         sems.at[i % in_flight])
+
+        for i in range(in_flight):
+            copy(i).start()
+
+        def wait_and_refill(i, carry):
+            copy(i).wait()
+
+            @pl.when(i + in_flight < n)
+            def _():
+                copy(i + in_flight).start()
+            return carry
+
+        jax.lax.fori_loop(0, n, wait_and_refill, 0)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((n,) + slab.shape[1:], slab.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((in_flight,))]),
+        interpret=_auto_interpret(),
+        name='pt_residency_copy_rows',
+    )(slots, slab)
+
+
 class ResidencyTier(object):
     """Budget-bounded device-resident slab of wire-dtype rows with batch LRU.
 
-    Rows live in per-field slabs of shape ``(capacity,) + row_shape`` in
-    the wire dtype.  Each admitted batch occupies a contiguous slot range
+    Rows live in per-field slabs in the wire dtype, each row where one
+    copy can reach it (module docstring): a field of up to
+    ``_TAKE_MAX_ROW_ELEMS`` elements a row as ``(capacity, elems)`` (a 1-D
+    field as ``(capacity,)``), a wider one as ``(capacity, ceil(elems /
+    128), 128)``.  Each admitted batch occupies a contiguous slot range
     tracked as one LRU entry; ``slot_of_row`` maps dataset row id →
     slab slot (-1 when not resident).  Admission writes through a jitted
     ``dynamic_update_slice_in_dim`` with the slab donated off-CPU, so an
     "eviction" is just the LRU entry releasing its slot range — the bytes
     are overwritten in place by the next donated admission.
 
-    Warm serving is one jitted gather: slice ``batch_size`` row ids out
+    Warm serving is one jitted program: slice ``batch_size`` row ids out
     of the epoch permutation, map them through the device copy of
-    ``slot_of_row``, ``take`` from each slab, and widen — no host work at
-    all.
+    ``slot_of_row``, read those rows of each slab (``take`` for the narrow
+    fields, one DMA a row for the wide ones), restore ``row_shape`` and
+    widen — no host work at all, and no device work that grows with
+    ``capacity``.
     """
 
     def __init__(self, plan, n_rows, batch_size, budget_bytes, counters,
@@ -308,7 +442,13 @@ class ResidencyTier(object):
         self._n = int(n_rows)
         self._bs = int(batch_size)
         self._device = device
-        row_bytes = max(1, plan.wire_row_nbytes)
+        self._slab_rows = {name: _slab_row_shape(f)
+                           for name, f in plan.fields.items()}
+        # what a row takes in the slabs: its wire bytes, and the lanes'
+        # padding of a wide row whose elements are no multiple of 128
+        row_bytes = self._row_bytes = max(1, sum(
+            int(np.prod(self._slab_rows[name], dtype=np.int64))
+            * f.wire.itemsize for name, f in plan.fields.items()))
         if budget_bytes is None:
             self._capacity = self._n
         else:
@@ -316,6 +456,8 @@ class ResidencyTier(object):
         self._c = counters
         counters.budget.set(int(budget_bytes) if budget_bytes is not None
                             else self._capacity * row_bytes)
+        counters.rowcopy_fields.set(
+            sum(len(shape) == 2 for shape in self._slab_rows.values()))
         self._slabs = None
         self._entries = OrderedDict()   # seq -> (slot, rows)
         self._seq = 0
@@ -324,7 +466,7 @@ class ResidencyTier(object):
         self._slot_of_row = np.full(self._n, -1, dtype=np.int32)
         self._slot_map_dev = None
         self._write_fns = {}
-        self._gather_fn = None
+        self._gather_fns = {}           # rows of the batch -> jitted program
         self._dropped = False
         self._donate = donation_supported()
 
@@ -359,7 +501,7 @@ class ResidencyTier(object):
         if self._slabs is not None:
             return
         def _zeros():
-            return {name: jnp.zeros((self._capacity,) + f.row_shape,
+            return {name: jnp.zeros((self._capacity,) + self._slab_rows[name],
                                     dtype=jnp.dtype(f.wire))
                     for name, f in self._plan.fields.items()}
         if self._device is not None:
@@ -457,17 +599,26 @@ class ResidencyTier(object):
             'residency', outcome, 'residency_budget', _inputs, slot=slot)
         return outcome
 
+    def _update_program(self):
+        """``(slabs, batch, start) -> slabs`` with the batch's rows written
+        from ``start`` on, jitted, the slabs donated where that recycles."""
+        slab_rows = self._slab_rows
+
+        @jax.named_scope('pt/residency_update')
+        def pt_residency_update(slabs, batch, start):
+            return {name: jax.lax.dynamic_update_slice_in_dim(
+                        slabs[name],
+                        _to_slab_rows(batch[name], slab_rows[name]),
+                        start, axis=0)
+                    for name in slabs}
+
+        return jax.jit(pt_residency_update,
+                       donate_argnums=(0,) if self._donate else ())
+
     def _write(self, slot, rows, wire_dev):
         fn = self._write_fns.get(rows)
         if fn is None:
-            @jax.named_scope('pt/residency_update')
-            def pt_residency_update(slabs, batch, start):
-                return {name: jax.lax.dynamic_update_slice_in_dim(
-                            slabs[name], batch[name], start, axis=0)
-                        for name in slabs}
-            donate = (0,) if self._donate else ()
-            fn = jax.jit(pt_residency_update, donate_argnums=donate)
-            self._write_fns[rows] = fn
+            fn = self._write_fns[rows] = self._update_program()
         self._slabs = fn(self._slabs, wire_dev, slot)
 
     def backfill(self, cache, plan):
@@ -502,39 +653,44 @@ class ResidencyTier(object):
             self._slot_map_dev = jnp.asarray(self._slot_of_row)
         return self._slot_map_dev
 
+    def _gather_program(self, rows):
+        """``(slabs, slot_map, order, start) -> batch`` of ``rows`` rows,
+        jitted.  Named for the device trace: the program is
+        ``jit_pt_residency_gather`` in ``XLA Modules`` and its operations
+        carry the two scopes."""
+        if rows in self._gather_fns:
+            return self._gather_fns[rows]
+        fields = self._plan.fields
+
+        def pt_residency_gather(slabs, slot_map, order, start):
+            with jax.named_scope('pt/residency_gather'):
+                idx = jax.lax.dynamic_slice_in_dim(order, start, rows)
+                slots = jnp.take(slot_map, idx)
+                batch = {}
+                for name, slab in slabs.items():
+                    read = (_copy_rows(slab, slots) if slab.ndim == 3
+                            else jnp.take(slab, slots, axis=0))
+                    batch[name] = _from_slab_rows(read,
+                                                  fields[name].row_shape)
+            with jax.named_scope('pt/residency_widen'):
+                return {name: batch[name].astype(jnp.dtype(fields[name].out))
+                        for name in batch}
+
+        fn = self._gather_fns[rows] = jax.jit(pt_residency_gather)
+        return fn
+
     def gather(self, order_dev, start):
-        """One warm full batch: jitted slice→map→take→widen, zero host work."""
-        if self._gather_fn is None:
-            bs = self._bs
-            outs = {name: jnp.dtype(f.out)
-                    for name, f in self._plan.fields.items()}
-
-            # Named for the device trace: the program is
-            # ``jit_pt_residency_gather`` in ``XLA Modules`` and its
-            # operations carry the two scopes, where an anonymous ``copy``
-            # stood before.
-            def pt_residency_gather(slabs, slot_map, order, start):
-                with jax.named_scope('pt/residency_gather'):
-                    idx = jax.lax.dynamic_slice_in_dim(order, start, bs)
-                    slots = jnp.take(slot_map, idx)
-                    rows = {name: jnp.take(slabs[name], slots, axis=0)
-                            for name in slabs}
-                with jax.named_scope('pt/residency_widen'):
-                    return {name: rows[name].astype(outs[name])
-                            for name in rows}
-
-            self._gather_fn = jax.jit(pt_residency_gather)
+        """One warm full batch: jitted slice→map→read→widen, zero host work."""
         self._c.hits.inc()
-        return self._gather_fn(self._slabs, self._slot_map(), order_dev, start)
+        return self._gather_program(self._bs)(
+            self._slabs, self._slot_map(), order_dev, start)
 
     def gather_tail(self, order_dev, start):
-        """Ragged final batch (``drop_last=False``): unjitted, once per epoch."""
-        idx = order_dev[start:]
-        slots = jnp.take(self._slot_map(), idx)
+        """Ragged final batch (``drop_last=False``): the same program at the
+        tail's length, once per epoch."""
         self._c.hits.inc()
-        return {name: jnp.take(self._slabs[name], slots,
-                               axis=0).astype(jnp.dtype(f.out))
-                for name, f in self._plan.fields.items()}
+        return self._gather_program(self._n - int(start))(
+            self._slabs, self._slot_map(), order_dev, start)
 
     # -- teardown -----------------------------------------------------------
 

@@ -139,6 +139,86 @@ def test_tier_admit_gather_roundtrip():
                                   tree['id'][onp[4:8]].astype(np.int32))
 
 
+# How a row lies in its slab follows its elements (ISSUE 27): up to
+# ``_TAKE_MAX_ROW_ELEMS`` flat and read with ``take``, beyond it as
+# ``(ceil(elems / 128), 128)`` and read one copy a row.  Every case is served
+# beside a 1-D field, and must deliver what streaming delivers.
+ROW_CASES = {
+    'wide_uint8': (np.uint8, (96, 128, 3), 1),          # 36,864 = 288 x 128
+    'wide_uint8_ragged_lanes': (np.uint8, (181, 61, 3), 1),   # 33,123
+    'wide_float32_as_bf16': (np.float32, (129, 256), 1),      # 33,024
+    'widest_narrow_uint8': (np.uint8, (256, 128), 0),   # 32,768: still take
+    'narrow_uint8_odd_bytes': (np.uint8, (7, 5, 3), 0),       # 105 bytes
+    'narrow_float32_as_bf16': (np.float32, (13,), 0),
+    'narrow_int32': (np.int32, (26,), 0),
+    'one_d_only': (np.int64, (), 0),
+}
+
+
+def _case_tree(case, n):
+    dtype, row_shape, _ = ROW_CASES[case]
+    rng = np.random.default_rng(n)
+    if np.dtype(dtype).kind == 'f':
+        field = rng.standard_normal((n,) + row_shape).astype(dtype)
+    else:
+        field = rng.integers(0, 200, (n,) + row_shape).astype(dtype)
+    return {'field': field, 'id': np.arange(n, dtype=np.int64)}
+
+
+def _streamed(plan, tree, idx):
+    """What the streamed path delivers for these rows: widen(narrow(rows))."""
+    wire = plan.narrow({k: v[idx] for k, v in tree.items()})
+    return {k: np.asarray(v) for k, v in
+            plan.widen({k: jax.device_put(v) for k, v in wire.items()}).items()}
+
+
+def _assert_delivery(got, want):
+    _assert_same([{k: np.asarray(v) for k, v in got.items()}], [want])
+
+
+@pytest.mark.parametrize('case', sorted(ROW_CASES))
+def test_warm_gather_is_bit_identical_to_streamed(case):
+    n, bs = 22, 4                      # five full batches and a tail of two
+    tree = _case_tree(case, n)
+    plan = residency.wire_plan(tree, 'auto')
+    c = _counters()
+    tier = residency.ResidencyTier(plan, n, bs, None, c)
+    assert int(c.rowcopy_fields.value) == ROW_CASES[case][2]
+    for start in range(0, n, bs):
+        assert _admit(tier, plan, tree, start, min(bs, n - start)) == 'admitted'
+    assert tier.serving_ok()
+    wide = tier._slabs['field'].ndim == 3
+    assert wide == bool(ROW_CASES[case][2])
+    assert tier._slabs['field'].shape[0] == n and tier._slabs['id'].shape == (n,)
+    order = residency.epoch_permutation(3, 1, n)
+    onp = np.asarray(order)
+    for start in (0, 8, 16):
+        _assert_delivery(tier.gather(order, start),
+                         _streamed(plan, tree, onp[start:start + bs]))
+    _assert_delivery(tier.gather_tail(order, 20), _streamed(plan, tree, onp[20:]))
+    assert int(c.hits.value) == 4
+
+
+@pytest.mark.parametrize('case', sorted(ROW_CASES))
+def test_gather_after_eviction_and_readmission_into_a_freed_range(case):
+    n, bs = 12, 4
+    tree = _case_tree(case, n)
+    plan = residency.wire_plan(tree, 'auto')
+    # what a row takes in the slabs (a wide row's lanes are padded)
+    stored = residency.ResidencyTier(plan, n, bs, None, _counters())._row_bytes
+    assert plan.wire_row_nbytes <= stored < plan.wire_row_nbytes + 128 * 4
+    tier = residency.ResidencyTier(plan, n, bs, 8 * stored, _counters())
+    assert tier.capacity_rows == 8
+    assert _admit(tier, plan, tree, 0, 4) == 'admitted'
+    assert _admit(tier, plan, tree, 4, 4) == 'admitted'
+    assert _admit(tier, plan, tree, 8, 4) == 'evicted'     # into rows 0-3's range
+    assert sorted(tier._slot_of_row[8:12]) == [0, 1, 2, 3]
+    order = jnp.asarray([9, 4, 11, 7, 8, 6, 10, 5], jnp.int32)
+    for start in (0, 4):
+        _assert_delivery(tier.gather(order, start),
+                         _streamed(plan, tree, np.asarray(order)[start:start + 4]))
+
+
 def test_tier_lru_eviction_under_tight_budget():
     tree = _tree()
     plan = residency.wire_plan(tree, 'auto')
@@ -236,12 +316,50 @@ def test_resident_epochs_bit_identical_to_streamed(dataset, monkeypatch):
     assert stats['admitted'] == 4 and stats['evictions'] == 0
 
 
+def test_wide_rows_resident_epochs_bit_identical_to_streamed(
+        tmp_path, monkeypatch):
+    """The same contract over a field the tier serves one copy a row, with
+    the ragged tail delivered (``drop_last=False``)."""
+    from petastorm_tpu.codecs import NdarrayCodec
+    from petastorm_tpu.etl.dataset_metadata import DatasetWriter
+    from petastorm_tpu.unischema import Unischema, UnischemaField
+    schema = Unischema('Wide', [
+        UnischemaField('id', np.int64, (), None, False),
+        UnischemaField('image', np.uint8, (96, 128, 3), NdarrayCodec(), False)])
+    url = 'file://' + str(tmp_path / 'wide')
+    rng = np.random.default_rng(5)
+    with DatasetWriter(url, schema, rows_per_rowgroup=7) as writer:
+        writer.write_many([
+            {'id': np.int64(i),
+             'image': rng.integers(0, 255, (96, 128, 3), dtype=np.uint8)}
+            for i in range(21)])
+
+    def pull(kill):
+        if kill:
+            monkeypatch.setenv(residency.KILL_SWITCH, '1')
+        else:
+            monkeypatch.delenv(residency.KILL_SWITCH, raising=False)
+        ldr = ResidentDataLoader(
+            make_reader(url, reader_pool_type='dummy', num_epochs=1,
+                        shuffle_row_groups=False),
+            batch_size=8, num_epochs=3, seed=7, drop_last=False)
+        return _pull_all(ldr), ldr.residency_stats
+
+    resident, stats = pull(kill=False)
+    killed, killed_stats = pull(kill=True)
+    _assert_same(resident, killed)
+    assert [len(b['id']) for b in resident] == [8, 8, 5] * 3
+    assert stats['host_batches'] == 3 and stats['hits'] == 6
+    assert stats['rowcopy_fields'] == 1 and killed_stats['rowcopy_fields'] == 0
+
+
 def test_kill_switch_counters_keep_full_shape(dataset, monkeypatch):
     ldr = _loader(dataset, monkeypatch, kill=True, num_epochs=2, seed=1)
     _pull_all(ldr)
     stats = ldr.residency_stats
     assert stats == {'admitted': 0, 'evictions': 0, 'hits': 0,
-                     'bypass': 0, 'thrash': 0, 'host_batches': 8}
+                     'bypass': 0, 'thrash': 0, 'host_batches': 8,
+                     'rowcopy_fields': 0}
     # The rollup carries every counter even with the plane off.
     counters = ldr.metrics.snapshot()['counters']
     for name in residency.COUNTER_NAMES:

@@ -166,7 +166,7 @@ def test_jitted_programs_carry_their_names_and_scopes():
     wire = {k: jax.device_put(v) for k, v in plan.narrow(host).items()}
     assert tier.admit(np.arange(8), wire) == 'admitted'
     tier.gather(jnp.arange(8), 0)
-    gather = tier._gather_fn.lower(
+    gather = tier._gather_program(4).lower(
         tier._slabs, tier._slot_map(), jnp.arange(8), 0).as_text(debug_info=True)
     assert 'jit_pt_residency_gather' in gather
     assert 'pt/residency_gather' in gather and 'pt/residency_widen' in gather

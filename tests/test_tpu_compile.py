@@ -156,3 +156,65 @@ def test_device_inmem_gather_compiles_at_epoch_size(one_chip, tmp_path):
     # the epoch cache plus one batch, far inside one chip's 16 GB
     assert (memory.argument_size_in_bytes + memory.output_size_in_bytes
             + memory.temp_size_in_bytes) < 2 << 30
+
+
+#: The tier's fields beside which it is compiled: ImageNet's row alone, and
+#: with a narrow multi-byte field (DLRM's 13 dense float32, riding as bf16).
+TIER_FIELDS = {'imagenet': {'image': (np.uint8, IMAGE), 'noun_id': (np.int64, ())},
+               'imagenet_and_dense': {'image': (np.uint8, IMAGE),
+                                      'noun_id': (np.int64, ()),
+                                      'dense': (np.float32, (13,))}}
+
+
+@pytest.mark.parametrize('program', ['gather', 'update'])
+@pytest.mark.parametrize('fields,capacity', [('imagenet', 9984),
+                                             ('imagenet', 19968),
+                                             ('imagenet_and_dense', 9984)])
+def test_residency_programs_need_no_scratch_that_grows_with_the_tier(
+        one_chip, monkeypatch, fields, capacity, program):
+    """The resident tier's warm gather and its admission, over a tier of
+    ImageNet rows as large as the benchmark's and twice that.
+
+    Stored as ``u8[capacity, 224, 224, 3]`` the slab took the device's NHWC
+    layout, batch axis in the lanes, and the gather re-laid all of it into a
+    temporary of its size every step (1.50 GB at 9,984 rows, 3.0 GB at
+    19,968; 7.4 ms a step on the v5e).  Stored flat, ``take`` still sliced the
+    whole slab by columns (1.2 GB)."""
+    import time
+
+    from petastorm_tpu.jax import residency
+    from petastorm_tpu.telemetry import MetricsRegistry
+    # as it lowers on the chip: the row copies through Mosaic, not interpreted
+    monkeypatch.setattr(residency, '_auto_interpret', lambda: False)
+    host = {name: np.zeros((BATCH,) + shape, dtype)
+            for name, (dtype, shape) in TIER_FIELDS[fields].items()}
+    plan = residency.wire_plan(host, 'auto')
+    tier = residency.ResidencyTier(
+        plan, capacity, BATCH, None,
+        residency.ensure_counters(MetricsRegistry('compile')))
+    tier._donate = True                # what the tier reads on the chip
+    assert tier._slab_rows['image'] == (1176, 128)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    slabs = {name: on_chip((capacity,) + tier._slab_rows[name], f.wire)
+             for name, f in plan.fields.items()}
+    start = on_chip((), jnp.int32)
+    t0 = time.monotonic()
+    if program == 'gather':
+        compiled = tier._gather_program(BATCH).lower(
+            slabs, on_chip((capacity,), jnp.int32),
+            on_chip((capacity,), jnp.int32), start).compile()
+        assert 'tpu_custom_call' in compiled.as_text()
+    else:
+        batch = {name: on_chip((BATCH,) + f.row_shape, f.wire)
+                 for name, f in plan.fields.items()}
+        compiled = tier._update_program().lower(slabs, batch, start).compile()
+    assert time.monotonic() - t0 < 60
+    memory = compiled.memory_analysis()
+    batch_bytes = BATCH * plan.wire_row_nbytes
+    assert memory.temp_size_in_bytes <= 4 * batch_bytes
+    if program == 'gather':
+        assert memory.output_size_in_bytes >= batch_bytes
+    else:                               # the donated slabs are the output
+        assert memory.alias_size_in_bytes >= capacity * plan.wire_row_nbytes
