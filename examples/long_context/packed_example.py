@@ -110,7 +110,7 @@ def train(dataset_url, steps=20, rows_per_batch=4, lr=3e-3):
         # PackedDataLoader = pack_stream + the DataLoader's double-buffered
         # device delivery (same prefetch/sharding machinery as images).
         loader = PackedDataLoader(reader, 'tokens', max_len=MAX_LEN,
-                                  rows_per_batch=rows_per_batch, prefetch=2,
+                                  batch_size=rows_per_batch, prefetch=2,
                                   transform_fn=count_tokens)
         for batch in loader:
             params, opt_state, loss = step(

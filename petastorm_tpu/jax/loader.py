@@ -2312,27 +2312,56 @@ class PackedDataLoader(DataLoader):
 
     The loader-layer home of ``petastorm_tpu.jax.packing.pack_stream``:
     rows stream out of the reader, their ``tokens_field`` column is packed
-    into ``(rows_per_batch, max_len)`` batches with ``segment_ids`` /
+    into ``(batch_size, max_len)`` batches with ``segment_ids`` /
     ``positions``, and batches ride the same double-buffered
     ``device_put`` path as :class:`DataLoader` (``prefetch`` /
     ``device`` / ``sharding`` / ``transform_fn`` all apply)::
 
-        with make_reader(url, schema_fields=['tokens']) as reader:
+        with make_reader(url, schema_fields=['doc_id', 'tokens']) as reader:
             loader = PackedDataLoader(reader, 'tokens', max_len=4096,
-                                      rows_per_batch=8, sharding=sharding)
+                                      batch_size=8, id_field='doc_id',
+                                      sharding=sharding)
             for batch in loader:
                 step(batch['tokens'], batch['segment_ids'],
                      batch['positions'])
+
+    ``batch_size`` is the number of packed rows a batch, as every other
+    loader spells it; ``rows_per_batch`` is its older synonym (give one, or
+    both alike).  ``id_field`` names a scalar integer column that is carried
+    through the packer: the batch gains ``doc_ids`` ``[batch_size, max_len]``,
+    the id of the document each token belongs to
+    (``packing.NO_DOCUMENT`` on padding; ``packing.document_ids(batch)``
+    lists them on the host), and ``state_dict`` keeps the ids of the
+    documents it holds back.
 
     Ordering comes from the reader (shuffle row groups there);
     ``shuffling_queue_capacity`` is rejected — reordering between packing
     and delivery would break nothing but adds no mixing the reader can't
     already provide.  With ``drop_last=False`` the final short batch is
     padded with all-padding rows (static shapes), not ragged.
+
+    **At an epoch's end** the open rows are closed as they are, so no
+    document of one epoch waits in an open row while the next epoch's are
+    delivered: after any number of batches every document has been
+    delivered ``n`` or ``n + 1`` times.  The loader counts the reader's rows
+    against ``reader.num_local_rows()``; under a predicate, an NGram or a
+    row-dropping transform that count is only an upper bound, and open rows
+    are then closed at the end of the stream alone.  The rows closed early
+    cost padding once an epoch.
+
+    Packing is the stage ``pack`` (``pack_s``, histogram ``pack``, profiler
+    span ``ptp/pack`` inside ``pt/host_batch``): one sample each time the
+    packer emits (one batch in the steady state; the rows closed at an epoch's
+    end come out as several), the packer's time over the documents since the
+    emission before.
+    Counters ``packed_rows``, ``packed_documents``, ``packed_tokens``,
+    ``packed_pad_tokens`` and the gauge ``pack_open_rows`` say what was
+    packed.
     """
 
-    def __init__(self, reader, tokens_field, max_len, rows_per_batch,
-                 pad_id=0, open_rows=32, **loader_kwargs):
+    def __init__(self, reader, tokens_field, max_len, rows_per_batch=None,
+                 pad_id=0, open_rows=32, id_field=None, batch_size=None,
+                 **loader_kwargs):
         if loader_kwargs.get('shuffling_queue_capacity'):
             raise ValueError('PackedDataLoader does not support '
                              'shuffling_queue_capacity; shuffle in the '
@@ -2341,12 +2370,53 @@ class PackedDataLoader(DataLoader):
             raise ValueError('PackedDataLoader needs a ROW reader '
                              '(make_reader): batch readers yield columnar '
                              'chunks, not per-document sequences')
-        super().__init__(reader, batch_size=rows_per_batch, **loader_kwargs)
+        if batch_size is None:
+            batch_size = rows_per_batch
+        if batch_size is None:
+            raise TypeError('PackedDataLoader needs batch_size (packed rows '
+                            'a batch)')
+        if rows_per_batch is not None and rows_per_batch != batch_size:
+            raise ValueError('batch_size=%r and its synonym rows_per_batch=%r '
+                             'differ' % (batch_size, rows_per_batch))
+        super().__init__(reader, batch_size=batch_size, **loader_kwargs)
         self._tokens_field = tokens_field
+        self._id_field = id_field
         self._max_len = int(max_len)
         self._pad_id = pad_id
         self._open_rows = int(open_rows)
         self._packer = None
+        self._epoch_row = 0
+        self._stage.instruments('pack')
+        self._m_packed = {name: self.metrics.counter('packed_' + name)
+                          for name in ('rows', 'documents', 'tokens',
+                                       'pad_tokens')}
+        self._g_open_rows = self.metrics.gauge('pack_open_rows')
+
+    def _rows_an_epoch(self):
+        """Rows the reader delivers an epoch, where that is exact and the
+        reader goes on to another epoch; else ``None``."""
+        reader = self.reader
+        if getattr(reader, 'num_epochs', 1) == 1 \
+                or not hasattr(reader, 'num_local_rows') \
+                or getattr(reader, 'predicate', None) is not None \
+                or getattr(reader, 'ngram', None) is not None \
+                or getattr(reader, 'transform_may_change_row_count', False):
+            return None
+        return reader.num_local_rows() or None
+
+    def _ready_counted(self):
+        """Hands out the staged batches, counting what each holds."""
+        from petastorm_tpu.jax.packing import document_starts
+        while self._packed_ready:
+            batch = self._packed_ready.pop(0)
+            segment_ids = batch['segment_ids']
+            tokens = int(np.count_nonzero(segment_ids))
+            self._m_packed['rows'].inc(len(segment_ids))
+            self._m_packed['documents'].inc(
+                int(np.count_nonzero(document_starts(segment_ids))))
+            self._m_packed['tokens'].inc(tokens)
+            self._m_packed['pad_tokens'].inc(segment_ids.size - tokens)
+            yield batch
 
     def _host_batches(self):
         from petastorm_tpu.jax.packing import StreamPacker
@@ -2354,27 +2424,43 @@ class PackedDataLoader(DataLoader):
         packer = StreamPacker(self._max_len, self.batch_size,
                               pad_id=self._pad_id, open_rows=self._open_rows,
                               drop_last=self._drop_last)
-        if self._resume_state and self._resume_state.get('packer'):
-            packer.load_state_dict(self._resume_state['packer'])
+        resume = self._resume_state or {}
+        if resume.get('packer'):
+            packer.load_state_dict(resume['packer'])
         self._packer = packer
+        self._epoch_row = int(resume.get('packed_epoch_row', 0))
+        rows_an_epoch = self._rows_an_epoch()
         # Ready-but-unyielded batches stage here so a state_dict() taken
         # between two yields of the same add() loses nothing.
-        self._packed_ready = list((self._resume_state or {})
-                                  .get('packed_ready', []))
+        self._packed_ready = list(resume.get('packed_ready', []))
+        carried = 0.0     # packer seconds of the documents not yet emitted
         for row in self._row_source():
-            value = (row[self._tokens_field] if isinstance(row, dict)
-                     else getattr(row, self._tokens_field))
-            self._packed_ready.extend(packer.add(value))
-            while self._packed_ready:
-                yield self._packed_ready.pop(0)
-        self._packed_ready.extend(packer.flush())
-        while self._packed_ready:
-            yield self._packed_ready.pop(0)
+            with self._stage('pack', span='ptp/pack') as pack:
+                pack.carried = carried
+                ready = packer.add(row[self._tokens_field],
+                                   None if self._id_field is None
+                                   else row[self._id_field])
+                self._epoch_row += 1
+                if self._epoch_row == rows_an_epoch:
+                    ready += packer.close_open()
+                    self._epoch_row = 0
+                pack.keep = bool(ready)
+            carried = 0.0 if ready else carried + pack.seconds
+            self._g_open_rows.set(packer.open_rows)
+            self._packed_ready.extend(ready)
+            yield from self._ready_counted()
+        with self._stage('pack', span='ptp/pack') as pack:
+            pack.carried = carried
+            self._packed_ready.extend(packer.flush())
+            pack.keep = bool(self._packed_ready)
+        self._g_open_rows.set(0)
+        yield from self._ready_counted()
 
     def state_dict(self):
         """Exact packed snapshot: DataLoader state + the packer residue
-        (open rows, closed rows, sticky dtype) + ready-but-unyielded
-        batches.
+        (open rows, closed rows, their documents' ids, sticky dtype) +
+        ready-but-unyielded batches + how far into its epoch the reader
+        was.
 
         The pump stays paused across BOTH reads (the base snapshot and
         the packer residue): ``_pump_paused`` counts, so the nested
@@ -2388,9 +2474,11 @@ class PackedDataLoader(DataLoader):
             if self._packer is not None:   # iteration started
                 state['packer'] = self._packer.state_dict()
                 state['packed_ready'] = list(self._packed_ready)
+                state['packed_epoch_row'] = self._epoch_row
             else:                          # restored but not yet iterated
                 state['packer'] = rs.get('packer')
                 state['packed_ready'] = list(rs.get('packed_ready', []))
+                state['packed_epoch_row'] = rs.get('packed_epoch_row', 0)
             return state
 
 

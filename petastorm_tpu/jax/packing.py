@@ -41,15 +41,21 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ['pack_sequences', 'pack_stream', 'StreamPacker', 'segment_mask',
-           'packed_attention', 'next_token_targets']
+           'packed_attention', 'next_token_targets', 'document_starts',
+           'document_ids']
+
+#: What ``doc_ids`` holds where ``segment_ids`` is 0 (padding: no document).
+NO_DOCUMENT = -1
 
 
-def _emit(rows, max_len, dtype, pad_id):
+def _emit(rows, max_len, dtype, pad_id, ids=None):
     """Render packed rows (lists of sequences) to the batch dict.
 
     ``dtype=None`` promotes over the actual sequences in this batch (the
     streaming packer can't know future dtypes, so each batch is exactly
     wide enough for its own rows — never a silent narrowing cast).
+    ``ids`` (one list of document ids a row, beside ``rows``) adds the leaf
+    ``doc_ids``: every token's document id, ``NO_DOCUMENT`` on padding.
     """
     n = len(rows)
     if dtype is None:
@@ -57,6 +63,10 @@ def _emit(rows, max_len, dtype, pad_id):
     tokens = np.full((n, max_len), pad_id, dtype)
     segment_ids = np.zeros((n, max_len), np.int32)
     positions = np.zeros((n, max_len), np.int32)
+    doc_ids = None
+    if ids is not None:
+        doc_ids = np.full((n, max_len), NO_DOCUMENT, np.result_type(
+            np.int32, np.asarray([i for row in ids for i in row])))
     for r, seqs in enumerate(rows):
         off = 0
         for s, seq in enumerate(seqs):
@@ -64,9 +74,14 @@ def _emit(rows, max_len, dtype, pad_id):
             tokens[r, off:off + L] = seq
             segment_ids[r, off:off + L] = s + 1
             positions[r, off:off + L] = np.arange(L)
+            if doc_ids is not None:
+                doc_ids[r, off:off + L] = ids[r][s]
             off += L
-    return {'tokens': tokens, 'segment_ids': segment_ids,
-            'positions': positions}
+    batch = {'tokens': tokens, 'segment_ids': segment_ids,
+             'positions': positions}
+    if doc_ids is not None:
+        batch['doc_ids'] = doc_ids
+    return batch
 
 
 def pack_sequences(sequences, max_len, pad_id=0):
@@ -139,6 +154,15 @@ class StreamPacker(object):
     snapshot the residue — open rows, closed rows, sticky dtype — for
     exact mid-epoch checkpoint/resume
     (``petastorm_tpu.jax.PackedDataLoader.state_dict``).
+
+    ``add(seq, doc_id)`` carries the document's id through: every batch
+    then holds ``doc_ids`` ``[rows, max_len]``, the id of the document each
+    token belongs to (``NO_DOCUMENT`` on padding).  The layout is that of
+    ``segment_ids``, so one leaf of a fixed shape says which documents a
+    batch holds and where each lies, with no bound on documents a row that a
+    ``[rows, max_docs]`` table would need (a row of ``max_len`` tokens can
+    hold ``max_len`` documents) and no second leaf of counts; see
+    :func:`document_ids`.  A packer is fed ids for every sequence or none.
     """
 
     def __init__(self, max_len, rows_per_batch, pad_id=0, open_rows=32,
@@ -150,27 +174,42 @@ class StreamPacker(object):
         self._pad_id = pad_id
         self._open_rows = open_rows
         self._drop_last = drop_last
-        self._open = []      # list of (room, [seqs])
-        self._closed = []
+        self._open = []      # list of [room, seqs, ids]
+        self._closed = []    # list of (seqs, ids)
         self._dtype = None   # promoted over everything seen; never narrows
+        self._with_ids = None
+
+    @property
+    def open_rows(self):
+        """Rows that still take documents (at most ``open_rows``)."""
+        return len(self._open)
+
+    def _render(self, closed):
+        seqs = [row[0] for row in closed]
+        ids = [row[1] for row in closed] if self._with_ids else None
+        return _emit(seqs, self._max_len, self._dtype, self._pad_id, ids)
 
     def _close_fullest(self):
         i = min(range(len(self._open)), key=lambda j: self._open[j][0])
-        self._closed.append(self._open.pop(i)[1])
+        self._closed.append(tuple(self._open.pop(i)[1:]))
 
     def _ready_batches(self):
         out = []
         while len(self._closed) >= self._rows_per_batch:
-            out.append(_emit(self._closed[:self._rows_per_batch],
-                             self._max_len, self._dtype, self._pad_id))
+            out.append(self._render(self._closed[:self._rows_per_batch]))
             self._closed = self._closed[self._rows_per_batch:]
         return out
 
-    def add(self, seq):
+    def add(self, seq, doc_id=None):
         """Fold one sequence in; returns the batches that became ready."""
         seq = np.asarray(seq)
         if seq.ndim != 1:
             raise ValueError('expected 1-D sequences, got %r' % (seq.shape,))
+        if self._with_ids is None:
+            self._with_ids = doc_id is not None
+        elif self._with_ids != (doc_id is not None):
+            raise ValueError('a packer carries an id for every sequence or '
+                             'for none')
         self._dtype = (seq.dtype if self._dtype is None
                        else np.result_type(self._dtype, seq.dtype))
         max_len = self._max_len
@@ -178,40 +217,47 @@ class StreamPacker(object):
             raise ValueError('sequence of length %d exceeds max_len=%d'
                              % (len(seq), max_len))
         if len(seq) == max_len:     # exactly-full row: close it now
-            self._closed.append([seq])
+            self._closed.append(([seq], [doc_id]))
         else:
-            fits = [i for i, (room, _) in enumerate(self._open)
-                    if room >= len(seq)]
+            fits = [i for i, row in enumerate(self._open)
+                    if row[0] >= len(seq)]
             if fits:
                 i = min(fits, key=lambda j: self._open[j][0])   # best fit
-                room, seqs = self._open[i]
-                seqs.append(seq)
-                self._open[i] = (room - len(seq), seqs)
-                if self._open[i][0] == 0:
-                    self._closed.append(self._open.pop(i)[1])
+                row = self._open[i]
+                row[0] -= len(seq)
+                row[1].append(seq)
+                row[2].append(doc_id)
+                if row[0] == 0:
+                    self._closed.append(tuple(self._open.pop(i)[1:]))
             else:
-                self._open.append((max_len - len(seq), [seq]))
+                self._open.append([max_len - len(seq), [seq], [doc_id]])
                 if len(self._open) > self._open_rows:
                     self._close_fullest()
+        return self._ready_batches()
+
+    def close_open(self):
+        """Close every open row as it is, fullest first; returns the batches
+        that became ready.  What a loader calls at the end of an epoch, so
+        that no document waits in an open row while the next epoch's are
+        delivered (:class:`petastorm_tpu.jax.PackedDataLoader`)."""
+        self._closed.extend(
+            tuple(row[1:]) for row in sorted(self._open, key=lambda e: e[0]))
+        self._open = []
         return self._ready_batches()
 
     def flush(self):
         """Drain open rows; returns the final batches (tail short-padded
         to full shape unless ``drop_last``)."""
-        self._closed.extend(
-            seqs for _, seqs in sorted(self._open, key=lambda e: e[0]))
-        self._open = []
-        out = self._ready_batches()
+        out = self.close_open()
         if self._closed and not self._drop_last:
             pad_rows = self._rows_per_batch - len(self._closed)
-            batch = _emit(self._closed, self._max_len, self._dtype,
-                          self._pad_id)
+            batch = self._render(self._closed)
             if pad_rows:
+                fill = {'tokens': self._pad_id, 'doc_ids': NO_DOCUMENT}
                 batch = {k: np.concatenate(
-                    [v, np.zeros((pad_rows,) + v.shape[1:], v.dtype)])
+                    [v, np.full((pad_rows,) + v.shape[1:], fill.get(k, 0),
+                                v.dtype)])
                     for k, v in batch.items()}
-                if self._pad_id != 0:
-                    batch['tokens'][-pad_rows:] = self._pad_id
             out.append(batch)
         self._closed = []
         return out
@@ -219,17 +265,28 @@ class StreamPacker(object):
     # -- exact-checkpoint support --------------------------------------------
 
     def state_dict(self):
-        return {
+        state = {
             'open': [(room, [np.asarray(s) for s in seqs])
-                     for room, seqs in self._open],
+                     for room, seqs, _ in self._open],
             'closed': [[np.asarray(s) for s in seqs]
-                       for seqs in self._closed],
+                       for seqs, _ in self._closed],
             'dtype': None if self._dtype is None else np.dtype(self._dtype).str,
         }
+        if self._with_ids:
+            state['open_ids'] = [list(ids) for _, _, ids in self._open]
+            state['closed_ids'] = [list(ids) for _, ids in self._closed]
+        return state
 
     def load_state_dict(self, state):
-        self._open = [(room, list(seqs)) for room, seqs in state['open']]
-        self._closed = [list(seqs) for seqs in state['closed']]
+        self._with_ids = True if 'open_ids' in state else None
+        open_ids = state.get('open_ids') or [
+            [None] * len(seqs) for _, seqs in state['open']]
+        closed_ids = state.get('closed_ids') or [
+            [None] * len(seqs) for seqs in state['closed']]
+        self._open = [[room, list(seqs), list(ids)]
+                      for (room, seqs), ids in zip(state['open'], open_ids)]
+        self._closed = [(list(seqs), list(ids))
+                        for seqs, ids in zip(state['closed'], closed_ids)]
         self._dtype = (None if state['dtype'] is None
                        else np.dtype(state['dtype']))
 
@@ -300,3 +357,22 @@ def next_token_targets(tokens, segment_ids):
         xp.float32)
     return targets, weights
 
+
+def document_starts(segment_ids):
+    """Boolean ``[batch, seq]``: True on the first token of every packed
+    document (a nonzero segment id that differs from the one before it in
+    the row).  Works on numpy or jax arrays."""
+    xp = jnp if isinstance(segment_ids, jnp.ndarray) else np
+    before = xp.concatenate(
+        [xp.zeros_like(segment_ids[:, :1]), segment_ids[:, :-1]], axis=1)
+    return (segment_ids != 0) & (segment_ids != before)
+
+
+def document_ids(batch):
+    """The ids of the documents a packed batch holds, row by row and in the
+    order they lie in each row: ``doc_ids`` on the first token of every
+    document.  Host side (the result's length depends on the data); on the
+    device, ``doc_ids`` masked by :func:`document_starts` says the same in a
+    fixed shape."""
+    segment_ids = np.asarray(batch['segment_ids'])
+    return np.asarray(batch['doc_ids'])[document_starts(segment_ids)]

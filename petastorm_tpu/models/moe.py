@@ -53,15 +53,20 @@ def _a2a_bwd(axis_name, split_axis, concat_axis, _, g):
 _a2a.defvjp(_a2a_fwd, _a2a_bwd)
 
 
+def fan_in_normal(rng, shape, dtype=jnp.float32):
+    """Normal(0, 1 / fan_in) for a ``[..., fan_in, fan_out]`` stack of
+    matrices: the initialiser of every router and expert matrix here, the
+    Switch layer's and the top-k share layer's alike."""
+    return (jax.random.normal(rng, shape) / np.sqrt(shape[-2])).astype(dtype)
+
+
 def moe_init(rng, d_model, d_ff, num_experts, dtype=jnp.float32):
     """{'router': [d, E], 'w1': [E, d, f], 'w2': [E, f, d]}."""
     k1, k2, k3 = jax.random.split(rng, 3)
-    scale1 = 1.0 / np.sqrt(d_model)
-    scale2 = 1.0 / np.sqrt(d_ff)
     return {
-        'router': (jax.random.normal(k1, (d_model, num_experts)) * scale1).astype(dtype),
-        'w1': (jax.random.normal(k2, (num_experts, d_model, d_ff)) * scale1).astype(dtype),
-        'w2': (jax.random.normal(k3, (num_experts, d_ff, d_model)) * scale2).astype(dtype),
+        'router': fan_in_normal(k1, (d_model, num_experts), dtype),
+        'w1': fan_in_normal(k2, (num_experts, d_model, d_ff), dtype),
+        'w2': fan_in_normal(k3, (num_experts, d_ff, d_model), dtype),
     }
 
 
@@ -173,3 +178,129 @@ def make_expert_parallel_moe(mesh, num_experts, expert_axis='expert',
         }
 
     return fn, param_shardings, NamedSharding(mesh, token_spec)
+
+
+# ---------------------------------------------------------------------------
+# top-k experts, no capacity: one chip's share of an expert-parallel layer
+# ---------------------------------------------------------------------------
+
+def moe_share_shapes(d_model, d_ff, num_experts, experts_held):
+    """{name: shape} of the parameters of :func:`moe_share_apply`: the router
+    over ALL experts ``[d, E]`` and the SwiGLU matrices of the experts held
+    here, ``w1`` and ``w3`` ``[H, d, f]``, ``w2`` ``[H, f, d]``; each is
+    initialised by :func:`fan_in_normal`."""
+    held = len(experts_held)
+    return {'router': (d_model, num_experts),
+            'w1': (held, d_model, d_ff),
+            'w3': (held, d_model, d_ff),
+            'w2': (held, d_ff, d_model)}
+
+
+def moe_share_init(rng, d_model, d_ff, num_experts, experts_held,
+                   dtype=jnp.float32):
+    """Parameters of :func:`moe_share_apply` (:func:`moe_share_shapes`)."""
+    shapes = moe_share_shapes(d_model, d_ff, num_experts, experts_held)
+    return {name: fan_in_normal(key, shape, dtype) for (name, shape), key
+            in zip(shapes.items(), jax.random.split(rng, len(shapes)))}
+
+
+def sigmoid_top_k(logits, bias, top_k, scale=1.0, eps=1e-6):
+    """Sigmoid scores, the ``top_k`` largest of ``score + bias`` selected
+    (``bias`` steers the choice only and gets no gradient), weights
+    ``score_i / (sum of the selected scores + eps) * scale``.  Returns
+    ``(experts [T, k] int32, weights [T, k] float32)``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, experts = jax.lax.top_k(
+        jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
+    picked = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps) * scale
+    return experts.astype(jnp.int32), weights
+
+
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    """``x[perm]`` for a permutation: its transpose is the gather by the
+    inverse permutation, not the scatter XLA would derive."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inverse):
+    return x[perm], (perm, inverse)
+
+
+def _permute_bwd(res, g):
+    perm, inverse = res
+    return g[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def moe_share_apply(params, x, experts_held, top_k, expert_bias=None,
+                    scale=1.0, eps=1e-6, dtype=None):
+    """What the experts held here add to a top-k mixture's result.
+
+    ``x``: [T, d] tokens.  The router scores every token over ALL experts
+    (``params['router']`` is ``[d, E]``) and selects ``top_k`` of them
+    (:func:`sigmoid_top_k`); this chip holds the experts ``experts_held``
+    (global ids, in the order of the leading axis of ``w1``/``w3``/``w2``)
+    and returns ``sum over the selected experts held here of w_i * E_i(x)``,
+    ``E_i`` SwiGLU.  What the absent experts would add is left out: in an
+    expert-parallel job the shares of all chips add up to the whole layer
+    (``tests/test_lfm2.py`` holds that), and one chip alone runs without
+    the exchange.  There is no capacity and no token is dropped: all
+    ``T * top_k`` assignments are sorted by expert, those of held experts
+    first, and the held experts' matrices are applied as grouped products
+    over the ragged groups (``jax.lax.ragged_dot``: on the TPU a grouped
+    matmul that visits only the tiles of rows that belong to a group).
+
+    Returns ``(y [T, d], stats)``; ``stats['tokens_per_expert']`` ``[H]`` is
+    how many tokens went to each held expert and ``stats['held_share']`` the
+    share of all assignments that fell on held experts.
+    """
+    tokens, d_model = x.shape
+    held = len(experts_held)
+    num_experts = params['router'].shape[1]
+    dtype = dtype or x.dtype
+    if expert_bias is None:
+        expert_bias = jnp.zeros((num_experts,), jnp.float32)
+    with jax.named_scope('pt/moe_route'):
+        # float32 at full precision whatever ``dtype`` is: the choice of
+        # top_k experts is discrete, and a bfloat16 product picks other
+        # experts on near ties
+        logits = jnp.dot(x.astype(jnp.float32),
+                         params['router'].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        experts, weights = sigmoid_top_k(logits, expert_bias, top_k, scale, eps)
+        # global expert id -> place among the held ones; ``held`` = not here
+        place = np.full((num_experts,), held, np.int32)
+        place[np.asarray(experts_held)] = np.arange(held)
+        local = jnp.asarray(place)[experts].reshape(-1)          # [T * k]
+        order = jnp.argsort(local, stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        group_sizes = jnp.bincount(local, length=held + 1)[:held] \
+            .astype(jnp.int32)
+        in_a_group = jnp.arange(order.shape[0]) < jnp.sum(group_sizes)
+        xs = _permute(jnp.repeat(x.astype(dtype), top_k, axis=0), order,
+                      inverse)
+    with jax.named_scope('pt/moe_experts'):
+        w1, w3, w2 = (params[k].astype(dtype) for k in ('w1', 'w3', 'w2'))
+
+        def grouped(rows, matrices):
+            # rows past the last group belong to absent experts: the grouped
+            # product visits none of them, and what it leaves there, in the
+            # result as in the cotangent, is not data (on the TPU not even
+            # finite), so both are blanked
+            rows = jnp.where(in_a_group[:, None], rows, 0)
+            out = jax.lax.ragged_dot(rows, matrices, group_sizes)
+            return jnp.where(in_a_group[:, None], out, 0)
+        ys = grouped(jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3), w2)
+    with jax.named_scope('pt/moe_combine'):
+        here = (local < held).reshape(tokens, top_k)
+        ya = _permute(ys, inverse, order).reshape(tokens, top_k, d_model)
+        y = jnp.sum(ya.astype(jnp.float32)
+                    * jnp.where(here, weights, 0.0)[:, :, None], axis=1)
+    stats = {'tokens_per_expert': group_sizes,
+             'held_share': jnp.sum(group_sizes) / (tokens * top_k)}
+    return y.astype(x.dtype), stats

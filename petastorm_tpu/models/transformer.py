@@ -15,6 +15,15 @@ TPU design notes:
   ``[batch, seq, heads, head_dim]`` and delegates — so one model definition
   serves dense oracle, Pallas flash, ring (seq axis over ICI ring via
   ppermute), and Ulysses (all-to-all) without touching the module.
+* A layer is a token mixer and a feed-forward, each of a KIND: ``Block``
+  takes ``mixer`` ('attention' | 'conv', the gated short convolution) and
+  ``ffn`` ('gelu' | 'swiglu' | 'moe', one chip's share of a top-k expert
+  layer: ``models.moe.moe_share_apply``), and ``TransformerLM`` a
+  ``layer_types`` pattern with ``num_dense_layers`` leading dense ones —
+  how LFM2-style hybrids (conv, conv, attention, conv; dense then experts)
+  are spelled.  The defaults are the classic attention + GELU block, with
+  the parameter tree unchanged.  In a packed row (``segment_ids``) neither
+  mixer reaches across a document boundary.
 * ``param_shardings`` maps the param pytree onto a mesh: attention/MLP
   input projections shard their *output* features over ``model``; output
   projections shard their *input* features — the Megatron sandwich, which
@@ -91,9 +100,17 @@ class Attention(nn.Module):
     #: stored rotated — standard practice); None = positions handled
     #: upstream (learned table in TransformerLM).
     pos_mode: Any = None
+    rope_base: float = 10000.0
+    #: RMS-normalise q and k over the head dimension (a learned scale each,
+    #: ``q_norm`` / ``k_norm``) before the rotation, as LFM2 and others do.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    use_bias: bool = True
 
     @nn.compact
-    def __call__(self, x, positions=None):
+    def __call__(self, x, positions=None, segment_ids=None):
+        """``segment_ids`` ([batch, seq], 0 = padding) keeps attention inside
+        each packed document; it is handed to ``attn_fn`` by keyword."""
         d_model = x.shape[-1]
         if d_model % self.num_heads:
             raise ValueError('d_model %d not divisible by %d heads'
@@ -101,17 +118,25 @@ class Attention(nn.Module):
         head_dim = d_model // self.num_heads
         if self.num_kv_heads is None:
             qkv = nn.DenseGeneral((3, self.num_heads, head_dim), axis=-1,
-                                  dtype=self.dtype, name='qkv')(x)
+                                  dtype=self.dtype, use_bias=self.use_bias,
+                                  name='qkv')(x)
             q, k, v = jnp.moveaxis(qkv, -3, 0)  # each [b, s, h, hd]
         else:
             if self.num_heads % self.num_kv_heads:
                 raise ValueError('num_heads %d not divisible by num_kv_heads %d'
                                  % (self.num_heads, self.num_kv_heads))
             q = nn.DenseGeneral((self.num_heads, head_dim), axis=-1,
-                                dtype=self.dtype, name='q')(x)
+                                dtype=self.dtype, use_bias=self.use_bias,
+                                name='q')(x)
             kv = nn.DenseGeneral((2, self.num_kv_heads, head_dim), axis=-1,
-                                 dtype=self.dtype, name='kv')(x)
+                                 dtype=self.dtype, use_bias=self.use_bias,
+                                 name='kv')(x)
             k, v = jnp.moveaxis(kv, -3, 0)      # [b, s, h_kv, hd]
+        if self.qk_norm:
+            # the float32 scale promotes: back to the compute dtype, or the
+            # attention kernels run in float32
+            q = RMSNorm(self.norm_eps, name='q_norm')(q).astype(self.dtype)
+            k = RMSNorm(self.norm_eps, name='k_norm')(k).astype(self.dtype)
         if self.pos_mode == 'rope':
             if positions is None:
                 if self.decode:
@@ -121,16 +146,18 @@ class Attention(nn.Module):
                                      'explicit positions')
                 positions = jnp.broadcast_to(jnp.arange(x.shape[1]),
                                              x.shape[:2])
-            cs = rope_cos_sin(positions, q.shape[-1])  # once for q AND k
+            cs = rope_cos_sin(positions, q.shape[-1],
+                              self.rope_base)  # once for q AND k
             q = rope(q, cos_sin=cs)
             k = rope(k, cos_sin=cs)
         if self.decode:
             out = self._decode_step(q, k, v)
         else:
             k, v = self._expand_kv(k, v)
-            out = self.attn_fn(q, k, v, causal=self.causal)
+            packed = {} if segment_ids is None else {'segment_ids': segment_ids}
+            out = self.attn_fn(q, k, v, causal=self.causal, **packed)
         return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
-                               name='out')(out)
+                               use_bias=self.use_bias, name='out')(out)
 
     def _expand_kv(self, k, v):
         """Broadcast KV heads to the query head count (GQA no-op for MHA)."""
@@ -207,6 +234,72 @@ class Attention(nn.Module):
         return out.reshape(b, seq, h, hd).astype(q.dtype)
 
 
+class ShortConv(nn.Module):
+    """Gated short convolution, LFM2's second kind of token mixer:
+    ``[B, C, X] = split3(W_in x)``; ``u = B * X``; ``c_t = sum_k w_k *
+    u_{t-k}`` (depthwise, causal, one ``kernel``-tap filter a channel, no
+    bias); ``y = W_out (C * c)``.  In a packed row a tap that would reach
+    into another document or into padding (a different ``segment_id``, or
+    0) contributes 0."""
+    kernel: int = 3
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, segment_ids=None):
+        d_model, length = x.shape[-1], x.shape[1]
+        bcx = nn.Dense(3 * d_model, use_bias=False, dtype=self.dtype,
+                       name='in_proj')(x)
+        gate_b, gate_c, value = jnp.split(bcx, 3, axis=-1)
+        taps = self.param('conv', nn.initializers.normal(
+            1.0 / self.kernel ** 0.5), (self.kernel, d_model))
+        with jax.named_scope('pt/lfm2_conv'):
+            u = gate_b * value
+            conv = u * taps[0].astype(self.dtype)
+            for k in range(1, self.kernel):
+                shifted = jnp.pad(u, ((0, 0), (k, 0), (0, 0)))[:, :length]
+                if segment_ids is not None:
+                    before = jnp.pad(segment_ids, ((0, 0), (k, 0)))[:, :length]
+                    same = (segment_ids == before) & (segment_ids != 0)
+                    shifted = jnp.where(same[:, :, None], shifted, 0)
+                conv = conv + shifted * taps[k].astype(self.dtype)
+            gated = gate_c * conv
+        return nn.Dense(d_model, use_bias=False, dtype=self.dtype,
+                        name='out_proj')(gated)
+
+
+class MoEShare(nn.Module):
+    """``models.moe.moe_share_apply`` as a module: the router over all
+    ``num_experts``, the SwiGLU experts ``experts_held`` here, ``top_k`` a
+    token, no capacity.  The selection bias is a buffer (collection
+    ``buffers``, no gradient); what was routed where is sown into the
+    collection ``diagnostics``."""
+    num_experts: int
+    top_k: int
+    d_expert: int
+    experts_held: Any = None        # global ids held here; None = all
+    scale: float = 1.0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        from petastorm_tpu.models.moe import (
+            fan_in_normal, moe_share_apply, moe_share_shapes)
+        held = tuple(range(self.num_experts)) if self.experts_held is None \
+            else tuple(self.experts_held)
+        d_model = x.shape[-1]
+        params = {name: self.param(name, fan_in_normal, shape)
+                  for name, shape in moe_share_shapes(
+                      d_model, self.d_expert, self.num_experts, held).items()}
+        bias = self.variable('buffers', 'expert_bias', jnp.zeros,
+                             (self.num_experts,), jnp.float32)
+        y, stats = moe_share_apply(
+            params, x.reshape(-1, d_model), held, self.top_k,
+            expert_bias=bias.value, scale=self.scale, dtype=self.dtype)
+        for name, value in stats.items():
+            self.sow('diagnostics', name, value)
+        return y.reshape(x.shape)
+
+
 class Block(nn.Module):
     num_heads: int
     d_ff: int
@@ -217,18 +310,54 @@ class Block(nn.Module):
     max_decode_len: int = 2048
     num_kv_heads: Any = None
     pos_mode: Any = None
+    #: Token mixer: 'attention' or 'conv' (:class:`ShortConv`).
+    mixer: str = 'attention'
+    #: Feed-forward: 'gelu' (two matrices with biases), 'swiglu'
+    #: (``W2(silu(W1 h) * W3 h)``, no bias) or 'moe' (:class:`MoEShare`,
+    #: built from the ``moe`` dict of its fields).
+    ffn: str = 'gelu'
+    moe: Any = None
+    rope_base: float = 10000.0
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    use_bias: bool = True
+    conv_kernel: int = 3
 
     @nn.compact
-    def __call__(self, x, positions=None):
-        x = x + Attention(self.num_heads, self.dtype, self.attn_fn,
-                          causal=self.causal, decode=self.decode,
-                          max_decode_len=self.max_decode_len,
-                          num_kv_heads=self.num_kv_heads,
-                          pos_mode=self.pos_mode,
-                          name='attn')(RMSNorm(name='ln1')(x), positions)
-        h = nn.Dense(self.d_ff, dtype=self.dtype, name='ffw_in')(RMSNorm(name='ln2')(x))
-        h = nn.gelu(h)
-        return x + nn.Dense(x.shape[-1], dtype=self.dtype, name='ffw_out')(h)
+    def __call__(self, x, positions=None, segment_ids=None):
+        h = RMSNorm(self.norm_eps, name='ln1')(x)
+        if self.mixer == 'attention':
+            with jax.named_scope('pt/attention'):
+                x = x + Attention(
+                    self.num_heads, self.dtype, self.attn_fn,
+                    causal=self.causal, decode=self.decode,
+                    max_decode_len=self.max_decode_len,
+                    num_kv_heads=self.num_kv_heads, pos_mode=self.pos_mode,
+                    rope_base=self.rope_base, qk_norm=self.qk_norm,
+                    norm_eps=self.norm_eps, use_bias=self.use_bias,
+                    name='attn')(h, positions, segment_ids)
+        elif self.mixer == 'conv':
+            x = x + ShortConv(self.conv_kernel, self.dtype,
+                              name='conv')(h, segment_ids)
+        else:
+            raise ValueError("mixer must be 'attention' or 'conv', got %r"
+                             % (self.mixer,))
+        h = RMSNorm(self.norm_eps, name='ln2')(x)
+        if self.ffn == 'gelu':
+            h = nn.Dense(self.d_ff, dtype=self.dtype, name='ffw_in')(h)
+            return x + nn.Dense(x.shape[-1], dtype=self.dtype,
+                                name='ffw_out')(nn.gelu(h))
+        if self.ffn == 'swiglu':
+            gate = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
+                            name='w1')(h)
+            up = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
+                          name='w3')(h)
+            return x + nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
+                                name='w2')(nn.silu(gate) * up)
+        if self.ffn == 'moe':
+            return x + MoEShare(dtype=self.dtype, name='moe', **self.moe)(h)
+        raise ValueError("ffn must be 'gelu', 'swiglu' or 'moe', got %r"
+                         % (self.ffn,))
 
 
 class TransformerLM(nn.Module):
@@ -246,13 +375,29 @@ class TransformerLM(nn.Module):
     decode: bool = False  # KV-cache incremental mode (models.decoding)
     num_kv_heads: Any = None  # GQA: KV heads < query heads (see Attention)
     pos_embed: str = 'learned'  # 'learned' table | 'rope' rotary q/k
+    #: The mixer of each layer, 'full_attention' or 'conv' (the names of
+    #: LFM2's ``layer_types``), ``num_layers`` long; None = attention
+    #: everywhere.
+    layer_types: Any = None
+    #: The feed-forward of the layers: 'gelu', 'swiglu', or 'moe' with the
+    #: first ``num_dense_layers`` layers 'swiglu' of width ``d_ff`` and the
+    #: others :class:`MoEShare` built from ``moe``.
+    ffn: str = 'gelu'
+    num_dense_layers: int = 0
+    moe: Any = None
+    rope_base: float = 10000.0
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    use_bias: bool = True
+    conv_kernel: int = 3
 
     @nn.compact
-    def __call__(self, tokens, positions=None):
+    def __call__(self, tokens, positions=None, segment_ids=None):
         """``positions`` overrides the default row-absolute ``arange``
         positions — pass ``packing.pack_*``'s per-segment ``positions`` so
         each packed document is embedded (or RoPE-rotated) as if it
-        started at 0."""
+        started at 0.  ``segment_ids`` keeps both kinds of mixer inside each
+        packed document."""
         if self.pos_embed not in ('learned', 'rope'):
             raise ValueError("pos_embed must be 'learned' or 'rope', got %r"
                              % (self.pos_embed,))
@@ -270,14 +415,26 @@ class TransformerLM(nn.Module):
         if self.remat:
             block = nn.remat(Block)
         rope_mode = 'rope' if self.pos_embed == 'rope' else None
-        for i in range(self.num_layers):
+        layer_types = self.layer_types or ('full_attention',) * self.num_layers
+        if len(layer_types) != self.num_layers:
+            raise ValueError('layer_types names %d layers, num_layers is %d'
+                             % (len(layer_types), self.num_layers))
+        for i, kind in enumerate(layer_types):
+            ffn = self.ffn
+            if ffn == 'moe' and i < self.num_dense_layers:
+                ffn = 'swiglu'
             x = block(self.num_heads, self.d_ff, self.dtype, self.attn_fn,
                       decode=self.decode, max_decode_len=self.max_seq_len,
                       num_kv_heads=self.num_kv_heads, pos_mode=rope_mode,
-                      name='block_%d' % i)(x, positions)
-        x = RMSNorm(name='ln_f')(x)
+                      mixer='attention' if kind == 'full_attention' else kind,
+                      ffn=ffn, moe=self.moe, rope_base=self.rope_base,
+                      qk_norm=self.qk_norm, norm_eps=self.norm_eps,
+                      use_bias=self.use_bias, conv_kernel=self.conv_kernel,
+                      name='block_%d' % i)(x, positions, segment_ids)
+        x = RMSNorm(self.norm_eps, name='ln_f')(x)
         # Tied output head: attend() reuses the (vocab-sharded) embedding.
-        return embed.attend(x.astype(self.dtype)).astype(jnp.float32)
+        with jax.named_scope('pt/lm_head_loss'):
+            return embed.attend(x.astype(self.dtype)).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -144,6 +144,7 @@ def _fwd(q3, k3, v3, seg3, seg3_k, scale, causal, seq_len, block_q, block_k,
             jax.ShapeDtypeStruct((bh, 1, seq_pad), jnp.float32),
         ],
         interpret=interpret,
+        name='pt_flash_fwd',
     )(*args)
 
 
@@ -339,6 +340,7 @@ def _bwd_dq_call(q3, k_c, v_c, seg3, seg_k, do3, lse, delta, scale, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq_pad, d), q3.dtype),
         interpret=interpret,
+        name='pt_flash_bwd_dq',
     )(*dq_args)
 
 
@@ -379,6 +381,7 @@ def _bwd_dkv_call(q_c, k_c, v_c, seg_q, seg_k, do_c, lse_c, delta_c, scale,
             jax.ShapeDtypeStruct((bh, kv_pad, d), v_c.dtype),
         ],
         interpret=interpret,
+        name='pt_flash_bwd_dkv',
     )(*dkv_args)
 
 

@@ -68,7 +68,10 @@ class Stages(object):
     Instruments are made on a stage's first use.  A block left by an
     exception, or with ``stage.keep = False``, leaves the profiler span
     and nothing else (the pull that met the end of the stream is no
-    sample).
+    sample).  Such a block can still be read (``stage.seconds``), and the
+    block that closes the sample takes the seconds of the ones before it as
+    ``stage.carried``: a stage whose work for one sample comes in several
+    blocks (the packer's, one a document) is still one sample.
 
     ``pt/`` is for the spans that may own an idle gap of the device: the
     top-level stages of the thread that feeds it, and the pauses of the
@@ -104,7 +107,7 @@ class Stages(object):
 
 class _Stage(object):
     __slots__ = ('_stages', 'name', 'span', 'event', 'event_args', 'keep',
-                 'window', '_profiler_span')
+                 'carried', 'window', '_profiler_span')
 
     def __init__(self, stages, name, span, event, event_args):
         self._stages = stages
@@ -113,6 +116,7 @@ class _Stage(object):
         self.event = event
         self.event_args = event_args
         self.keep = True
+        self.carried = 0.0
         self.window = None
 
     @property
@@ -128,11 +132,11 @@ class _Stage(object):
     def __exit__(self, exc_type, exc, tb):
         t0, t1 = self.window[0], time.monotonic()
         self._profiler_span.__exit__(exc_type, exc, tb)
+        self.window[1] = t1
         if exc_type is None and self.keep:
-            self.window[1] = t1
             counter, hist = self._stages.instruments(self.name)
-            counter.inc(t1 - t0)
-            hist.observe(t1 - t0)
+            counter.inc(t1 - t0 + self.carried)
+            hist.observe(t1 - t0 + self.carried)
             recorder = self._stages.recorder
             if recorder is not None and self.event is not None:
                 recorder.event(self.event, t0, t1, **self.event_args)

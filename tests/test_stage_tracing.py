@@ -396,6 +396,24 @@ def test_stage_primitive_feeds_every_surface_from_one_pair_of_readings():
     assert metrics.histogram('quiet').count == 1
 
 
+def test_a_sample_can_carry_the_seconds_of_the_blocks_before_it():
+    """A stage whose work for one sample comes in several blocks (the packer's,
+    one a document): the blocks that close no sample are disowned but can be
+    read, and the one that closes it carries their seconds."""
+    metrics = MetricsRegistry('m')
+    stages = Stages(metrics)
+    carried = 0.0
+    for closes in (False, False, True):
+        with stages('pack', span='ptp/pack') as block:
+            block.carried = carried
+            time.sleep(0.001)
+            block.keep = closes
+        carried = 0.0 if closes else carried + block.seconds
+    hist = metrics.histogram('pack')
+    assert hist.count == 1 and hist.sum >= 0.003
+    assert metrics.counter('pack_s').value == hist.sum
+
+
 def test_stages_are_safe_to_share_between_threads():
     import sys
     metrics = MetricsRegistry('shared')

@@ -218,3 +218,45 @@ def test_residency_programs_need_no_scratch_that_grows_with_the_tier(
         assert memory.output_size_in_bytes >= batch_bytes
     else:                               # the donated slabs are the output
         assert memory.alias_size_in_bytes >= capacity * plan.wire_row_nbytes
+
+
+#: What the v5e's allocator offers a program (``memory_stats()['bytes_limit']``
+#: on the chip; PERF.md section 4).
+V5E_BYTES_LIMIT = 16.91e9
+
+
+def test_lfm2_step_compiles_at_its_published_sizes_inside_the_chips_memory(
+        one_chip, flash):
+    """``lfm2.packed``'s step as the benchmark jits it (state donated, 4
+    packed rows of 8,192 tokens, every width as published, recomputation a
+    layer): the flash kernels and the grouped expert products lower for the
+    chip under the names the per-layer metrics read, and parameters, Adam's
+    moments and the step's temporaries fit the chip."""
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, 'benchmarks')
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import catalog
+    base = os.path.join(bench, 'configs', 'lfm2-24b-a2b')
+    with open(base + '.json') as f:
+        config = catalog._module(base + '.py').Config(json.load(f))
+    assert (config.batch, config.max_len, config.hidden) == (4, 8192, 2048)
+    name, step, shapes, donated = config.rehearsal_programs(
+        jax.eval_shape(lambda: jax.random.key(0)))[0]
+    assert name == 'step'
+    placed = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    compiled = jax.jit(step, donate_argnums=donated).lower(*placed).compile()
+    text = compiled.as_text()
+    for kernel in ('pt_flash_fwd', 'pt_flash_bwd_dq', 'pt_flash_bwd_dkv',
+                   'ragged-dot'):
+        assert kernel in text, kernel
+    m = compiled.memory_analysis()
+    state = m.argument_size_in_bytes
+    peak = state + m.output_size_in_bytes - m.alias_size_in_bytes \
+        + m.temp_size_in_bytes
+    # parameters and Adam's two moments in float32: 12 B a parameter
+    assert state == pytest.approx(12 * config.parameter_count(), rel=0.01)
+    assert 0.6 * V5E_BYTES_LIMIT < peak < V5E_BYTES_LIMIT, peak
