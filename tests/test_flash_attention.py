@@ -214,6 +214,209 @@ def test_packed_in_jit(rng):
     assert np.isfinite(np.asarray(out)).all()
 
 
+# -- packed: which tiles are visited, and in what precision -------------------
+
+def _layout(name, s=96):
+    """Two rows of segment ids at block 16, each a way the tile runs can go
+    wrong."""
+    seg = np.zeros((2, s), np.int32)
+    if name == 'end_to_end':        # one document over many blocks, several in one
+        seg[0, :50], seg[0, 50:54], seg[0, 54:57], seg[0, 57:62] = 1, 2, 3, 4
+        seg[0, 62:] = 5
+        seg[1, :3], seg[1, 3:9], seg[1, 9:16], seg[1, 16:81], seg[1, 81:] = 1, 2, 3, 4, 5
+    elif name == 'one_document':
+        seg[:] = 7
+    elif name == 'returning_id':    # NOT laid end to end: an id comes back
+        seg[0, :20], seg[0, 20:40], seg[0, 40:70], seg[0, 70:] = 1, 2, 1, 3
+        seg[1, :10], seg[1, 10:30], seg[1, 30:35], seg[1, 35:90] = 5, 0, 2, 5
+    elif name == 'padded_tail':     # a padded tail, and a row of padding only
+        seg[0, :30], seg[0, 30:41] = 1, 2
+    return jnp.asarray(seg)
+
+
+LAYOUTS = ['end_to_end', 'one_document', 'returning_id', 'padded_tail']
+
+
+@pytest.mark.parametrize('kv_chunk', [0, 32], ids=['whole_kv', 'chunked'])
+@pytest.mark.parametrize('causal', [False, True])
+@pytest.mark.parametrize('layout', LAYOUTS)
+def test_packed_layouts_match_oracle_forward_and_gradients(rng, layout, causal,
+                                                           kv_chunk):
+    from petastorm_tpu.jax.packing import packed_attention
+
+    q, k, v = _qkv(rng, s=96, d=8)
+    seg = _layout(layout)
+    dout = jnp.asarray(rng.standard_normal(q.shape).astype(np.float32))
+
+    def both(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out,) + vjp(dout)
+
+    got = both(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, segment_ids=seg, block_q=16, block_k=16,
+        kv_chunk=kv_chunk))
+    want = both(lambda q, k, v: packed_attention(q, k, v, seg, causal=causal))
+    for g, w, name in zip(got, want, ['out', 'dq', 'dk', 'dv']):
+        np.testing.assert_allclose(g, w, atol=3e-5, rtol=3e-5, err_msg=name)
+
+
+@pytest.mark.parametrize('layout', ['end_to_end', 'returning_id'])
+def test_packed_bfloat16_within_its_tolerance_of_the_float32_oracle(rng, layout):
+    from petastorm_tpu.jax.packing import packed_attention
+
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(rng, s=96, d=16))
+    seg = _layout(layout)
+    dout = jnp.asarray(rng.standard_normal(q.shape).astype(np.float32))
+
+    def both(fn, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out,) + vjp(dout.astype(out.dtype))
+
+    got = both(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, segment_ids=seg, block_q=16, block_k=32), q, k, v)
+    want = both(lambda q, k, v: packed_attention(q, k, v, seg, causal=True),
+                *(x.astype(jnp.float32) for x in (q, k, v)))
+    for g, w, name in zip(got, want, ['out', 'dq', 'dk', 'dv']):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_allclose(g.astype(np.float32), w, atol=4e-2,
+                                   rtol=4e-2, err_msg=name)
+
+
+def _kernels(closed_jaxpr):
+    """Every ``pallas_call`` equation of a traced program, at any depth."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == 'pallas_call':
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(closed_jaxpr.jaxpr))
+
+
+def _products(jaxpr):
+    """The operand dtypes of every ``dot_general`` of a kernel's body."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'dot_general':
+            yield tuple(str(x.aval.dtype) for x in eqn.invars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _products(sub)
+
+
+@pytest.mark.parametrize('packed', [False, True])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_kernels_multiply_in_the_dtype_of_their_inputs(rng, dtype, packed):
+    q, k, v = (x.astype(dtype) for x in _qkv(rng, s=64, d=16))
+    seg = _segments(rng, 2, 64) if packed else None
+    program = jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, segment_ids=seg, block_q=32, block_k=32)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    products = [(kernel.params['name'], operands)
+                for kernel in _kernels(jax.make_jaxpr(program)(q, k, v))
+                for operands in _products(kernel.params['jaxpr'])]
+    # two products forward, three in the dQ kernel, four in the dK/dV kernel
+    assert sorted(name for name, _ in products) == (
+        ['pt_flash_bwd_dkv'] * 4 + ['pt_flash_bwd_dq'] * 3 + ['pt_flash_fwd'] * 2)
+    assert {d for _, operands in products for d in operands} == {dtype}
+
+
+@pytest.mark.parametrize('seq_len,block', [(64, 512), (197, 512), (512, 512),
+                                           (513, 128), (700, 256), (2000, 512),
+                                           (8192, 512), (32768, 512)])
+def test_block_default_is_the_largest_tile_that_pads_at_most_an_eighth(
+        seq_len, block):
+    from petastorm_tpu.ops.flash_attention import block_default
+    assert block_default(seq_len) == block
+    padded = -(-seq_len // block) * block
+    # 128, the smallest tile the chip takes, is what is left when none fits
+    assert seq_len <= block or block == 128 or padded - seq_len <= seq_len / 8
+
+
+def test_blocks_default_to_the_rule_and_named_ones_are_honoured(rng):
+    """The grid of each kernel says which tile a call took."""
+    q, k, v = _qkv(rng, b=1, s=1024, h=1, d=8)
+
+    def grids(**blocks):
+        return {kernel.params['name']: kernel.params['grid_mapping'].grid
+                for kernel in _kernels(jax.make_jaxpr(jax.grad(
+                    lambda q: flash_attention(q, k, v, causal=True,
+                                              **blocks).sum()))(q))}
+
+    assert grids() == {'pt_flash_fwd': (1, 2), 'pt_flash_bwd_dq': (1, 2),
+                       'pt_flash_bwd_dkv': (1, 2)}
+    assert grids(block_q=128, block_k=256) == {
+        'pt_flash_fwd': (1, 8), 'pt_flash_bwd_dq': (1, 8),
+        'pt_flash_bwd_dkv': (1, 4)}
+
+
+def test_tile_visits_of_one_document_a_row_is_the_causal_triangle():
+    from petastorm_tpu.ops.flash_attention import tile_visits
+    seg = np.full((3, 1024), 5, np.int32)
+    assert tile_visits(seg, 128, 128, True) == (3 * 36, 3 * 36, 3 * 36)
+    assert tile_visits(seg, 128, 128, False) == (3 * 64, 3 * 64, 3 * 64)
+    # blocks that do not divide the length: padded like the call pads
+    assert tile_visits(seg[:, :1000], 128, 256, True) == (3 * 20, 3 * 20, 3 * 20)
+
+
+@pytest.mark.parametrize('causal', [False, True])
+def test_tile_visits_of_documents_of_one_block_each_is_one_tile_each(causal):
+    from petastorm_tpu.ops.flash_attention import tile_visits
+    seg = np.repeat(np.arange(1, 9, dtype=np.int32), 128)[None]
+    visited, triangle, holding = tile_visits(seg, 128, 128, causal)
+    assert (visited, holding) == (8, 8)
+    assert triangle == (36 if causal else 64)
+    # a padded tail and a row of padding only are not visited at all
+    seg = np.concatenate([seg, np.zeros_like(seg)])
+    seg[0, 640:] = 0
+    assert tile_visits(seg, 128, 128, causal)[::2] == (5, 5)
+
+
+def test_tile_visits_on_the_configurations_length_law():
+    """50 rows packed from lfm2-24b-a2b's law of document lengths: no tile
+    that holds a needed pair is left out, and about half of the causal
+    triangle is visited at 128 x 128."""
+    import json
+    import os
+    from petastorm_tpu.jax.packing import StreamPacker
+    from petastorm_tpu.ops.flash_attention import _tile_maps, tile_visits
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'lfm2-24b-a2b.json')) as f:
+        spec = json.load(f)
+    law, max_len = spec['dataset'], spec['max_len']
+    rng = np.random.default_rng(29)
+    lengths = np.clip(np.rint(rng.lognormal(
+        np.log(law['length_median']), law['length_sigma'], 4000)),
+        law['length_min'], max_len).astype(np.int64)
+    packer, rows = StreamPacker(max_len, 1), []
+    for n in lengths:
+        rows += [b['segment_ids'] for b in packer.add(np.ones(n, np.int32))]
+        if len(rows) >= 50:
+            break
+    seg = np.concatenate(rows[:50])
+    assert seg.shape == (50, max_len)
+    visited, triangle, holding = _tile_maps(seg, 128, 128, True)
+    assert not (holding & ~visited).any()
+    assert not (visited & ~triangle).any()
+    counts = tile_visits(seg, 128, 128, True)
+    assert counts == (visited.sum(), 50 * triangle.sum(), holding.sum())
+    assert 0.45 < counts[0] / counts[1] < 0.70
+    # larger tiles skip less, and still leave nothing out
+    visited, _, holding = _tile_maps(seg[:8], 512, 256, True)
+    assert not (holding & ~visited).any()
+
+
+def test_tile_visits_leaves_no_pair_out_for_ids_in_any_order(rng):
+    """The range test is conservative for ids that are not laid end to end."""
+    from petastorm_tpu.ops.flash_attention import _tile_maps
+    seg = rng.integers(0, 6, (4, 256)).astype(np.int32)
+    seg[1] = np.sort(seg[1])[::-1]
+    seg[2, 40:200] = 0
+    for causal in (False, True):
+        for block_q, block_k in ((16, 16), (32, 16), (16, 64)):
+            visited, _, holding = _tile_maps(seg, block_q, block_k, causal)
+            assert not (holding & ~visited).any()
+
+
 # -- K/V chunking (streaming long sequences through VMEM-sized chunks) -------
 
 @pytest.mark.parametrize('causal', [False, True])

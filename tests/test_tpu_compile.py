@@ -65,29 +65,36 @@ def flash(monkeypatch):
     return module.flash_attention
 
 
+#: ``lfm2.packed``'s own call: 4 packed rows of 8,192 tokens, 32 heads of 64.
+CELL_SHAPE = (4, 8192, 32, 64)
+
+
 def _flash_program(flash, mode):
     if mode == 'fwd':
         return lambda q, k, v: flash(q, k, v, causal=True)
     if mode == 'bwd':
         return jax.grad(lambda q, k, v: flash(q, k, v, causal=True)
                         .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    if mode == 'packed_fwd':
+        return lambda q, k, v, seg: flash(q, k, v, causal=True, segment_ids=seg)
     return jax.grad(lambda q, k, v, seg: flash(q, k, v, causal=True,
                                                segment_ids=seg)
                     .astype(jnp.float32).sum(), argnums=(0, 1, 2))
 
 
-@pytest.mark.parametrize('mode', ['fwd', 'bwd', 'packed'])
-@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
-@pytest.mark.parametrize('length', ['2048', 'chunked'])
+@pytest.mark.parametrize('length,dtype,mode', [
+    (length, dtype, mode) for length in ('2048', 'chunked')
+    for dtype in ('bfloat16', 'float32') for mode in ('fwd', 'bwd', 'packed')
+] + [('cell', 'bfloat16', 'packed_fwd'), ('cell', 'bfloat16', 'packed')])
 def test_flash_attention_compiles_at_its_defaults(one_chip, flash, length,
                                                   dtype, mode):
     from petastorm_tpu.ops.flash_attention import kv_chunk_default
-    shape = SHAPE if length == '2048' else CHUNKED_SHAPE[dtype]
+    shape = {'2048': SHAPE, 'cell': CELL_SHAPE}.get(length) or CHUNKED_SHAPE[dtype]
     if length == 'chunked':
         assert shape[1] > kv_chunk_default(shape[3], dtype)
     x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
     args = (x, x, x)
-    if mode == 'packed':
+    if mode.startswith('packed'):
         args += (jax.ShapeDtypeStruct(shape[:2], jnp.int32, sharding=one_chip),)
     compiled = jax.jit(_flash_program(flash, mode)).lower(*args).compile()
     assert 'tpu_custom_call' in compiled.as_text()
