@@ -64,7 +64,7 @@ def train(dataset_url, epochs=1, batch_size=2048, lr=1e-3, scan_steps=0):
                 # (embedding gathers + small MLPs), so per-step dispatch
                 # latency — not compute — is where a fast device stalls;
                 # k steps per stacked device_put + lax.scan dispatch
-                # amortizes it k-fold (the bench's stall_pct_dlrm_scan leg).
+                # amortizes it k-fold.
                 def scan_step(carry, batch):
                     p, o = carry
                     p, o, loss = train_step(p, o, batch)

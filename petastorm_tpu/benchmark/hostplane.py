@@ -8,37 +8,7 @@ rules must not fork between them.
 
 import time
 
-# Promoted to test_util (ISSUE 14 satellite) — it is the ingest plane's
-# correctness harness, not bench-private plumbing; re-exported here so
-# every existing import path keeps working.
-from petastorm_tpu.test_util.emulation import BandwidthLimitedFilesystem  # noqa: F401
-
-__all__ = ['open_host_reader', 'pump_host_batches', 'IpcBenchWorker',
-           'BandwidthLimitedFilesystem']
-
-
-class IpcBenchWorker(object):
-    """ProcessPool worker for the IPC-plane microbench.
-
-    Each ventilated item publishes one synthetic uint8 batch of the given
-    shape — pure result-plane traffic, no decode work — so the pool's
-    delivery path (shm descriptors vs pickle-over-ZMQ) is the only thing
-    measured.  Lives here (not in bench.py) because the pool's
-    fresh-interpreter children must import the class by module path.
-    """
-
-    def __init__(self, worker_id, publish, args):
-        import numpy as np
-        self._publish = publish
-        self._batch = np.zeros(tuple(args), np.uint8)
-        self._batch.ravel()[::4096] = worker_id  # defeat page dedup tricks
-
-    def process(self, n=1):
-        for _ in range(int(n)):
-            self._publish([self._batch])
-
-    def shutdown(self):
-        pass
+__all__ = ['open_host_reader', 'pump_host_batches']
 
 
 def open_host_reader(dataset_url, **reader_kwargs):

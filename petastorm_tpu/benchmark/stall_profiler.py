@@ -16,57 +16,7 @@ blocks, which is exactly what this measures.  Optional
 profiles (enabled when ``annotate=True``).
 """
 
-import math
-import os
 import time
-
-#: Env override for :func:`fused_dispatch_window` — pins the hbm_scan
-#: fused-window step count regardless of the auto-size math below.
-DISPATCH_WINDOW_ENV = 'PETASTORM_TPU_BENCH_DISPATCH_WINDOW_STEPS'
-
-#: One fused dispatch of W steps pays one host→device dispatch round trip
-#: no matter how large W is.  A planning ceiling for that round trip, not a
-#: measurement of any device: a caller that has measured its own passes it
-#: as ``dispatch_latency_ms``.
-DEFAULT_DISPATCH_LATENCY_MS = 100.0
-
-#: The phantom stall budget: the per-window dispatch latency amortized
-#: over the window must cost no more than this share of step time, so the
-#: measured stall_pct reflects the data plane rather than the dispatch.
-PHANTOM_STALL_BUDGET_PCT = 3.0
-
-
-def fused_dispatch_window(train_steps, step_floor_ms=None,
-                          dispatch_latency_ms=DEFAULT_DISPATCH_LATENCY_MS,
-                          phantom_stall_budget_pct=PHANTOM_STALL_BUDGET_PCT):
-    """Steps to fold into one fused hbm_scan dispatch window.
-
-    A window too short charges its one dispatch round trip to the data
-    plane as a *phantom* stall.  Each fused window pays ~one
-    ``dispatch_latency_ms`` regardless of length, so the window must be
-    long enough that this overhead amortizes below
-    ``phantom_stall_budget_pct`` of the measured step time:
-
-        W_min = dispatch_latency_ms / (budget% * step_floor_ms)
-
-    rounded up to a whole multiple of ``train_steps`` (windows must tile
-    the measured span).  Without a measured ``step_floor_ms`` (the
-    bootstrap call that measures it) the window is 4x ``train_steps``;
-    the result is capped at 8x to keep bench wall time bounded on very
-    fast devices.  The
-    ``PETASTORM_TPU_BENCH_DISPATCH_WINDOW_STEPS`` env var overrides
-    everything (floored at one ``train_steps`` tile).
-    """
-    base = max(1, int(train_steps))
-    pinned = os.environ.get(DISPATCH_WINDOW_ENV)
-    if pinned:
-        return max(base, int(pinned))
-    if not step_floor_ms or step_floor_ms <= 0:
-        return 4 * base
-    need = dispatch_latency_ms / (
-        step_floor_ms * phantom_stall_budget_pct / 100.0)
-    mult = max(1, int(math.ceil(need / base)))
-    return min(8, mult) * base
 
 
 class StallMonitor(object):

@@ -808,9 +808,8 @@ class DataLoader(object):
         The same batches ``__iter__`` would stage (shuffling, batching,
         ``transform_fn``, resume all apply) but stopping at the host
         boundary: for feeding non-JAX consumers, writing derived datasets,
-        or measuring the host delivery plane in isolation (``bench.py``'s
-        ``delivery_plane_images_per_sec_host`` leg uses this to prove the
-        consumer path sustains chip rate independent of the transport).
+        or measuring the host delivery plane in isolation (the doctor's
+        host-plane section and ``benchmark.autotune`` do).
 
         Caveat on resume: batches restored from ``resume_state`` were
         snapshotted AFTER the device-transfer filter, so they carry only

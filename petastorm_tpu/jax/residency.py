@@ -123,9 +123,8 @@ def donation_supported():
     """Whether buffer donation actually recycles memory on this backend.
 
     ``jax.jit(..., donate_argnums=...)`` is a no-op (a copy) on CPU; the
-    tier still runs there — tests and the CPU-emulated bench leg exercise
-    the exact same code path — but the in-place recycling story only
-    holds on accelerators.
+    tier still runs there — the tests exercise the exact same code
+    path — but the in-place recycling story only holds on accelerators.
     """
     return jax.default_backend() != 'cpu'
 

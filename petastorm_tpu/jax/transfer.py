@@ -5,8 +5,8 @@ not paid inline.  This module makes the transfer a first-class pipeline
 stage:
 
 * **Ring-buffered staging** — a fixed ring of reused host staging slabs
-  (reuse matters: first-touch page faults cost ~20x the memcpy on the
-  virtualized bench kernel).  A slot is rewritten only after the batch
+  (reuse matters: a fresh slab pays a first-touch page fault a page
+  before the memcpy).  A slot is rewritten only after the batch
   it last carried is committed on device (``jax.block_until_ready`` on
   slot reuse), so with ``ring_slots`` slots up to ``ring_slots - 1``
   transfers are in flight while the step runs — batch N+1's DMA
@@ -121,9 +121,9 @@ def plane_enabled(transfer):
 
     ``False``/``None`` → off; ``True`` → on (tests force the plane on the
     CPU backend this way); ``'auto'`` → on unless the backend is the CPU,
-    where the "link" is a memcpy and the extra staging pass buys nothing
-    (bench.py ``transfer_plane`` leg).  The kill switch wins over
-    everything.  A backend that cannot initialize raises here.
+    where the "link" is a memcpy and the extra staging pass buys nothing.
+    The kill switch wins over everything.  A backend that cannot
+    initialize raises here.
     """
     validate_transfer(transfer)
     if os.environ.get(KILL_SWITCH):

@@ -302,7 +302,7 @@ class Worker(object):  # ptlint: disable=pickle-unsafe-attrs — a worker IS a p
 
     def start(self):
         """Run the worker in a daemon thread (in-process deployments:
-        tests, the bench's service leg).  The CLI calls :meth:`run`."""
+        tests).  The CLI calls :meth:`run`."""
         self._thread = threading.Thread(target=self.run,
                                         name='service-worker', daemon=True)
         self._thread.start()

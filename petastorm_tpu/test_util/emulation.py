@@ -1,4 +1,4 @@
-"""Deterministic storage emulation for tests and benches (ISSUE 14).
+"""Deterministic storage emulation for tests (ISSUE 14).
 
 :class:`BandwidthLimitedFilesystem` emulates cold-object-store storage
 over any fsspec filesystem: every binary read streams chunk by chunk
@@ -6,10 +6,9 @@ paying ``bytes/bps`` of GIL-released sleep, and files at or above
 ``cold_threshold`` bytes additionally pay ``cold_latency`` once per open
 handle before their first read — a cold-tier GET/recall round trip.
 
-Promoted out of ``benchmark/hostplane`` (which re-exports it): it is the
-correctness harness for the ingest plane and the skew-scheduling leg,
-so it needs direct unit tests (``tests/test_emulation_fs.py``) instead
-of being exercised only by running the bench.
+It is the correctness harness of the ingest plane's and the adaptive
+scheduler's tests, and has unit tests of its own
+(``tests/test_emulation_fs.py``).
 """
 
 import time
@@ -71,7 +70,7 @@ class _BandwidthLimitedFile(object):
 class BandwidthLimitedFilesystem(object):
     """Delegating fsspec wrapper emulating cold-storage bandwidth: every
     binary read sleeps ``bytes/bps``.  The skew-scheduling and
-    object-store-ingest bench legs use it to make row groups
+    object-store-ingest tests use it to make row groups
     *fetch-dominated* — the latency parallelizes across worker/fetch
     threads like a real remote filesystem, independent of host core
     count (the cold-filesystem skew source from the adaptive scheduler's

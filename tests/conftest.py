@@ -178,3 +178,12 @@ def pytest_sessionfinish(session, exitstatus):
 @pytest.fixture(scope='session')
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture(params=[False, True], ids=['inline', 'pumped'])
+def transfer(request):
+    """The loader's two iteration paths, as its ``transfer=`` option: off
+    (``_iter_inline``, what ``'auto'`` resolves to on the CPU backend) and
+    on (``_iter_pumped``: dispatch thread and transfer plane, the path every
+    streaming cell of the benchmark runs on the chip)."""
+    return request.param

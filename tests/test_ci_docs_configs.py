@@ -102,132 +102,11 @@ def test_ci_tier1_names_its_slowest_tests():
     assert '--durations=25' in run_text
 
 
-def test_bench_compact_line_pins_shm_plane_fields():
-    """The shm result plane's evidence fields must ride the bench's
-    compact machine line — a rename would silently drop them from every
-    future BENCH_r{N}.json."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('ipc_bytes_per_s',
-                  'delivery_plane_processpool_images_per_sec_host_shm',
-                  'delivery_plane_processpool_images_per_sec_host_bytes',
-                  'delivery_plane_service_images_per_sec_host_w1_bytes'):
-        assert "'%s'" % field in block.group(1), field
-
-
-def test_bench_compact_line_pins_epoch_cache_fields():
-    """The epoch-cache plane's cold/warm evidence (ISSUE 3) and the
-    measured scan_batches stall must ride the compact machine line."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('epoch_cache_streaming_cold_images_per_sec',
-                  'epoch_cache_streaming_warm_images_per_sec',
-                  'epoch_cache_streaming_warm_over_cold',
-                  'epoch_cache_service_cold_images_per_sec',
-                  'epoch_cache_service_warm_images_per_sec',
-                  'epoch_cache_service_warm_over_cold',
-                  'stall_pct_epoch_cache_warm_scan',
-                  'stall_pct_streaming_scan'):
-        assert "'%s'" % field in block.group(1), field
-    # ...and the leg itself must be wired into BOTH main() paths (the
-    # shared host-leg table), not just defined.
-    assert re.search(r"_IPC_PLANE_LEGS = \((?:.|\n)*?epoch_cache_plane_leg",
-                     src), 'epoch_cache_plane_leg missing from the leg table'
-
-
-def test_bench_compact_line_pins_transfer_plane_fields():
-    """The transfer plane's evidence (ISSUE 6): coalesced/narrowed
-    delivered throughput vs the inline device_put baseline, the
-    bytes-on-wire ratio, and the bit-identity check must ride the
-    compact machine line, and the leg must sit in the shared host-leg
-    table so both main() paths run it."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('transfer_plane_images_per_sec_inline',
-                  'transfer_plane_images_per_sec_coalesced',
-                  'transfer_plane_images_per_sec_narrowed',
-                  'transfer_plane_coalesced_over_inline',
-                  'transfer_plane_narrowed_over_inline',
-                  'transfer_plane_wire_bytes_ratio',
-                  'transfer_plane_bit_identical'):
-        assert "'%s'" % field in block.group(1), field
-    assert re.search(r"_IPC_PLANE_LEGS = \((?:.|\n)*?transfer_plane_leg",
-                     src), 'transfer_plane_leg missing from the leg table'
-
-
-def test_bench_compact_line_pins_adaptive_sched_fields():
-    """The adaptive scheduler's evidence (ISSUE 9): fifo vs adaptive
-    epoch throughput on the skew-heavy dataset, the uniform-twin noise
-    control, and the delivery-order bit-identity check must ride the
-    compact machine line; the leg must sit in the shared host-leg table;
-    and the adaptive throughput must be trend-gated."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('adaptive_sched_images_per_sec_fifo',
-                  'adaptive_sched_images_per_sec_adaptive',
-                  'adaptive_sched_adaptive_over_fifo',
-                  'adaptive_sched_uniform_over_fifo',
-                  'adaptive_sched_delivery_identical'):
-        assert "'%s'" % field in block.group(1), field
-    assert re.search(r"_IPC_PLANE_LEGS = \((?:.|\n)*?adaptive_sched_leg",
-                     src), 'adaptive_sched_leg missing from the leg table'
-    from petastorm_tpu.benchmark import trend
-    assert 'adaptive_sched_images_per_sec_adaptive' in trend.TRACKED_FIELDS
-
-
-def test_bench_compact_line_pins_cluster_cache_fields():
-    """The cluster cache tier's evidence (ISSUE 10): the three fleet
-    rates (a lone cold decoder, the two-worker cold fleet, the
-    decoded-elsewhere fleet), both ratios, the mechanism counters, and
-    the in-leg bit-identity flag must ride the compact machine line;
-    the leg must sit in the shared host-leg table; and the warm rate
-    must be trend-gated."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('cluster_cache_images_per_sec_cold_join',
-                  'cluster_cache_images_per_sec_cold_fleet',
-                  'cluster_cache_images_per_sec_warm',
-                  'cluster_cache_warm_over_cold_join',
-                  'cluster_cache_warm_over_cold_fleet',
-                  'cluster_cache_remote_hits',
-                  'cluster_cache_peer_fills',
-                  'cluster_cache_peer_degraded',
-                  'cluster_cache_bit_identical'):
-        assert "'%s'" % field in block.group(1), field
-    assert re.search(r"_IPC_PLANE_LEGS = \((?:.|\n)*?cluster_cache_leg",
-                     src), 'cluster_cache_leg missing from the leg table'
-    from petastorm_tpu.benchmark import trend
-    assert 'cluster_cache_images_per_sec_warm' in trend.TRACKED_FIELDS
-
-
-def test_bench_compact_line_pins_object_store_ingest_fields():
-    """The ingest plane's evidence (ISSUE 14): sync vs plane cold-epoch
-    throughput, the ratio, the in-leg delivery-digest flag, and the
-    degrade count must ride the compact machine line; the leg must sit
-    in the shared host-leg table; the plane throughput must be
-    trend-gated; and the docs must carry the new kwargs/regime rows."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('object_store_ingest_images_per_sec_sync',
-                  'object_store_ingest_images_per_sec_plane',
-                  'object_store_ingest_plane_over_sync',
-                  'object_store_ingest_delivery_identical',
-                  'object_store_ingest_degraded'):
-        assert "'%s'" % field in block.group(1), field
-    assert re.search(
-        r"_IPC_PLANE_LEGS = \((?:.|\n)*?object_store_ingest_leg", src), \
-        'object_store_ingest_leg missing from the leg table'
-    from petastorm_tpu.benchmark import trend
-    assert 'object_store_ingest_images_per_sec_plane' in trend.TRACKED_FIELDS
+def test_docs_carry_ingest_plane_rows():
+    """ISSUE 14 docs: the ingest plane's kwargs, kill switch, regime and
+    counters stay documented."""
     perf = open(os.path.join(REPO, 'docs', 'performance.md')).read()
-    for needle in ('ingest_window', 'PETASTORM_TPU_NO_INGEST_PLANE',
-                   'object_store_ingest'):
+    for needle in ('ingest_window', 'PETASTORM_TPU_NO_INGEST_PLANE'):
         assert needle in perf, needle
     api = open(os.path.join(REPO, 'docs', 'api.md')).read()
     assert '`ingest`' in api and '`ingest_window`' in api
@@ -235,67 +114,6 @@ def test_bench_compact_line_pins_object_store_ingest_fields():
     for needle in ('fetch-bound', 'ingest_degraded', 'ingest_wait',
                    'sched_ingest_window'):
         assert needle in obs, needle
-
-
-def test_bench_compact_line_pins_provenance_fields():
-    """The provenance plane's overhead evidence (ISSUE 13): the
-    interleaved on/off rates and the derived overhead percentage must
-    ride the compact machine line (and through it the BENCH_HISTORY
-    trend store), and the leg must sit in the shared host-leg table."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('provenance_images_per_sec_on',
-                  'provenance_images_per_sec_off',
-                  'provenance_overhead_pct'):
-        assert "'%s'" % field in block.group(1), field
-    assert re.search(
-        r"_IPC_PLANE_LEGS = \((?:.|\n)*?provenance_overhead_leg", src), \
-        'provenance_overhead_leg missing from the leg table'
-
-
-def test_bench_compact_line_pins_control_plane_recovery_fields():
-    """The crash-survivable control plane's evidence (ISSUE 15):
-    dispatcher-restart time-to-first-batch cold vs ledger-restored, the
-    speedup ratio, and the in-leg exactly-once flag must ride the
-    compact machine line; the leg must sit in the shared host-leg
-    table; and the speedup must be trend-gated."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('control_plane_ttfb_cold_s',
-                  'control_plane_ttfb_restored_s',
-                  'control_plane_recovery_speedup',
-                  'control_plane_exactly_once'):
-        assert "'%s'" % field in block.group(1), field
-    assert re.search(
-        r"_IPC_PLANE_LEGS = \((?:.|\n)*?control_plane_recovery_leg", src), \
-        'control_plane_recovery_leg missing from the leg table'
-    from petastorm_tpu.benchmark import trend
-    assert 'control_plane_recovery_speedup' in trend.TRACKED_FIELDS
-
-
-def test_bench_compact_line_pins_multi_tenant_fields():
-    """The multi-tenant serving tier's evidence (ISSUE 16): warm-solo
-    vs duo fleet rates, the decode-bound fair-share ratio (WDRR weight
-    target 3.0), the co-tenant compounding ratio + remote-hit count,
-    and the in-leg exactly-once flag must ride the compact machine
-    line; the leg must sit in the shared host-leg table; and the
-    fair-share ratio must be trend-gated."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    for field in ('multi_tenant_images_per_sec_warm_solo',
-                  'multi_tenant_images_per_sec_duo',
-                  'multi_tenant_fair_share_ratio',
-                  'multi_tenant_duo_over_warm_solo',
-                  'multi_tenant_remote_hits',
-                  'multi_tenant_exactly_once'):
-        assert "'%s'" % field in block.group(1), field
-    assert re.search(r"_IPC_PLANE_LEGS = \((?:.|\n)*?multi_tenant_leg",
-                     src), 'multi_tenant_leg missing from the leg table'
-    from petastorm_tpu.benchmark import trend
-    assert 'multi_tenant_fair_share_ratio' in trend.TRACKED_FIELDS
 
 
 def test_docs_carry_tenancy_and_autoscaler_rows():
@@ -308,7 +126,6 @@ def test_docs_carry_tenancy_and_autoscaler_rows():
     for needle in ('Sharing a fleet', 'register_tenant_job',
                    'max_tenant_jobs', 'retry_after_s',
                    'tenant_shm_quota_bytes', 'tenant_cache_quota_bytes',
-                   'multi_tenant_fair_share_ratio',
                    'PETASTORM_TPU_NO_AUTOSCALE', '--autoscale',
                    'autoscale_storm'):
         assert needle in ds, needle
@@ -348,7 +165,7 @@ def test_docs_carry_control_plane_rows():
                    'dispatcher_ledger', 'drain_timeout_s',
                    'petastorm-tpu-chaos', 'PETASTORM_TPU_CHAOS',
                    'PETASTORM_TPU_NO_BACKOFF_JITTER',
-                   'control_plane_recovery_speedup', 'ledger_restores'):
+                   'ledger_restores'):
         assert needle in ds, needle
     obs = open(os.path.join(REPO, 'docs', 'observability.md')).read()
     for needle in ('control-plane-degraded', 'ledger_restores',
@@ -364,7 +181,7 @@ def test_docs_carry_provenance_plane_rows():
     hygiene sweep."""
     obs = open(os.path.join(REPO, 'docs', 'observability.md')).read()
     for needle in ('petastorm-tpu-explain', 'PETASTORM_TPU_NO_PROVENANCE',
-                   'provenance_overhead_pct', 'batch_slo_ms',
+                   'batch_slo_ms',
                    'sweep_dumps', 'provenance_slo_',
                    'test_top_json_golden_schema', 'dump_provenance'):
         assert needle in obs, needle
@@ -414,13 +231,9 @@ def test_docs_span_catalogue_synced_with_code():
 def test_cluster_cache_config_and_cli_surfaces():
     """ISSUE 10 entry-point-free surfaces: the ServiceConfig kwarg (and
     its job_info field), the dispatcher/worker CLI flags, the per-worker
-    plane-dir override, the doctor's --dispatcher flag, and the trend
-    integrity vocabulary (platform names only: bench.py labels a run
-    with the platform it really used, never with a story about another
-    one)."""
+    plane-dir override and the doctor's --dispatcher flag."""
     import inspect
 
-    from petastorm_tpu.benchmark import trend
     from petastorm_tpu.service import ServiceConfig, Worker
     from petastorm_tpu.service import cli as service_cli
 
@@ -440,14 +253,6 @@ def test_cluster_cache_config_and_cli_surfaces():
     doctor_src = open(os.path.join(
         REPO, 'petastorm_tpu', 'tools', 'doctor.py')).read()
     assert "'--dispatcher'" in doctor_src
-    bench_src = open(os.path.join(REPO, 'bench.py')).read()
-    assert trend.BACKEND_VOCABULARY == {'cpu', 'gpu', 'tpu'}
-    # bench.py writes 'backend' from the live platform, never from a
-    # string literal: a literal there is a label for a platform the run
-    # did not use.
-    assert not re.search(r"'backend':\s*'", bench_src)
-    assert "'backend': platform" in bench_src
-    assert 'fallback' not in bench_src.lower()
 
 
 def test_docs_conf_compiles_and_has_sphinx_settings():
@@ -487,9 +292,8 @@ def test_console_script_entry_points_resolve():
     assert len(lines) >= 8, lines  # reference-parity CLIs + data service
     names = [l.split('=', 1)[0].strip() for l in lines]
     assert 'petastorm-tpu-data-service' in names, names
-    # ISSUE 7: the diagnosis + perf-trend CLIs must stay registered
+    # ISSUE 7: the diagnosis CLI must stay registered
     assert 'petastorm-tpu-diagnose' in names, names
-    assert 'petastorm-tpu-bench-trend' in names, names
     # ISSUE 11: the deadlock-analysis CLI
     assert 'petastorm-tpu-lockdep' in names, names
     # ISSUE 13: the per-batch provenance explainer
@@ -624,15 +428,6 @@ def test_pyproject_carries_ruff_config():
     assert '"petastorm/**"' in src  # legacy alias package stays ignored
 
 
-def test_bench_compact_line_pins_telemetry_fields():
-    """The stall-attribution top component (ISSUE 5 satellite) must ride
-    the compact machine line next to the stall family it explains."""
-    src = open(os.path.join(REPO, 'bench.py')).read()
-    block = re.search(r'_COMPACT_KEYS = \((.*?)\n\)', src, re.S)
-    assert block, 'bench.py lost its _COMPACT_KEYS tuple'
-    assert "'stall_top_component'" in block.group(1)
-
-
 def test_ci_uploads_telemetry_dump_on_failure():
     """A red/hung tier-1 run must ship the conftest telemetry dump as an
     artifact (ISSUE 5 satellite) — the timeline IS the bug report for
@@ -644,15 +439,6 @@ def test_ci_uploads_telemetry_dump_on_failure():
     step = uploads[0]
     assert step.get('if') == 'failure()'
     assert 'test-artifacts' in step['with']['path']
-
-
-def test_ci_bench_trend_step_runs_bare_file():
-    """The bench-trend check (ISSUE 7) must run trend.py as a BARE FILE
-    from the checkout (stdlib-only, no package import) so it lives in
-    the no-install lint job — renaming the invocation must fail here."""
-    job = _load_ci()['jobs']['lint']
-    run_text = '\n'.join(s['run'] for s in job['steps'] if 'run' in s)
-    assert 'python petastorm_tpu/benchmark/trend.py --check' in run_text
 
 
 def test_docs_carry_lockdep_rule_catalogue_and_dump_rows():

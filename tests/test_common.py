@@ -87,3 +87,15 @@ def shm_residue(prefix=None):
     prefix = prefix or shm_plane.PREFIX
     return {f for f in os.listdir(shm_plane.SHM_DIR)
             if f.startswith(prefix)}
+
+
+def assert_iteration_path(loader, transfer):
+    """``loader`` took the iteration path the ``transfer`` fixture (conftest)
+    asked for: pumped, every batch crossed the transfer plane's ring and none
+    fell back to an inline put; inline, the plane carried nothing."""
+    counters = loader.metrics.as_dict()
+    if transfer:
+        assert counters.get('h2d_batches', 0) > 0, counters
+        assert counters.get('h2d_degraded', 0) == 0, counters
+    else:
+        assert counters.get('h2d_batches', 0) == 0, counters

@@ -1,4 +1,4 @@
-"""``enable_compile_cache``: one helper for chip_smoke.py, bench.py and the
+"""``enable_compile_cache``: one helper for chip_smoke.py and the
 example mains.  The directory can be placed from outside
 (``JAX_COMPILATION_CACHE_DIR``); otherwise it is one fixed path inside the
 checkout — never one built from ``tempfile``, a pid or the time, because the
@@ -61,19 +61,19 @@ def test_unset_the_cache_is_one_fixed_path_inside_the_checkout():
 
 
 def test_every_entry_point_uses_the_one_helper():
-    """chip_smoke.py, bench.py and the example mains call the helper; no file
-    of the repo sets a cache directory of its own."""
-    callers = ['chip_smoke.py', 'bench.py']
+    """chip_smoke.py and the example mains call the helper; no file of the
+    repo sets a cache directory of its own."""
+    callers = ['chip_smoke.py']
     for root, _, files in os.walk(os.path.join(REPO, 'examples')):
         callers += [os.path.relpath(os.path.join(root, f), REPO)
                     for f in files if f.endswith('.py')
                     and 'ensure_jax_backend()' in open(
                         os.path.join(root, f)).read()]
-    assert len(callers) == 10, callers
+    assert len(callers) == 9, callers
     for path in callers:
         assert 'enable_compile_cache()' in open(os.path.join(REPO, path)).read(), path
     setters = []
-    for top in ('petastorm_tpu', 'examples', 'bench.py', 'chip_smoke.py',
+    for top in ('petastorm_tpu', 'examples', 'chip_smoke.py',
                 '__graft_entry__.py'):
         paths = [os.path.join(REPO, top)] if top.endswith('.py') else [
             os.path.join(r, f) for r, _, fs in os.walk(os.path.join(REPO, top))

@@ -391,18 +391,22 @@ def test_dispatcher_sigkill_restart_completes_epoch_bit_identical(tmp_path):
     assert 'f' not in codes
 
 
+@pytest.mark.parametrize('ledger', [True, False], ids=['restored', 'cold'])
 def test_client_rides_through_dispatcher_outage_with_backoff(dataset_url,
-                                                             tmp_path):
+                                                             tmp_path, ledger):
     """A live client keeps polling through a dispatcher outage on the
     exponential discovery backoff (no 1 Hz hammer), then finishes the
     epoch against the restarted dispatcher — no resume token, no client
-    error."""
+    error.  With a ledger the new dispatcher goes on where the old one
+    stopped; without one it hands every split out again and the client
+    drops what it has already delivered: exactly once either way."""
     import socket
     import threading
     with socket.socket() as s:
         s.bind(('127.0.0.1', 0))
         addr = 'tcp://127.0.0.1:%d' % s.getsockname()[1]
-    config = _config(dataset_url, tmp_path)
+    config = _config(dataset_url, tmp_path, ledger_path=str(
+        tmp_path / 'ledger.json') if ledger else None)
     d1 = Dispatcher(config, bind=addr).start()
     worker = Worker(addr).start()
     ids = []
@@ -440,7 +444,7 @@ def test_client_rides_through_dispatcher_outage_with_backoff(dataset_url,
     assert sorted(ids) == list(range(ROWS))
     assert connection.retry_attempts >= 1, \
         'outage never exercised the discovery backoff'
-    assert d2.ledger_restores == 1
+    assert d2.ledger_restores == (1 if ledger else 0)
 
 
 def test_drain_rpc_reaches_worker_via_heartbeat(dataset_url, tmp_path):

@@ -1,8 +1,7 @@
 """Direct unit tests for ``test_util.emulation.BandwidthLimitedFilesystem``
-(ISSUE 14 satellite): promoted out of ``benchmark/hostplane`` because it
-is the correctness harness for the ingest plane and the skew leg — its
-cold-latency gate and bandwidth accounting must be pinned here, not only
-exercised by running the bench.
+(ISSUE 14 satellite): it is the correctness harness of the ingest plane's
+and the adaptive scheduler's tests, so its cold-latency gate and bandwidth
+accounting are pinned here.
 
 Sleeps are intercepted (monkeypatched ``time.sleep`` in the emulation
 module), so the tests are deterministic and instant.
@@ -36,14 +35,6 @@ def sleeps(monkeypatch):
     recorded = []
     monkeypatch.setattr(emulation.time, 'sleep', recorded.append)
     return recorded
-
-
-def test_reexported_from_hostplane_unchanged():
-    """The promotion must not fork the class: bench imports and
-    test_util imports are the SAME object."""
-    from petastorm_tpu.benchmark.hostplane import \
-        BandwidthLimitedFilesystem as bench_cls
-    assert bench_cls is BandwidthLimitedFilesystem
 
 
 def test_bandwidth_accounting_is_per_chunk(sleeps):

@@ -1,13 +1,11 @@
 """Fleet health & diagnosis plane (ISSUE 7).
 
-Covers the four tentpole pieces: the flight recorder (bounded ring,
+Covers the three tentpole pieces: the flight recorder (bounded ring,
 windowed deltas, persistence, pid-keyed singleton), the health engine
 (every regime classified from a synthetic fixture — these fixtures ARE
 the rule contract), the ``petastorm-tpu-diagnose`` CLI over all three
 input kinds (live fleet RPC, flight dump, watchdog artifact — including
-the end-to-end watchdog round-trip that pins the artifact schema), and
-the perf-trend store/gate (append, median check, noise band, gate
-flip-on at 3 rounds).
+the end-to-end watchdog round-trip that pins the artifact schema).
 """
 
 import json
@@ -540,127 +538,6 @@ def test_worker_clock_ewma_and_drift():
     before = worker.clock_offset
     worker._update_clock(100.0, 210.0, 210.0)
     assert abs(worker.clock_offset - before) < 2.0
-
-
-# -- perf-trend store + regression gate ---------------------------------------
-
-def _entry(value, **extra):
-    return dict({'value': value, 'metric': 'm', 'unit': 'images/s'},
-                **extra)
-
-
-def test_trend_append_and_round_numbering(tmp_path):
-    from petastorm_tpu.benchmark import trend
-    path = str(tmp_path / 'hist.jsonl')
-    first = trend.append_entry(_entry(100.0), path=path)
-    assert first['round'] == 1 and first['ts']
-    assert trend.append_entry(_entry(110.0), path=path)['round'] == 2
-    # degraded rounds do not append (they would poison the medians)
-    assert trend.append_entry(_entry(1.0, error='wedged'),
-                              path=path) is None
-    assert trend.append_entry(_entry(1.0, throughput_error='x'),
-                              path=path) is None
-    assert trend.append_entry(None, path=path) is None
-    assert len(trend.load_history(path)) == 2
-
-
-def test_trend_gate_flips_on_at_three_rounds(tmp_path):
-    from petastorm_tpu.benchmark import trend
-    path = str(tmp_path / 'hist.jsonl')
-    trend.append_entry(_entry(100.0), path=path)
-    trend.append_entry(_entry(104.0), path=path)
-    # 2 prior rounds: a 90% drop annotates but does NOT gate — and the
-    # per-field ok agrees with the exit code (below_floor carries the
-    # annotation)
-    report = trend.check(current=_entry(10.0), path=path)
-    assert report['ok'] and not report['fields']['value']['gating']
-    assert report['fields']['value']['below_floor']
-    assert report['fields']['value']['ok']
-    trend.append_entry(_entry(96.0), path=path)
-    # 3 prior rounds: the same drop now gates
-    report = trend.check(current=_entry(10.0), path=path)
-    assert not report['ok'] and report['regressions'] == ['value']
-    # within the ±30% noise band: fine
-    assert trend.check(current=_entry(71.0), path=path)['ok']
-
-
-def test_trend_integrity_rejects_fabricated_rounds(tmp_path, capsys):
-    """ISSUE 10 satellite: history may only grow through append_entry
-    at the end of a real bench.py run.  The two fabrication patterns
-    this repo's history actually carried — duplicate timestamps within
-    hand-copied trios, and truncated backend labels the emitter never
-    produces — must fail --check with exit 1, unconditionally (no
-    minimum-rounds grace)."""
-    import json
-
-    from petastorm_tpu.benchmark import trend
-    path = str(tmp_path / 'hist.jsonl')
-    trend.append_entry(_entry(100.0), path=path)
-    # A legitimate follow-up round appended the only legitimate way
-    # keeps the check green (ts stamps at microsecond resolution, so
-    # honest back-to-back appends never collide).
-    trend.append_entry(_entry(102.0), path=path)
-    assert trend.check(path=path)['integrity'] == []
-    # Hand-copy a round: same ts, truncated backend label.
-    rows = trend.load_history(path)
-    fake = dict(rows[-1], round=3, backend='cpu-fallback (...)')
-    with open(path, 'a') as f:
-        f.write(json.dumps(fake) + '\n')
-    report = trend.check(path=path)
-    assert not report['ok']
-    assert len(report['integrity']) == 2     # dup ts + bad label
-    assert any('duplicate ts' in v for v in report['integrity'])
-    assert any('not one bench.py emits' in v for v in report['integrity'])
-    assert trend.main(['--check', '--history', path]) == 1
-    assert 'INTEGRITY' in capsys.readouterr().out
-    # The real emitter vocabulary passes: every label bench.py produces.
-    for label in trend.BACKEND_VOCABULARY:
-        assert trend.check_integrity([
-            {'round': 1, 'ts': '2026-01-01T00:00:00Z',
-             'backend': label}]) == []
-
-
-def test_trend_cli_exit_codes_and_default_tail_mode(tmp_path, capsys):
-    from petastorm_tpu.benchmark import trend
-    path = str(tmp_path / 'hist.jsonl')
-    for v in (100.0, 102.0, 98.0, 101.0):
-        trend.append_entry(_entry(v), path=path)
-    # newest-vs-priors mode: healthy history exits 0
-    assert trend.main(['--check', '--history', path]) == 0
-    capsys.readouterr()
-    trend.append_entry(_entry(20.0), path=path)
-    rc = trend.main(['--check', '--history', path])
-    assert rc == 1
-    assert 'REGRESSION' in capsys.readouterr().out
-    # empty history: annotate, exit 0 (round 1 can never gate)
-    assert trend.main(['--check', '--history',
-                       str(tmp_path / 'none.jsonl')]) == 0
-    capsys.readouterr()
-    assert trend.main(['--check', '--history', path, '--current',
-                       str(tmp_path / 'missing.json')]) == 2
-
-
-def test_trend_is_stdlib_only_bare_file():
-    """The CI step runs trend.py as a bare file from the checkout
-    (before any install), like the lint gate — prove it imports nothing
-    beyond the stdlib even with the heavy deps blocked."""
-    probe = ('import runpy, sys\n'
-             'class Block:\n'
-             '    def find_module(self, name, path=None):\n'
-             '        base = name.split(".")[0]\n'
-             '        if base in ("numpy", "pyarrow", "jax", "zmq",\n'
-             '                    "petastorm_tpu"):\n'
-             '            raise ImportError("blocked: " + name)\n'
-             'sys.meta_path.insert(0, Block())\n'
-             'sys.argv = ["trend.py", "--check", "--history",\n'
-             '            "/nonexistent/h.jsonl"]\n'
-             'runpy.run_path(%r, run_name="__main__")\n'
-             % os.path.join(REPO, 'petastorm_tpu', 'benchmark', 'trend.py'))
-    out = subprocess.run([sys.executable, '-c', probe],
-                         capture_output=True, text=True, timeout=60)
-    # the file exits via sys.exit(main()) -> SystemExit(0) -> rc 0
-    assert out.returncode == 0, out.stderr
-    assert 'bench-trend' in out.stdout
 
 
 # -- control-plane-degraded regime + verdicts (ISSUE 15) ----------------------

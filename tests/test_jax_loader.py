@@ -13,7 +13,7 @@ from petastorm_tpu import make_batch_reader, make_reader
 from petastorm_tpu.jax import DataLoader
 from petastorm_tpu.parallel import data_parallel_sharding, make_mesh
 
-from test_common import create_test_dataset
+from test_common import assert_iteration_path, create_test_dataset
 
 
 @pytest.fixture(scope='module')
@@ -22,11 +22,12 @@ def dataset(tmp_path_factory):
     return create_test_dataset('file://' + str(path), num_rows=64, rows_per_rowgroup=8)
 
 
-def test_row_loader_yields_device_batches(dataset):
+def test_row_loader_yields_device_batches(dataset, transfer):
     with DataLoader(make_reader(dataset.url, reader_pool_type='dummy',
                                 shuffle_row_groups=False),
-                    batch_size=16) as loader:
+                    batch_size=16, transfer=transfer) as loader:
         batches = list(loader)
+    assert_iteration_path(loader, transfer)
     assert len(batches) == 4
     b = batches[0]
     assert isinstance(b['image_png'], jax.Array)
@@ -40,50 +41,57 @@ def test_row_loader_yields_device_batches(dataset):
                                   expected[int(ids[0])]['matrix'])
 
 
-def test_row_loader_all_rows_once(dataset):
+def test_row_loader_all_rows_once(dataset, transfer):
     with DataLoader(make_reader(dataset.url, reader_pool_type='thread', workers_count=4),
-                    batch_size=16) as loader:
+                    batch_size=16, transfer=transfer) as loader:
         ids = np.concatenate([np.asarray(b['id']) for b in loader])
+    assert_iteration_path(loader, transfer)
     assert sorted(ids.tolist()) == list(range(64))
 
 
-def test_columnar_loader_rebatches(dataset):
+def test_columnar_loader_rebatches(dataset, transfer):
     # batch reader yields 8-row chunks; loader re-batches to 10 with drop_last.
     with DataLoader(make_batch_reader(dataset.url, reader_pool_type='dummy',
                                       shuffle_row_groups=False),
-                    batch_size=10) as loader:
+                    batch_size=10, transfer=transfer) as loader:
         batches = list(loader)
+    assert_iteration_path(loader, transfer)
     assert len(batches) == 6  # 64 rows -> 6 full batches of 10
     for b in batches:
         assert np.asarray(b['id']).shape == (10,)
 
 
-def test_columnar_loader_keep_last(dataset):
+def test_columnar_loader_keep_last(dataset, transfer):
     with DataLoader(make_batch_reader(dataset.url, reader_pool_type='dummy'),
-                    batch_size=10, drop_last=False) as loader:
+                    batch_size=10, drop_last=False, transfer=transfer) as loader:
         sizes = [len(np.asarray(b['id'])) for b in loader]
+    assert_iteration_path(loader, transfer)
     assert sorted(sizes, reverse=True) == [10] * 6 + [4]
 
 
-def test_shuffling_changes_order_not_content(dataset):
+def test_shuffling_changes_order_not_content(dataset, transfer):
     with DataLoader(make_reader(dataset.url, reader_pool_type='dummy',
                                 shuffle_row_groups=False),
-                    batch_size=16, shuffling_queue_capacity=32, seed=5) as loader:
+                    batch_size=16, shuffling_queue_capacity=32, seed=5,
+                    transfer=transfer) as loader:
         shuffled = np.concatenate([np.asarray(b['id']) for b in loader])
+    assert_iteration_path(loader, transfer)
     assert sorted(shuffled.tolist()) == list(range(64))
     assert shuffled.tolist() != list(range(64))
 
 
-def test_columnar_shuffle(dataset):
+def test_columnar_shuffle(dataset, transfer):
     with DataLoader(make_batch_reader(dataset.url, reader_pool_type='dummy',
                                       shuffle_row_groups=False),
-                    batch_size=16, shuffling_queue_capacity=32, seed=5) as loader:
+                    batch_size=16, shuffling_queue_capacity=32, seed=5,
+                    transfer=transfer) as loader:
         ids = np.concatenate([np.asarray(b['id']) for b in loader])
+    assert_iteration_path(loader, transfer)
     assert sorted(ids.tolist()) == list(range(64))
     assert ids.tolist() != list(range(64))
 
 
-def test_transform_fn_casts(dataset):
+def test_transform_fn_casts(dataset, transfer):
     def to_bf16(batch):
         batch['matrix'] = batch['matrix'].astype('bfloat16') \
             if hasattr(batch['matrix'], 'astype') else batch['matrix']
@@ -96,8 +104,9 @@ def test_transform_fn_casts(dataset):
 
     with DataLoader(make_reader(dataset.url, schema_fields=['id', 'matrix'],
                                 reader_pool_type='dummy'),
-                    batch_size=8, transform_fn=cast) as loader:
+                    batch_size=8, transform_fn=cast, transfer=transfer) as loader:
         b = next(iter(loader))
+    assert_iteration_path(loader, transfer)
     np.testing.assert_array_equal(np.asarray(b['matrix']),
                                   np.ones((8, 8, 4), np.float32))
 
@@ -139,11 +148,12 @@ def test_global_sharded_batch_over_mesh(tmp_path):
     assert np.isfinite(float(val))
 
 
-def test_prefetch_pipeline_depth(dataset):
+def test_prefetch_pipeline_depth(dataset, transfer):
     with DataLoader(make_reader(dataset.url, reader_pool_type='dummy',
                                 shuffle_row_groups=False),
-                    batch_size=8, prefetch=3) as loader:
+                    batch_size=8, prefetch=3, transfer=transfer) as loader:
         batches = list(loader)
+    assert_iteration_path(loader, transfer)
     assert len(batches) == 8
     ids = np.concatenate([np.asarray(b['id']) for b in batches])
     np.testing.assert_array_equal(ids, np.arange(64))
@@ -171,24 +181,26 @@ def test_columnar_decode_fast_path(dataset):
                                   expected[int(chunks[0].id[3])]['matrix'])
 
 
-def test_columnar_decode_through_loader(dataset):
+def test_columnar_decode_through_loader(dataset, transfer):
     with DataLoader(make_reader(dataset.url, reader_pool_type='thread', workers_count=4,
                                 columnar_decode=True),
-                    batch_size=16) as loader:
+                    batch_size=16, transfer=transfer) as loader:
         ids = np.concatenate([np.asarray(b['id']) for b in loader])
+    assert_iteration_path(loader, transfer)
     assert sorted(ids.tolist()) == list(range(64))
 
 
-def test_per_stage_stats_and_pool_utilization(dataset):
+def test_per_stage_stats_and_pool_utilization(dataset, transfer):
     """SURVEY §5.1: per-stage timing on the loader + decode-plane
     utilization in reader diagnostics."""
     with make_reader(dataset.url, workers_count=2,
                      shuffle_row_groups=False) as reader:
         loader = DataLoader(reader, batch_size=16,
-                            transform_fn=lambda b: b)
+                            transform_fn=lambda b: b, transfer=transfer)
         n = sum(1 for _ in loader)
         diag = reader.diagnostics
     assert n == 4
+    assert_iteration_path(loader, transfer)
     stats = loader.stats
     assert stats['batches'] == 4
     assert stats['host_batch_s'] > 0.0
@@ -386,14 +398,15 @@ def test_device_inmem_scan_epochs_no_shuffle_order(dataset):
     np.testing.assert_array_equal(np.asarray(outs).ravel(), np.arange(64))
 
 
-def test_echo_repeats_batches(dataset):
+def test_echo_repeats_batches(dataset, transfer):
     """echo=2: every decoded batch is served twice consecutively (data
     echoing for decode-bound pipelines); works through __iter__ and
     scan_batches alike."""
     with make_reader(dataset.url, reader_pool_type='dummy',
                      shuffle_row_groups=False) as reader:
-        loader = DataLoader(reader, batch_size=16, echo=2)
+        loader = DataLoader(reader, batch_size=16, echo=2, transfer=transfer)
         ids = [np.asarray(b['id']) for b in loader]
+    assert_iteration_path(loader, transfer)
     assert len(ids) == 8  # 4 batches x 2 echoes
     for i in range(0, 8, 2):
         np.testing.assert_array_equal(ids[i], ids[i + 1])
@@ -405,10 +418,11 @@ def test_echo_repeats_batches(dataset):
 
     with make_reader(dataset.url, reader_pool_type='dummy',
                      shuffle_row_groups=False) as reader:
-        loader = DataLoader(reader, batch_size=16, echo=3)
+        loader = DataLoader(reader, batch_size=16, echo=3, transfer=transfer)
         chunks = list(loader.scan_batches(step, np.int32(0),
                                           steps_per_call=6,
                                           donate_carry=False))
+    assert_iteration_path(loader, transfer)
     assert int(np.asarray(chunks[-1][0])) == 12  # 4 batches x 3 echoes
     with pytest.raises(ValueError, match='echo'):
         with make_reader(dataset.url, reader_pool_type='dummy') as reader:
@@ -742,6 +756,32 @@ def test_disk_cache_reused_without_reader_work(dataset, tmp_path):
                                       expected[rid]['matrix'])
         np.testing.assert_array_equal(np.asarray(b0['image_png'][j]),
                                       expected[rid]['image_png'])
+
+
+def test_disk_cache_scan_batches_serves_what_iteration_serves(dataset, tmp_path):
+    """The fused driver over a complete decoded cache with no reader: the
+    chunks hold the seeded order ``__iter__`` serves, epoch after epoch."""
+    from petastorm_tpu.jax import DiskCachedDataLoader
+    cache = tmp_path / 'cscan'
+    with _disk_cached(dataset, cache, num_epochs=1) as loader:
+        list(loader)
+
+    def served():
+        return DiskCachedDataLoader(None, batch_size=16,
+                                    decoded_cache_dir=str(cache),
+                                    num_epochs=2, seed=3)
+
+    def step(carry, batch):
+        return carry + 1, batch['id']
+
+    with served() as loader:
+        want = [np.asarray(b['id']).tolist() for b in loader]
+    with served() as loader:
+        chunks = list(loader.scan_batches(step, np.int32(0), steps_per_call=3,
+                                          donate_carry=False))
+    got = [ids for _, outs in chunks for ids in np.asarray(outs).tolist()]
+    assert got == want and len(want) == 8
+    assert int(np.asarray(chunks[-1][0])) == 8
 
 
 def test_disk_cache_partial_build_is_rebuilt(dataset, tmp_path):

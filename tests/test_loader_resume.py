@@ -23,7 +23,7 @@ import pytest
 from petastorm_tpu import make_batch_reader, make_reader
 from petastorm_tpu.jax import DataLoader, PackedDataLoader
 
-from test_common import create_test_dataset
+from test_common import assert_iteration_path, create_test_dataset
 
 BATCH = 10
 ROWS = 64
@@ -117,13 +117,13 @@ def _run_interrupted(dataset, pool, k, loader_kwargs):
 
 
 @pytest.mark.parametrize('pool', ['dummy', 'thread', 'process'])
-def test_multiset_exactness_across_pools(dataset, pool, tmp_path):
+def test_multiset_exactness_across_pools(dataset, pool, tmp_path, transfer):
     """consumed ⊎ resumed == every row exactly twice (2 epochs) — nothing
     lost, nothing doubled, even with rows in flight in the pool at snapshot
     time.  drop_last=False so the invariant is order-independent (with a
     concurrent pool the *which-rows-land-in-the-tail* varies per run)."""
     loader_kwargs = {'seed': 5, 'shuffling_queue_capacity': 24,
-                     'drop_last': False}
+                     'drop_last': False, 'transfer': transfer}
     consumed, state = _run_interrupted(dataset, pool, 3, loader_kwargs)
     resumed = _resume_in_fresh_process(tmp_path, dataset, state, pool, {},
                                        loader_kwargs)
@@ -131,10 +131,11 @@ def test_multiset_exactness_across_pools(dataset, pool, tmp_path):
     assert got == sorted(list(range(ROWS)) * 2)
 
 
-def test_exact_order_for_seeded_dummy_pool(dataset, tmp_path):
+def test_exact_order_for_seeded_dummy_pool(dataset, tmp_path, transfer):
     """Deterministic pipeline: the resumed stream must be batch-for-batch
     identical to what the uninterrupted run had left."""
-    loader_kwargs = {'seed': 5, 'shuffling_queue_capacity': 24}
+    loader_kwargs = {'seed': 5, 'shuffling_queue_capacity': 24,
+                     'transfer': transfer}
     full = _run_uninterrupted(dataset, 'dummy', loader_kwargs)
     consumed, state = _run_interrupted(dataset, 'dummy', 3, loader_kwargs)
     assert consumed == full[:3]
@@ -143,8 +144,8 @@ def test_exact_order_for_seeded_dummy_pool(dataset, tmp_path):
     assert resumed == full[3:]
 
 
-def test_resume_without_shuffle_buffer(dataset, tmp_path):
-    loader_kwargs = {}
+def test_resume_without_shuffle_buffer(dataset, tmp_path, transfer):
+    loader_kwargs = {'transfer': transfer}
     full = _run_uninterrupted(dataset, 'dummy', loader_kwargs)
     consumed, state = _run_interrupted(dataset, 'dummy', 2, loader_kwargs)
     resumed = _resume_in_fresh_process(tmp_path, dataset, state, 'dummy', {},
@@ -152,10 +153,11 @@ def test_resume_without_shuffle_buffer(dataset, tmp_path):
     assert consumed + resumed == full
 
 
-def test_checkpoint_then_keep_training(dataset):
+def test_checkpoint_then_keep_training(dataset, transfer):
     """state_dict must not disturb the live run: the in-process stream
     continues exactly as if no snapshot had been taken."""
-    loader_kwargs = {'seed': 5, 'shuffling_queue_capacity': 24}
+    loader_kwargs = {'seed': 5, 'shuffling_queue_capacity': 24,
+                     'transfer': transfer}
     full = _run_uninterrupted(dataset, 'dummy', loader_kwargs)
     reader = _reader(dataset.url, 'dummy')
     with DataLoader(reader, batch_size=BATCH, **loader_kwargs) as loader:
@@ -164,19 +166,20 @@ def test_checkpoint_then_keep_training(dataset):
         loader.state_dict()   # snapshot mid-stream ...
         for b in it:          # ... and keep consuming
             got.append(np.asarray(b['id']).tolist())
+    assert_iteration_path(loader, transfer)
     assert got == full
 
 
-def test_columnar_reader_resume(dataset, tmp_path):
+def test_columnar_reader_resume(dataset, tmp_path, transfer):
     """make_batch_reader path: chunk residue rides the snapshot."""
     with DataLoader(make_batch_reader(dataset.url, reader_pool_type='dummy',
                                       shuffle_row_groups=False, num_epochs=1),
-                    batch_size=BATCH) as loader:
+                    batch_size=BATCH, transfer=transfer) as loader:
         full = [np.asarray(b['id']).tolist() for b in loader]
 
     reader = make_batch_reader(dataset.url, reader_pool_type='dummy',
                                shuffle_row_groups=False, num_epochs=1)
-    loader = DataLoader(reader, batch_size=BATCH)
+    loader = DataLoader(reader, batch_size=BATCH, transfer=transfer)
     it = iter(loader)
     consumed = [np.asarray(next(it)['id']).tolist() for _ in range(2)]
     state = loader.state_dict()
@@ -188,8 +191,10 @@ def test_columnar_reader_resume(dataset, tmp_path):
     # child uses make_reader; drive make_batch_reader inline instead
     reader2 = make_batch_reader(dataset.url, resume_state=state['reader'],
                                 **payload_kwargs)
-    with DataLoader(reader2, batch_size=BATCH, resume_state=state) as loader2:
+    with DataLoader(reader2, batch_size=BATCH, resume_state=state,
+                    transfer=transfer) as loader2:
         resumed = [np.asarray(b['id']).tolist() for b in loader2]
+    assert_iteration_path(loader2, transfer)
     assert consumed + resumed == full
 
 
@@ -228,7 +233,7 @@ class _SeqReader:
         self._inner.join()
 
 
-def test_packed_loader_resume_preserves_tokens(dataset):
+def test_packed_loader_resume_preserves_tokens(dataset, transfer):
     """Packer residue (open rows) must survive: token multiset across the
     remaining packed batches equals the uninterrupted run's remainder."""
     def seqs_of(batches):
@@ -244,7 +249,8 @@ def test_packed_loader_resume_preserves_tokens(dataset):
             num_epochs=1, resume_state=reader_resume))
         return reader, PackedDataLoader(reader, 'tokens', max_len=16,
                                         rows_per_batch=4, drop_last=False,
-                                        resume_state=resume)
+                                        resume_state=resume,
+                                        transfer=transfer)
 
     _, loader = build_loader()
     with loader:
@@ -260,6 +266,7 @@ def test_packed_loader_resume_preserves_tokens(dataset):
     _, loader2 = build_loader(resume=state, reader_resume=state['reader'])
     with loader2:
         resumed = list(loader2)
+    assert_iteration_path(loader2, transfer)
     assert seqs_of(consumed + resumed) == full
 
 
@@ -300,13 +307,13 @@ def test_disk_cached_loader_exact_resume(dataset, tmp_path):
 
 
 
-def test_state_dict_before_first_batch_preserves_restored_state(dataset,
-                                                                tmp_path):
+def test_state_dict_before_first_batch_preserves_restored_state(
+        dataset, tmp_path, transfer):
     """A checkpoint-every-N loop can land right after a restore, before the
     first next(): the re-snapshot must carry the restored rows forward, not
     silently drop them."""
     loader_kwargs = {'seed': 5, 'shuffling_queue_capacity': 24,
-                     'drop_last': False}
+                     'drop_last': False, 'transfer': transfer}
     consumed, state = _run_interrupted(dataset, 'dummy', 3, loader_kwargs)
 
     # restore, immediately re-checkpoint without consuming anything
@@ -325,7 +332,8 @@ def test_state_dict_before_first_batch_preserves_restored_state(dataset,
     assert got == sorted(list(range(ROWS)) * 2)
 
 
-def test_weighted_sampling_reader_resume_multiset(dataset, tmp_path):
+def test_weighted_sampling_reader_resume_multiset(dataset, tmp_path,
+                                                  transfer):
     """The mixed stream checkpoints too: constituent tokens + the draw
     rng + surviving-reader set.  exhaust='drop' delivers every row of
     every constituent exactly once, so consumed + resumed must equal the
@@ -351,7 +359,8 @@ def test_weighted_sampling_reader_resume_multiset(dataset, tmp_path):
     full = sorted(list(range(64)) + list(range(32)))
 
     mixed = build()
-    loader = DataLoader(mixed, batch_size=8, drop_last=False)
+    loader = DataLoader(mixed, batch_size=8, drop_last=False,
+                        transfer=transfer)
     it = iter(loader)
     consumed = [int(x) for _ in range(2) for x in np.asarray(next(it)['id'])]
     state = pickle.loads(pickle.dumps(loader.state_dict()))
@@ -359,8 +368,10 @@ def test_weighted_sampling_reader_resume_multiset(dataset, tmp_path):
     mixed.join()
 
     with DataLoader(build(mix_resume=state['reader']), batch_size=8,
-                    drop_last=False, resume_state=state) as loader2:
+                    drop_last=False, resume_state=state,
+                    transfer=transfer) as loader2:
         resumed = [int(x) for b in loader2 for x in np.asarray(b['id'])]
+    assert_iteration_path(loader2, transfer)
     assert sorted(consumed + resumed) == full
 
 

@@ -9,6 +9,8 @@ import jax.numpy as jnp
 from petastorm_tpu.jax import packing
 from petastorm_tpu.parallel import full_attention
 
+from test_common import assert_iteration_path
+
 
 def _random_seqs(rng, n, lo=3, hi=40):
     return [rng.integers(1, 1000, rng.integers(lo, hi + 1)).astype(np.int32)
@@ -265,7 +267,7 @@ def var_token_dataset(tmp_path_factory):
     return url, lengths
 
 
-def test_packed_loader_device_batches(var_token_dataset):
+def test_packed_loader_device_batches(var_token_dataset, transfer):
     from petastorm_tpu import make_reader
     from petastorm_tpu.jax import PackedDataLoader
 
@@ -273,7 +275,7 @@ def test_packed_loader_device_batches(var_token_dataset):
     with make_reader(url, schema_fields=['tokens'], num_epochs=1,
                      reader_pool_type='dummy', shuffle_row_groups=False) as r:
         loader = PackedDataLoader(r, 'tokens', max_len=64, rows_per_batch=4,
-                                  drop_last=False)
+                                  drop_last=False, transfer=transfer)
         seen = {}
         for batch in loader:
             assert isinstance(batch['tokens'], jax.Array)
@@ -286,10 +288,11 @@ def test_packed_loader_device_batches(var_token_dataset):
                     doc = int(vals[0])
                     assert (vals == doc).all()
                     seen[doc] = len(vals)
+    assert_iteration_path(loader, transfer)
     assert seen == lengths, 'every document must arrive intact exactly once'
 
 
-def test_packed_loader_sharded(var_token_dataset):
+def test_packed_loader_sharded(var_token_dataset, transfer):
     from jax.sharding import NamedSharding, PartitionSpec as P
     from petastorm_tpu import make_reader
     from petastorm_tpu.jax import PackedDataLoader
@@ -301,12 +304,17 @@ def test_packed_loader_sharded(var_token_dataset):
     with make_reader(url, schema_fields=['tokens'], num_epochs=1,
                      reader_pool_type='dummy') as r:
         loader = PackedDataLoader(r, 'tokens', max_len=64, rows_per_batch=4,
-                                  sharding=sharding)
+                                  sharding=sharding, transfer=transfer)
         n = 0
         for batch in loader:
             assert batch['tokens'].sharding == sharding
             n += 1
     assert n >= 1
+    # a spec that shards more than the batch axis is not the plane's: on
+    # the pumped path every batch rides the dispatch thread's inline put
+    counters = loader.metrics.as_dict()
+    assert counters.get('h2d_batches', 0) == 0
+    assert counters.get('h2d_degraded', 0) == (n if transfer else 0)
 
 
 def test_packed_loader_rejects_shuffle_queue(var_token_dataset):
@@ -330,7 +338,8 @@ def test_packed_loader_rejects_batch_reader(var_token_dataset):
             PackedDataLoader(r, 'tokens', 64, 4)
 
 
-def test_packed_loader_over_dataset_mixture(var_token_dataset, tmp_path):
+def test_packed_loader_over_dataset_mixture(var_token_dataset, tmp_path,
+                                            transfer):
     """LM-pretraining shape: WeightedSamplingReader mixes two corpora,
     PackedDataLoader packs the mixed stream."""
     from petastorm_tpu import make_reader
@@ -360,7 +369,7 @@ def test_packed_loader_over_dataset_mixture(var_token_dataset, tmp_path):
     from_a = from_b = 0
     with WeightedSamplingReader([ra, rb], [0.5, 0.5], seed=0) as mixed:
         loader = PackedDataLoader(mixed, 'tokens', max_len=64,
-                                  rows_per_batch=4)
+                                  rows_per_batch=4, transfer=transfer)
         for batch in loader:
             tok = np.asarray(batch['tokens'])
             seg = np.asarray(batch['segment_ids'])
@@ -373,6 +382,7 @@ def test_packed_loader_over_dataset_mixture(var_token_dataset, tmp_path):
                         from_b += 1
                     else:
                         from_a += 1
+    assert_iteration_path(loader, transfer)
     assert from_a > 5 and from_b > 5, (from_a, from_b)
 
 
@@ -393,7 +403,7 @@ def test_pack_stream_dtype_is_sticky_across_batches():
         [b['tokens'].dtype for b in batches]
 
 
-def test_packed_loader_scan_batches(tmp_path):
+def test_packed_loader_scan_batches(tmp_path, transfer):
     """PackedDataLoader composes with the fused scan driver: packed
     variable-length batches stream through one dispatch per k steps."""
     import numpy as np
@@ -421,11 +431,13 @@ def test_packed_loader_scan_batches(tmp_path):
     with make_reader(url, shuffle_row_groups=False,
                      reader_pool_type='dummy') as reader:
         loader = PackedDataLoader(reader, 'tokens', max_len=64,
-                                  rows_per_batch=4, drop_last=False)
+                                  rows_per_batch=4, drop_last=False,
+                                  transfer=transfer)
         carry = np.int32(0)
         for carry, _ in loader.scan_batches(step, carry, steps_per_call=2,
                                             donate_carry=False):
             pass
+    assert_iteration_path(loader, transfer)
     assert int(np.asarray(carry)) == total_tokens  # every token packed once
 
 
@@ -462,7 +474,7 @@ def test_packed_loader_takes_batch_size_like_every_loader(var_token_dataset):
             PackedDataLoader(r, 'tokens', 64)
 
 
-def test_packed_loader_carries_each_documents_id(var_token_dataset):
+def test_packed_loader_carries_each_documents_id(var_token_dataset, transfer):
     """``id_field``: one more fixed-shape leaf says which documents a batch
     holds and where each lies, on the device as on the host."""
     from petastorm_tpu import make_reader
@@ -473,7 +485,8 @@ def test_packed_loader_carries_each_documents_id(var_token_dataset):
     with make_reader(url, num_epochs=1, reader_pool_type='thread', workers_count=3,
                      seed=5) as r:
         with PackedDataLoader(r, 'tokens', max_len=64, batch_size=4,
-                              id_field='doc_id', drop_last=False) as loader:
+                              id_field='doc_id', drop_last=False,
+                              transfer=transfer) as loader:
             for batch in loader:
                 assert isinstance(batch['doc_ids'], jax.Array)
                 assert batch['doc_ids'].shape == (4, 64)
@@ -485,6 +498,7 @@ def test_packed_loader_carries_each_documents_id(var_token_dataset):
                 for doc_id, tokens in _documents_of(batch):
                     assert doc_id not in seen and (tokens == doc_id).all()
                     seen[doc_id] = len(tokens)
+    assert_iteration_path(loader, transfer)
     assert seen == lengths
 
 
@@ -495,7 +509,8 @@ def test_a_packer_is_fed_ids_for_every_sequence_or_none():
         packer.add(np.arange(5))
 
 
-def test_packed_loader_resumes_with_the_ids_it_held_back(var_token_dataset):
+def test_packed_loader_resumes_with_the_ids_it_held_back(var_token_dataset,
+                                                         transfer):
     from petastorm_tpu import make_reader
     from petastorm_tpu.jax import PackedDataLoader
 
@@ -506,7 +521,8 @@ def test_packed_loader_resumes_with_the_ids_it_held_back(var_token_dataset):
                              shuffle_row_groups=False, resume_state=reader_resume)
         return reader, PackedDataLoader(reader, 'tokens', max_len=64, batch_size=2,
                                         id_field='doc_id', drop_last=False,
-                                        open_rows=4, resume_state=resume)
+                                        open_rows=4, resume_state=resume,
+                                        transfer=transfer)
 
     reader, loader = build()
     it = iter(loader)
@@ -522,6 +538,7 @@ def test_packed_loader_resumes_with_the_ids_it_held_back(var_token_dataset):
     _, resumed = build(resume=state, reader_resume=state['reader'])
     with resumed:
         rest = list(resumed)
+    assert_iteration_path(resumed, transfer)
     seen = {}
     for batch in consumed + rest:
         for doc_id, tokens in _documents_of(batch):
@@ -530,7 +547,7 @@ def test_packed_loader_resumes_with_the_ids_it_held_back(var_token_dataset):
     assert seen == lengths
 
 
-def _delivered_ids_over_epochs(url, batches, monkeypatch=None):
+def _delivered_ids_over_epochs(url, batches, transfer, monkeypatch=None):
     from petastorm_tpu import make_reader
     from petastorm_tpu.jax import PackedDataLoader
     out = []
@@ -538,12 +555,13 @@ def _delivered_ids_over_epochs(url, batches, monkeypatch=None):
     # to hold several workers to exact epoch order
     with make_reader(url, num_epochs=None, reader_pool_type='dummy', seed=11) as r:
         with PackedDataLoader(r, 'tokens', max_len=64, batch_size=4,
-                              id_field='doc_id') as loader:
+                              id_field='doc_id', transfer=transfer) as loader:
             if monkeypatch is not None:
                 monkeypatch.setattr(loader, '_rows_an_epoch', lambda: None)
             for _, batch in zip(range(batches), loader):
                 out.append(packing.document_ids(batch))
             snapshot = loader.metrics.snapshot()
+    assert_iteration_path(loader, transfer)
     return out, snapshot
 
 
@@ -560,7 +578,8 @@ def _miscounted_at_every_prefix(ids_of_batches, stored):
 
 
 def test_packed_loader_closes_its_open_rows_at_an_epochs_end(var_token_dataset,
-                                                             monkeypatch):
+                                                             monkeypatch,
+                                                             transfer):
     """Epochs without end, across more than two epoch boundaries: after any
     number of batches every document has come ``n`` or ``n + 1`` times, which
     is what the benchmark's ``oracle.miscounted`` counts.  With the closing
@@ -568,16 +587,16 @@ def test_packed_loader_closes_its_open_rows_at_an_epochs_end(var_token_dataset,
     delivered, and the same count is not 0."""
     url, lengths = var_token_dataset
     stored = np.arange(len(lengths))
-    ids, _ = _delivered_ids_over_epochs(url, 24)
+    ids, _ = _delivered_ids_over_epochs(url, 24, transfer)
     assert sum(len(i) for i in ids) > 3 * len(stored)
     assert _miscounted_at_every_prefix(ids, stored) == [0] * len(ids)
-    ids, _ = _delivered_ids_over_epochs(url, 24, monkeypatch)
+    ids, _ = _delivered_ids_over_epochs(url, 24, transfer, monkeypatch)
     assert max(_miscounted_at_every_prefix(ids, stored)) > 0
 
 
-def test_packing_is_a_stage_with_counters(var_token_dataset):
+def test_packing_is_a_stage_with_counters(var_token_dataset, transfer):
     url, _ = var_token_dataset
-    ids, snap = _delivered_ids_over_epochs(url, 6)
+    ids, snap = _delivered_ids_over_epochs(url, 6, transfer)
     counters, pack = snap['counters'], snap['histograms']['pack']
     # the pump packs ahead of what was taken; 48 documents in 32 open rows
     # come out mostly at the epoch's end, several batches in one sample

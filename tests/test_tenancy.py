@@ -417,8 +417,11 @@ def test_single_tenant_default_config_parity(dataset_url, tmp_path):
 
 def test_two_tenants_share_one_worker_exactly_once(dataset_url, tmp_path):
     """Two tenants' loaders drain the SAME one-worker fleet
-    concurrently: each receives its whole dataset exactly once, and the
-    per-tenant rollups account for every grant."""
+    concurrently: each receives its whole dataset exactly once, bit for
+    bit what a direct read delivers, and the per-tenant rollups account
+    for every grant."""
+    from petastorm_tpu.test_util.chaos import (DeliveryDigest,
+                                               direct_read_digest)
     config = _config(dataset_url, tmp_path, ledger_path=None)
     with Dispatcher(config) as dispatcher:
         worker = Worker(dispatcher.addr).start()
@@ -428,6 +431,7 @@ def test_two_tenants_share_one_worker_exactly_once(dataset_url, tmp_path):
              'num_consumers': 1, 'reader_kwargs': {'workers_count': 1}},
             weight=3.0)
         ids = {'default': [], 'burst': []}
+        digests = {'default': DeliveryDigest(), 'burst': DeliveryDigest()}
         errors = []
 
         def pump(tenant):
@@ -440,6 +444,7 @@ def test_two_tenants_share_one_worker_exactly_once(dataset_url, tmp_path):
                     for batch in loader.iter_host_batches():
                         ids[tenant].extend(
                             np.asarray(batch['id']).tolist())
+                        digests[tenant].update(batch)
             except Exception as e:  # noqa: BLE001 — surface in-main
                 errors.append((tenant, e))
 
@@ -457,7 +462,46 @@ def test_two_tenants_share_one_worker_exactly_once(dataset_url, tmp_path):
     # Exactly once PER TENANT over the shared fleet.
     assert sorted(ids['default']) == list(range(ROWS))
     assert sorted(ids['burst']) == list(range(ROWS))
+    truth = direct_read_digest(dataset_url)
+    assert digests['default'].hexdigest() == truth
+    assert digests['burst'].hexdigest() == truth
     rows = stats['tenants']
     assert rows['default']['done'] == 8 and rows['burst']['done'] == 8
     assert rows['default']['grants'] >= 8
     assert rows['burst']['grants'] >= 8
+
+
+def test_co_tenant_on_a_decoded_dataset_rides_the_cache(dataset_url, tmp_path):
+    """A tenant registered on a fleet whose cache plane already holds its
+    dataset (another tenant's epoch decoded it) decodes nothing: every one
+    of its splits is served out of the plane, exactly once."""
+    plane = str(tmp_path / 'plane')
+    job = {'dataset_url': dataset_url, 'rowgroups_per_split': 2,
+           'num_consumers': 1, 'reader_kwargs': {'workers_count': 1},
+           'cache_plane': True, 'cache_plane_dir': plane}
+    config = _config(dataset_url, tmp_path, ledger_path=None,
+                     cache_plane=True, cache_plane_dir=plane)
+
+    def consume(addr, **kwargs):
+        ids = []
+        with ServiceDataLoader(addr, batch_size=8, consumer=0,
+                               drop_last=False, **kwargs) as loader:
+            for batch in loader.iter_host_batches():
+                ids.extend(np.asarray(batch['id']).tolist())
+        return sorted(ids)
+
+    with Dispatcher(config) as dispatcher:
+        worker = Worker(dispatcher.addr).start()
+        try:
+            assert consume(dispatcher.addr) == list(range(ROWS))
+            cold = dict(worker.diagnostics)
+            register_tenant_job(dispatcher.addr, 'burst', job, weight=3.0)
+            assert consume(dispatcher.addr, tenant='burst') \
+                == list(range(ROWS))
+            warm = dict(worker.diagnostics)
+        finally:
+            worker.stop()
+            worker.join()
+    assert cold['cache_misses'] == 16   # one decode a row group, once
+    assert warm['cache_misses'] == cold['cache_misses']
+    assert warm['cache_remote_hits'] - cold['cache_remote_hits'] == 16

@@ -14,7 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from petastorm_tpu import make_reader
+from petastorm_tpu import make_batch_reader, make_reader
 from petastorm_tpu.jax import DataLoader, DeviceInMemDataLoader
 from petastorm_tpu.jax.transfer import (KILL_SWITCH, TransferPlane,
                                         plane_enabled)
@@ -57,12 +57,8 @@ def test_plane_enabled_policy(monkeypatch):
 
 # -- coalesced slab round-trip ------------------------------------------------
 
-def test_coalesced_slab_pytree_roundtrip():
-    """Mixed-dtype nested pytree through pack → one device_put → jitted
-    on-device unpack equals jax.device_put bit-for-bit, canonicalization
-    included (int64 → int32 under default x64-disabled JAX)."""
-    rng = np.random.default_rng(0)
-    tree = {
+def _mixed_tree(rng):
+    return {
         'image': rng.integers(0, 256, (16, 8, 8, 3)).astype(np.uint8),
         'x': rng.standard_normal((16, 4)).astype(np.float32),
         'wide': rng.integers(-2 ** 50, 2 ** 50, (16,)).astype(np.int64),
@@ -70,6 +66,25 @@ def test_coalesced_slab_pytree_roundtrip():
         'small': rng.integers(-100, 100, (16,)).astype(np.int8),
         'nested': {'y': rng.standard_normal((16,)).astype(np.float64)},
     }
+
+
+def _wide_table_tree(rng):
+    """The regime coalescing is for: one image column beside 96 narrow
+    float columns and a label, 98 puts a batch without the plane."""
+    tree = {'image': rng.integers(0, 256, (64, 96, 96, 3)).astype(np.uint8),
+            'label': rng.integers(0, 1000, (64,)).astype(np.int64)}
+    for i in range(96):
+        tree['feat_%02d' % i] = rng.standard_normal((64, 16)).astype(np.float32)
+    return tree
+
+
+@pytest.mark.parametrize('make_tree', [_mixed_tree, _wide_table_tree],
+                         ids=['mixed', 'wide_table'])
+def test_coalesced_slab_pytree_roundtrip(make_tree):
+    """Mixed-dtype nested pytree through pack → one device_put → jitted
+    on-device unpack equals jax.device_put bit-for-bit, canonicalization
+    included (int64 → int32 under default x64-disabled JAX)."""
+    tree = make_tree(np.random.default_rng(0))
     plane = TransferPlane(ring_slots=2)
     _tree_equal(plane.put(tree), jax.device_put(tree))
     diag = plane.metrics.as_dict()
@@ -218,28 +233,60 @@ def test_unsupported_structure_degrades_transparently(dataset):
 
 # -- pumped DataLoader iteration ----------------------------------------------
 
-def test_pumped_loader_matches_inline(dataset):
+def _row_source(url, transfer):
+    return DataLoader(make_reader(url, reader_pool_type='dummy', seed=7),
+                      batch_size=16, shuffling_queue_capacity=24, seed=5,
+                      transfer=transfer)
+
+
+def _batch_source(url, transfer):
+    """The DLRM shape: a batch reader's many columns stacked into few
+    leaves by ``transform_fn`` on the pump thread."""
+    def stack(batch):
+        return {'id': batch['id'],
+                'dense': np.stack([batch['decimal_like'].astype(np.float32),
+                                   batch['id2'].astype(np.float32)], axis=1),
+                'matrix': batch['matrix']}
+
+    return DataLoader(make_batch_reader(url, reader_pool_type='dummy', seed=7),
+                      batch_size=10, drop_last=False, transform_fn=stack,
+                      shuffling_queue_capacity=24, seed=5, transfer=transfer)
+
+
+def _packed_source(url, transfer):
+    from petastorm_tpu.jax import PackedDataLoader
+    from test_loader_resume import _SeqReader
+    reader = _SeqReader(make_reader(url, reader_pool_type='dummy', seed=7,
+                                    num_epochs=1))
+    return PackedDataLoader(reader, 'tokens', max_len=16, batch_size=4,
+                            drop_last=False, transfer=transfer)
+
+
+@pytest.mark.parametrize('source', [_row_source, _batch_source, _packed_source],
+                         ids=['row', 'batch', 'packed'])
+def test_pumped_loader_matches_inline(dataset, source):
+    """The same seeded dataset through both iteration paths: the same
+    batches, bit for bit and leaf for leaf, in the same order, and on the
+    pumped path every one of them through the plane's ring."""
     def run(transfer):
-        with DataLoader(make_reader(dataset.url, reader_pool_type='dummy',
-                                    shuffle_row_groups=False),
-                        batch_size=16, transfer=transfer) as loader:
+        with source(dataset.url, transfer) as loader:
             return list(loader), dict(loader.diagnostics)
 
-    plain, _ = run(False)
+    plain, inline_diag = run(False)
     pumped, diag = run(True)
-    assert len(plain) == len(pumped) == 4
+    assert len(plain) == len(pumped) >= 4
     for a, b in zip(plain, pumped):
         assert set(a) == set(b)
         _tree_equal(a, b)
-    assert diag['h2d_batches'] == 4
+    assert 'h2d_batches' not in inline_diag  # the plane was never built
+    assert diag['h2d_batches'] == len(pumped)
     assert diag['h2d_degraded'] == 0
-    assert diag['batches'] == 4
-    assert diag['device_put_count'] == 4
+    assert diag['batches'] == diag['device_put_count'] == len(pumped)
 
 
 def test_pumped_loader_early_break_tears_down(dataset):
     """Abandoning iteration mid-stream must stop the dispatch thread and
-    leave the loader exitable (the bench legs break out of every loop)."""
+    leave the loader exitable (a training loop breaks out of it)."""
     with DataLoader(make_reader(dataset.url, reader_pool_type='dummy',
                                 shuffle_row_groups=False, num_epochs=None),
                     batch_size=16, transfer=True) as loader:
