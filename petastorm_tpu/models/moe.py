@@ -19,6 +19,24 @@ the XLA way:
 ``moe_apply`` is the single-device oracle (all experts everywhere);
 ``make_expert_parallel_moe`` returns the sharded twin + param shardings.
 Tested equal to the oracle (forward and gradients) on the CPU mesh.
+
+The second half of the module is another layer: **one chip's share of a
+top-k mixture with no capacity** (``moe_share_apply``, LFM2's expert layer;
+``models/transformer.py::MoEShare`` is its module).  The router scores all
+experts and picks ``top_k`` a token; the chip is told which experts it holds
+and returns what those add, by grouped products over rows sorted by expert
+(``jax.lax.ragged_dot``), so no token is dropped however skewed the routing.
+
+* **Budget** -- only assignments that fall on held experts are moved.  They
+  are laid in a buffer of a static size ``B`` (``share_budget``: twice the
+  fair share ``T * top_k * held / num_experts``, in whole row tiles): one
+  gather of ``[B, d]`` from the tokens, three grouped products over
+  ``[B, .]``, one add of ``[B, d]`` into ``[T, d]`` by token.
+* **Fallback** -- a step that routes more than ``B`` assignments to the held
+  experts takes the path over all ``T * top_k`` sorted rows (``lax.cond`` on
+  the count): the same sum, slower, nothing dropped.  ``stats['over_budget']``
+  counts it.  Where half of the experts or more are held, ``B`` is
+  ``T * top_k`` and there is no branch.
 """
 
 import functools
@@ -27,6 +45,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
@@ -236,6 +255,122 @@ def _permute_bwd(res, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+#: The buffer of the budgeted path holds this many times the assignments a
+#: share would get if the router spread them evenly over all experts.  Twice:
+#: the steps of ``lfm2.packed`` brought 0.92-1.18 of the fair share (PERF.md
+#: section 6, PR 31), a router in training drifts further than one twenty
+#: steps old, and a step that brings more loses nothing but the saving (it
+#: takes the path over all assignments, at more than twice this one's time).
+#: Not a knob: a constant with this reason.
+_BUDGET_FACTOR = 2
+#: Rows of the buffer come in multiples of this: the grouped product's row
+#: tile on the TPU (its tile list has 64 + 7 entries for 32,768 rows in 8
+#: groups: my chip run, PR 31).
+_ROW_TILE = 512
+
+
+def share_budget(tokens, top_k, held, num_experts):
+    """Rows of the buffer that :func:`moe_share_apply` lays the held experts'
+    assignments in: ``_BUDGET_FACTOR`` times the fair share
+    ``tokens * top_k * held / num_experts``, in whole row tiles, and never
+    more than all ``tokens * top_k`` assignments (then there is no buffer:
+    one path, no branch)."""
+    fair = -(-_BUDGET_FACTOR * tokens * top_k * held // num_experts)
+    return min(tokens * top_k, -(-fair // _ROW_TILE) * _ROW_TILE)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_of(x, token, tokens):
+    """``x[token]`` for ``x`` ``[tokens, d]``.  Its transpose adds the rows
+    back by token, accumulated in float32 whatever ``x`` is (a token may be
+    taken ``top_k`` times)."""
+    return x[token]
+
+
+def _rows_of_fwd(x, token, tokens):
+    return x[token], token
+
+
+def _rows_of_bwd(tokens, token, g):
+    added = _add_by_token(g.astype(jnp.float32), token, tokens)
+    return added.astype(g.dtype), None
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+def _add_by_token(rows, token, tokens):
+    """``out[t]`` = the sum of the ``rows`` whose ``token`` is ``t``: a
+    scatter-add of ``[B, d]`` into ``[tokens, d]`` (its transpose, which JAX
+    derives, is the gather ``g[token]``).  PERF.md section 6 (PR 31) has what
+    the other ways to add cost on the chip."""
+    return jnp.zeros((tokens, rows.shape[1]), rows.dtype).at[token].add(rows)
+
+
+#: What the budgeted path keeps for its backward pass: the gathered rows and
+#: the three grouped products' results.  Everything else (masks, SiLU, the
+#: weighted rows) is elementwise and recomputed there, so it never crosses
+#: the ``cond``'s boundary, where XLA would have to write each out in full.
+_KEPT = 'pt_moe_kept'
+_kept = functools.partial(checkpoint_name, name=_KEPT)
+
+
+def _swiglu_groups(xs, w1, w3, w2, group_sizes):
+    """SwiGLU of the held experts over rows sorted by expert, ``group_sizes``
+    of them to each; what it returns in the rows past the last group is 0."""
+    in_a_group = (jnp.arange(xs.shape[0]) < jnp.sum(group_sizes))[:, None]
+
+    def grouped(rows, matrices):
+        # rows past the last group belong to no held expert: the grouped
+        # product visits none of them, and what it leaves there, in the
+        # result as in the cotangent, is not data (on the TPU not even
+        # finite), so both are blanked
+        rows = jnp.where(in_a_group, rows, 0)
+        out = jax.lax.ragged_dot(rows, matrices, group_sizes)
+        return jnp.where(in_a_group, out, 0)
+    gate, up = _kept(grouped(xs, w1)), _kept(grouped(xs, w3))
+    return _kept(grouped(jax.nn.silu(gate) * up, w2))
+
+
+def _share_of_all(x, w1, w3, w2, weights, local, order, group_sizes):
+    """All ``T * top_k`` assignments, sorted, through the grouped products
+    and back to their tokens: the path of a step whose held assignments do
+    not fit the budget."""
+    tokens, top_k = weights.shape
+    held = group_sizes.shape[0]
+    with jax.named_scope('pt/moe_route'):
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        xs = _permute(jnp.repeat(x, top_k, axis=0), order, inverse)
+    with jax.named_scope('pt/moe_experts'):
+        ys = _swiglu_groups(xs, w1, w3, w2, group_sizes)
+    with jax.named_scope('pt/moe_combine'):
+        here = (local < held).reshape(tokens, top_k)
+        ya = _permute(ys, inverse, order).reshape(tokens, top_k, x.shape[1])
+        return jnp.sum(ya.astype(jnp.float32)
+                       * jnp.where(here, weights, 0.0)[:, :, None], axis=1)
+
+
+def _share_in_budget(budget, x, w1, w3, w2, weights, local, order,
+                     group_sizes):
+    """The same sum over the first ``budget`` places of ``order`` alone,
+    which hold every held assignment when ``sum(group_sizes) <= budget``:
+    no array here has ``T * top_k`` rows of a token's or an expert's width.
+    The places past the last group hold absent experts' assignments: their
+    rows are gathered and added too, as zeros."""
+    tokens, top_k = weights.shape
+    with jax.named_scope('pt/moe_route'):
+        first = order[:budget]
+        token = first // top_k
+        xs = _kept(_rows_of(x, token, tokens))
+    with jax.named_scope('pt/moe_experts'):
+        ys = _swiglu_groups(xs, w1, w3, w2, group_sizes)
+    with jax.named_scope('pt/moe_combine'):
+        gate = weights.reshape(-1)[first]
+        return _add_by_token(ys.astype(jnp.float32) * gate[:, None], token,
+                             tokens)
+
+
 def moe_share_apply(params, x, experts_held, top_k, expert_bias=None,
                     scale=1.0, eps=1e-6, dtype=None):
     """What the experts held here add to a top-k mixture's result.
@@ -248,15 +383,28 @@ def moe_share_apply(params, x, experts_held, top_k, expert_bias=None,
     ``E_i`` SwiGLU.  What the absent experts would add is left out: in an
     expert-parallel job the shares of all chips add up to the whole layer
     (``tests/test_lfm2.py`` holds that), and one chip alone runs without
-    the exchange.  There is no capacity and no token is dropped: all
-    ``T * top_k`` assignments are sorted by expert, those of held experts
-    first, and the held experts' matrices are applied as grouped products
-    over the ragged groups (``jax.lax.ragged_dot``: on the TPU a grouped
-    matmul that visits only the tiles of rows that belong to a group).
+    the exchange.  There is no capacity and no token is dropped.
+
+    The ``T * top_k`` assignments are sorted by expert as int32 keys, those
+    of held experts first; only rows of held experts are moved.  The first
+    ``B`` = :func:`share_budget` places of that order name the tokens whose
+    rows are gathered into a ``[B, d]`` buffer (one gather from ``[T, d]``),
+    the held experts' matrices are applied as grouped products over the
+    ragged groups (``jax.lax.ragged_dot``: on the TPU a grouped matmul that
+    visits only the tiles of rows that belong to a group), and the weighted
+    float32 rows are added into ``[T, d]`` by token.  A step that routes
+    more than ``B`` assignments to the held experts takes the path over all
+    ``T * top_k`` rows instead (``lax.cond`` on the count; the same sum, so
+    nothing is dropped and nothing approximated; it recomputes its forward
+    in the backward pass, so the step taken within budget saves no residual
+    of that size).  Where ``B`` would be ``T * top_k`` (half of the experts
+    or more are held) that path is the only one and there is no branch.
 
     Returns ``(y [T, d], stats)``; ``stats['tokens_per_expert']`` ``[H]`` is
-    how many tokens went to each held expert and ``stats['held_share']`` the
-    share of all assignments that fell on held experts.
+    how many tokens went to each held expert, ``stats['held_share']`` the
+    share of all assignments that fell on held experts and
+    ``stats['over_budget']`` (int32) 1 where this call took the path over
+    all assignments because they did not fit ``B``, else 0.
     """
     tokens, d_model = x.shape
     held = len(experts_held)
@@ -277,30 +425,24 @@ def moe_share_apply(params, x, experts_held, top_k, expert_bias=None,
         place[np.asarray(experts_held)] = np.arange(held)
         local = jnp.asarray(place)[experts].reshape(-1)          # [T * k]
         order = jnp.argsort(local, stable=True)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-        group_sizes = jnp.bincount(local, length=held + 1)[:held] \
-            .astype(jnp.int32)
-        in_a_group = jnp.arange(order.shape[0]) < jnp.sum(group_sizes)
-        xs = _permute(jnp.repeat(x.astype(dtype), top_k, axis=0), order,
-                      inverse)
-    with jax.named_scope('pt/moe_experts'):
-        w1, w3, w2 = (params[k].astype(dtype) for k in ('w1', 'w3', 'w2'))
-
-        def grouped(rows, matrices):
-            # rows past the last group belong to absent experts: the grouped
-            # product visits none of them, and what it leaves there, in the
-            # result as in the cotangent, is not data (on the TPU not even
-            # finite), so both are blanked
-            rows = jnp.where(in_a_group[:, None], rows, 0)
-            out = jax.lax.ragged_dot(rows, matrices, group_sizes)
-            return jnp.where(in_a_group[:, None], out, 0)
-        ys = grouped(jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3), w2)
-    with jax.named_scope('pt/moe_combine'):
-        here = (local < held).reshape(tokens, top_k)
-        ya = _permute(ys, inverse, order).reshape(tokens, top_k, d_model)
-        y = jnp.sum(ya.astype(jnp.float32)
-                    * jnp.where(here, weights, 0.0)[:, :, None], axis=1)
+        # a compare and a sum: ``bincount``'s scatter of T * k ones takes
+        # the chip longer than the sort above
+        group_sizes = jnp.sum(local[:, None] == jnp.arange(held), axis=0,
+                              dtype=jnp.int32)
+    operands = (x.astype(dtype),
+                *(params[k].astype(dtype) for k in ('w1', 'w3', 'w2')),
+                weights, local, order, group_sizes)
+    budget = share_budget(tokens, top_k, held, num_experts)
+    in_budget = jax.checkpoint(
+        functools.partial(_share_in_budget, budget),
+        policy=jax.checkpoint_policies.save_only_these_names(_KEPT))
+    over_budget = jnp.sum(group_sizes) > budget
+    if budget == tokens * top_k:            # never over: one path, no branch
+        y = in_budget(*operands)
+    else:
+        y = jax.lax.cond(over_budget, jax.checkpoint(_share_of_all), in_budget,
+                         *operands)
     stats = {'tokens_per_expert': group_sizes,
-             'held_share': jnp.sum(group_sizes) / (tokens * top_k)}
+             'held_share': jnp.sum(group_sizes) / (tokens * top_k),
+             'over_budget': over_budget.astype(jnp.int32)}
     return y.astype(x.dtype), stats

@@ -271,8 +271,9 @@ class MoEShare(nn.Module):
     """``models.moe.moe_share_apply`` as a module: the router over all
     ``num_experts``, the SwiGLU experts ``experts_held`` here, ``top_k`` a
     token, no capacity.  The selection bias is a buffer (collection
-    ``buffers``, no gradient); what was routed where is sown into the
-    collection ``diagnostics``."""
+    ``buffers``, no gradient); what was routed where
+    (``tokens_per_expert``, ``held_share``, ``over_budget``) is sown into
+    the collection ``diagnostics``."""
     num_experts: int
     top_k: int
     d_expert: int

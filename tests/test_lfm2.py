@@ -196,6 +196,135 @@ def test_no_token_is_dropped_under_skewed_routing(lfm2):
     assert not np.asarray(none).any() and int(stats['tokens_per_expert'].sum()) == 0
 
 
+def selection_bias(scores, held, by_all, by_none, some=None):
+    """A selection bias under which every token picks the experts ``by_all``
+    and no token any of ``by_none``; with ``some = (expert, n)`` exactly ``n``
+    tokens, those keenest on it, pick that held expert beside them
+    (``scores``: the router's, [T, E]; top-4)."""
+    bias = np.zeros(scores.shape[1], np.float32)
+    bias[list(by_all)], bias[list(by_none)] = 10.0, -10.0
+    if some:
+        expert, pickers = some
+        absent = [e for e in range(scores.shape[1]) if e not in held]
+        # it competes with the absent ones for the places ``by_all`` leave
+        rival = np.sort(np.asarray(scores)[:, absent], axis=1)[:, len(by_all) - 4]
+        need = np.sort(rival - np.asarray(scores)[:, expert])
+        bias[expert] = (need[pickers - 1] + need[pickers]) / 2
+    return jnp.asarray(bias)
+
+
+#: 512 tokens x top-4 = 2048 assignments, 16 experts.  Holding experts 4-7 the
+#: buffer has 2 x 2048 x 4 / 16 = 1024 rows; holding all there is no buffer.
+#: name: (held, picked by every token, picked by none, (expert, its pickers)
+#: or None, held assignments that makes)
+TOKENS = 512
+ROUTINGS = {
+    'over_the_budget': ((4, 5, 6, 7), (4, 5, 6, 7), (), None, 2048),
+    'one_over_the_budget': ((4, 5, 6, 7), (4, 5), (7,), (6, 1), 1025),
+    'exactly_at_the_budget': ((4, 5, 6, 7), (4, 5), (6, 7), None, 1024),
+    'one_under_the_budget': ((4, 5, 6, 7), (4,), (6, 7), (5, TOKENS - 1), 1023),
+    'chosen_by_no_token': ((4, 5, 6, 7), (), (4, 5, 6, 7), None, 0),
+    'as_the_router_likes': ((4, 5, 6, 7), (), (), None, None),
+    'all_experts_held': (tuple(range(16)), (), (), None, 2048),
+}
+
+
+@pytest.mark.parametrize('routing', sorted(ROUTINGS))
+def test_the_share_is_the_references_on_either_side_of_the_budget(lfm2, routing):
+    """Forward and gradients (input, router, ``w1``, ``w3``, ``w2``) against
+    the reference's experts, with the held assignments over, at, one off and
+    far under the buffer's rows.  Which path a call takes follows from the
+    routing alone (the selection bias), never from an argument."""
+    from petastorm_tpu.models import moe
+    held, by_all, by_none, some, count = ROUTINGS[routing]
+    config = config_of(lfm2, experts_held=list(held))
+    k, d, num_experts = config.top_k, config.hidden, config.num_experts
+    budget = moe.share_budget(TOKENS, k, len(held), num_experts)
+    assert (k, num_experts) == (4, 16)
+    assert budget == (TOKENS * k if len(held) == num_experts else 1024)
+    params = moe.moe_share_init(jax.random.PRNGKey(0), d, config.d_expert,
+                                num_experts, held)
+    x = jax.random.normal(jax.random.PRNGKey(1), (TOKENS, d))
+    scores = jax.nn.sigmoid(jnp.dot(x, params['router'], precision='highest'))
+    bias = selection_bias(scores, held, by_all, by_none, some)
+    probe = jax.random.normal(jax.random.PRNGKey(2), (TOKENS, d))
+
+    def program(p, x):
+        y, stats = moe.moe_share_apply(p, x, held, k, expert_bias=bias)
+        return jnp.sum(y * probe), (y, stats)
+
+    def reference(p, x):
+        y = config.reference_parts().experts(p, bias, x, jnp.ones((len(held),)),
+                                             held=held)
+        return jnp.sum(y * probe), y
+
+    (_, (y, stats)), got = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(params, x)
+    (_, want_y), want = jax.value_and_grad(
+        reference, argnums=(0, 1), has_aux=True)(params, x)
+    routed = int(stats['tokens_per_expert'].sum())
+    assert count is None or routed == count
+    assert int(stats['over_budget']) == int(routed > budget)
+    assert float(stats['held_share']) == pytest.approx(routed / (TOKENS * k))
+    close(y, want_y)
+    close(got[1], want[1])
+    for name in ('router', 'w1', 'w3', 'w2'):
+        close(got[0][name], want[0][name])
+    assert routed or not np.asarray(y).any()
+
+
+def rows_of_all_assignments(jaxpr, rows, widths, found=None, in_fallback=False):
+    """The arrays anywhere in ``jaxpr`` that have ``rows`` rows (all leading
+    axes together) of one of ``widths`` columns, as ``{'fallback': n,
+    'elsewhere': n, 'branches': n}``; the fallback is the branch a ``cond``
+    takes where its predicate holds."""
+    found = {'fallback': 0, 'elsewhere': 0, 'branches': 0} if found is None else found
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            shape = getattr(var.aval, 'shape', ())
+            if len(shape) >= 2 and shape[-1] in widths \
+                    and int(np.prod(shape[:-1])) == rows:
+                found['fallback' if in_fallback else 'elsewhere'] += 1
+        if eqn.primitive.name == 'cond':
+            found['branches'] += 1
+            within, beyond = eqn.params['branches']
+            rows_of_all_assignments(within.jaxpr, rows, widths, found, in_fallback)
+            rows_of_all_assignments(beyond.jaxpr, rows, widths, found, True)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            rows_of_all_assignments(sub, rows, widths, found, in_fallback)
+    return found
+
+
+@pytest.mark.parametrize('held', [(4, 5, 6, 7), tuple(range(16))],
+                         ids=['a_quarter_held', 'all_held'])
+def test_only_the_fallback_holds_rows_for_all_assignments(lfm2, held):
+    """A count the CPU may state: in the share's program, forward and
+    backward, at the tiny configuration's shapes (512 tokens x top-4), no
+    array outside the fallback branch has ``T * top_k`` rows of the model's or
+    an expert's width.  Where all experts are held there is no branch, and
+    the one path's buffer is all assignments."""
+    from petastorm_tpu.models import moe
+    config = config_of(lfm2, experts_held=list(held))
+    tokens, k = config.batch * config.max_len, config.top_k
+    d, f = config.hidden, config.d_expert
+    shapes = jax.eval_shape(lambda key: moe.moe_share_init(
+        key, d, f, config.num_experts, held), jax.random.PRNGKey(0))
+
+    def loss(p, x):
+        return jnp.sum(moe.moe_share_apply(p, x, held, k)[0])
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        shapes, jax.ShapeDtypeStruct((tokens, d), jnp.float32))
+    found = rows_of_all_assignments(jaxpr.jaxpr, tokens * k, (d, f))
+    if len(held) == config.num_experts:
+        assert moe.share_budget(tokens, k, len(held), config.num_experts) == tokens * k
+        assert found['branches'] == 0 and found['elsewhere'] > 0
+    else:
+        assert moe.share_budget(tokens, k, len(held), config.num_experts) == 1024
+        assert found['branches'] >= 2, found       # forward's and backward's
+        assert found['elsewhere'] == 0 and found['fallback'] > 0, found
+
+
 @pytest.mark.parametrize('cut', ['conv_experts', 'attention_experts'])
 def test_a_packed_row_equals_its_documents_one_by_one(lfm2, cut):
     """No leak: the convolution's taps and attention stop at document
