@@ -50,7 +50,21 @@ def cell(name, root=ROOT):
     module = _module(os.path.join(root, config['file'][:-len('.json')] + '.py'))
     traffic = _json(os.path.join(root, 'benchmarks', 'traffic',
                                  found['traffic'] + '.json'))
+    require(spec, traffic, config['file'])
     return found, spec, module, traffic
+
+
+def require(spec, traffic, file):
+    """Fails, naming the key, where the configuration's file lacks a key that
+    the traffic mix asks of it (``config_requires``, dotted paths)."""
+    for key in traffic.get('config_requires', []):
+        node = spec
+        for part in key.split('.'):
+            if not isinstance(node, dict) or part not in node:
+                raise SystemExit(
+                    'the traffic mix %r needs %r in %s: see benchmarks/README.md'
+                    % (traffic['name'], key, file))
+            node = node[part]
 
 
 def metric_module(name, root=ROOT):

@@ -189,6 +189,9 @@ class Config(object):
         self.rows_per_rowgroup = sizes['rows_per_rowgroup']
         self.length_law = (sizes['length_median'], sizes['length_sigma'],
                            sizes['length_min'], self.max_len)
+        #: fixes the documents' lengths and the order the reader delivers
+        #: them in: part of the configuration, the same in every run
+        self.layout_seed = sizes['layout_seed']
         self.zipf = sizes['token_zipf_exponent']
         self.compute_dtype = sizes['compute_dtype']
         self.optimizer = spec['optimizer']
@@ -205,14 +208,18 @@ class Config(object):
             UnischemaField('tokens', np.int32, (None,), NdarrayCodec(), False)])
 
     def write_dataset(self, path, seed):
-        """``rows`` documents: lengths from one stream, token ids from
-        another, both in bulk; one ``np.save`` cell a document, written with
-        pyarrow inside the package's own ``materialize_dataset_pyarrow`` stamp."""
+        """``rows`` documents: lengths from the configuration's
+        ``layout_seed`` (the same in every run), token ids from ``seed``, both
+        in bulk; one ``np.save`` cell a document, written with pyarrow inside
+        the package's own ``materialize_dataset_pyarrow`` stamp."""
         import pyarrow as pa
         import pyarrow.parquet as pq
         from petastorm_tpu.etl.dataset_metadata import materialize_dataset_pyarrow
 
-        lengths_seed, tokens_seed = np.random.SeedSequence(seed).spawn(2)
+        # the two streams a seed had before PR 33: layout_seed k draws the
+        # lengths that --seed k drew then
+        lengths_seed = np.random.SeedSequence(self.layout_seed).spawn(2)[0]
+        tokens_seed = np.random.SeedSequence(seed).spawn(2)[1]
         lengths = document_lengths(np.random.default_rng(lengths_seed), self.rows,
                                    *self.length_law)
         tokens = power_law_ids(np.random.default_rng(tokens_seed), self.vocab,
@@ -236,8 +243,11 @@ class Config(object):
                     row_group_size=self.rows_per_rowgroup)
 
     def open_reader(self, url, seed, num_epochs):
+        """Row groups shuffled by ``layout_seed``, not by the run's ``seed``:
+        every run packs the same lengths in the same order."""
         from petastorm_tpu import make_reader
-        return make_reader(url, num_epochs=num_epochs, seed=seed % (2 ** 31))
+        return make_reader(url, num_epochs=num_epochs,
+                           seed=self.layout_seed % (2 ** 31))
 
     def loader_kwargs(self):
         return {'tokens_field': 'tokens', 'id_field': 'doc_id',

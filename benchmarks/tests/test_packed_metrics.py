@@ -107,4 +107,4 @@ def test_the_flash_kernels_carry_the_names_the_reader_looks_for():
     source = inspect.getsource(sys.modules['petastorm_tpu.ops.flash_attention'])
     kernel = catalog.metric_module('flash_attention_roofline_pct').KERNEL
     for name in ('fwd', 'bwd_dq', 'bwd_dkv'):
-        assert "name='%s%s'" % (kernel, name) in source
+        assert "'%s%s'" % (kernel, name) in source

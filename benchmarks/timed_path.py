@@ -17,14 +17,25 @@ SAMPLED_BATCHES = 3
 STEP_NAME = 'pt_bench_train_step'
 
 
+def open_loader(config, traffic, data, seed, tiny=False):
+    """The system's reader over ``data`` inside the loader that the traffic
+    mix names, with the mix's and the configuration's arguments."""
+    import petastorm_tpu.jax as loaders
+    reader = config.open_reader('file://' + data, seed, traffic['reader_epochs'])
+    args = dict(traffic['loader_args'],
+                **(traffic.get('tiny_loader_args', {}) if tiny else {}))
+    args = {k: (seed % (2 ** 31) if v == '$seed' else v) for k, v in args.items()}
+    args.update(config.loader_kwargs())
+    return getattr(loaders, traffic['loader'])(
+        reader, batch_size=config.batch, **args)
+
+
 class TimedPath(object):
     def __init__(self, config, traffic, data, seed, key, tiny=False):
-        import jax
         self.config, self.traffic, self.key = config, traffic, key
         self.step, init_state, self.norms = self.programs()
         self.state = init_state(key)
-        reader = config.open_reader('file://' + data, seed, traffic['reader_epochs'])
-        self.loader = self.build_loader(reader, seed, tiny)
+        self.loader = open_loader(config, traffic, data, seed, tiny)
         #: every row id delivered from the first step on (device arrays)
         self.ids = []
         self.first_batches, self.first_losses = [], []
@@ -49,16 +60,6 @@ class TimedPath(object):
                     jax.jit(self.config.init_state), oracle.LeafNorms(self.config))
             self.config._timed_path_programs = made
         return made[1:]
-
-    def build_loader(self, reader, seed, tiny):
-        import petastorm_tpu.jax as loaders
-        traffic = self.traffic
-        args = dict(traffic['loader_args'],
-                    **(traffic.get('tiny_loader_args', {}) if tiny else {}))
-        args = {k: (seed % (2 ** 31) if v == '$seed' else v) for k, v in args.items()}
-        args.update(self.config.loader_kwargs())
-        return getattr(loaders, traffic['loader'])(
-            reader, batch_size=self.config.batch, **args)
 
     def __enter__(self):
         self.loader.__enter__()
