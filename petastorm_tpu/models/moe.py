@@ -261,7 +261,8 @@ _permute.defvjp(_permute_fwd, _permute_bwd)
 #: section 6, PR 31), a router in training drifts further than one twenty
 #: steps old, and a step that brings more loses nothing but the saving (it
 #: takes the path over all assignments, at more than twice this one's time).
-#: Not a knob: a constant with this reason.
+#: The default of ``budget_factor``; a configuration whose held share swings
+#: further states its own factor with the reading that set it.
 _BUDGET_FACTOR = 2
 #: Rows of the buffer come in multiples of this: the grouped product's row
 #: tile on the TPU (its tile list has 64 + 7 entries for 32,768 rows in 8
@@ -269,13 +270,13 @@ _BUDGET_FACTOR = 2
 _ROW_TILE = 512
 
 
-def share_budget(tokens, top_k, held, num_experts):
+def share_budget(tokens, top_k, held, num_experts, factor=_BUDGET_FACTOR):
     """Rows of the buffer that :func:`moe_share_apply` lays the held experts'
-    assignments in: ``_BUDGET_FACTOR`` times the fair share
+    assignments in: ``factor`` times the fair share
     ``tokens * top_k * held / num_experts``, in whole row tiles, and never
     more than all ``tokens * top_k`` assignments (then there is no buffer:
     one path, no branch)."""
-    fair = -(-_BUDGET_FACTOR * tokens * top_k * held // num_experts)
+    fair = -(-factor * tokens * top_k * held // num_experts)
     return min(tokens * top_k, -(-fair // _ROW_TILE) * _ROW_TILE)
 
 
@@ -372,7 +373,8 @@ def _share_in_budget(budget, x, w1, w3, w2, weights, local, order,
 
 
 def moe_share_apply(params, x, experts_held, top_k, expert_bias=None,
-                    scale=1.0, eps=1e-6, dtype=None):
+                    scale=1.0, eps=1e-6, dtype=None,
+                    budget_factor=_BUDGET_FACTOR):
     """What the experts held here add to a top-k mixture's result.
 
     ``x``: [T, d] tokens.  The router scores every token over ALL experts
@@ -387,7 +389,8 @@ def moe_share_apply(params, x, experts_held, top_k, expert_bias=None,
 
     The ``T * top_k`` assignments are sorted by expert as int32 keys, those
     of held experts first; only rows of held experts are moved.  The first
-    ``B`` = :func:`share_budget` places of that order name the tokens whose
+    ``B`` = :func:`share_budget` (``budget_factor`` times the fair share)
+    places of that order name the tokens whose
     rows are gathered into a ``[B, d]`` buffer (one gather from ``[T, d]``),
     the held experts' matrices are applied as grouped products over the
     ragged groups (``jax.lax.ragged_dot``: on the TPU a grouped matmul that
@@ -432,7 +435,7 @@ def moe_share_apply(params, x, experts_held, top_k, expert_bias=None,
     operands = (x.astype(dtype),
                 *(params[k].astype(dtype) for k in ('w1', 'w3', 'w2')),
                 weights, local, order, group_sizes)
-    budget = share_budget(tokens, top_k, held, num_experts)
+    budget = share_budget(tokens, top_k, held, num_experts, budget_factor)
     in_budget = jax.checkpoint(
         functools.partial(_share_in_budget, budget),
         policy=jax.checkpoint_policies.save_only_these_names(_KEPT))

@@ -16,14 +16,17 @@ TPU design notes:
   serves dense oracle, Pallas flash, ring (seq axis over ICI ring via
   ppermute), and Ulysses (all-to-all) without touching the module.
 * A layer is a token mixer and a feed-forward, each of a KIND: ``Block``
-  takes ``mixer`` ('attention' | 'conv', the gated short convolution) and
+  takes ``mixer`` ('attention' | 'conv', the gated short convolution | 'kda',
+  the gated delta rule with per-channel decays: :class:`DeltaAttention` |
+  'mla', latent attention without rotation: :class:`LatentAttention`) and
   ``ffn`` ('gelu' | 'swiglu' | 'moe', one chip's share of a top-k expert
-  layer: ``models.moe.moe_share_apply``), and ``TransformerLM`` a
-  ``layer_types`` pattern with ``num_dense_layers`` leading dense ones —
-  how LFM2-style hybrids (conv, conv, attention, conv; dense then experts)
-  are spelled.  The defaults are the classic attention + GELU block, with
-  the parameter tree unchanged.  In a packed row (``segment_ids``) neither
-  mixer reaches across a document boundary.
+  layer with, where the model has one, its shared expert:
+  ``models.moe.moe_share_apply``), and ``TransformerLM`` a ``layer_types``
+  pattern with ``num_dense_layers`` leading dense ones — how hybrids (conv,
+  conv, attention, conv, or three 'kda' to one 'mla'; dense then experts)
+  are spelled.  The defaults are the classic attention + GELU block with
+  the tied head, the parameter tree unchanged.  In a packed row
+  (``segment_ids``) no mixer reaches across a document boundary.
 * ``param_shardings`` maps the param pytree onto a mesh: attention/MLP
   input projections shard their *output* features over ``model``; output
   projections shard their *input* features — the Megatron sandwich, which
@@ -234,6 +237,24 @@ class Attention(nn.Module):
         return out.reshape(b, seq, h, hd).astype(q.dtype)
 
 
+def causal_taps(u, taps, segment_ids=None):
+    """``c_t = sum_k taps[k] * u_{t-k}``: a depthwise causal convolution over
+    ``u`` ``[b, s, channels]`` with ``taps`` ``[kernel, channels]`` (multiplied
+    in ``u``'s dtype).  In a packed row a tap that would reach into another
+    document or into padding (a different ``segment_id``, or 0) contributes
+    0: the segment rule of every convolution in this file."""
+    length = u.shape[1]
+    conv = u * taps[0].astype(u.dtype)
+    for k in range(1, taps.shape[0]):
+        shifted = jnp.pad(u, ((0, 0), (k, 0), (0, 0)))[:, :length]
+        if segment_ids is not None:
+            before = jnp.pad(segment_ids, ((0, 0), (k, 0)))[:, :length]
+            same = (segment_ids == before) & (segment_ids != 0)
+            shifted = jnp.where(same[:, :, None], shifted, 0)
+        conv = conv + shifted * taps[k].astype(u.dtype)
+    return conv
+
+
 class ShortConv(nn.Module):
     """Gated short convolution, LFM2's second kind of token mixer:
     ``[B, C, X] = split3(W_in x)``; ``u = B * X``; ``c_t = sum_k w_k *
@@ -246,25 +267,165 @@ class ShortConv(nn.Module):
 
     @nn.compact
     def __call__(self, x, segment_ids=None):
-        d_model, length = x.shape[-1], x.shape[1]
+        d_model = x.shape[-1]
         bcx = nn.Dense(3 * d_model, use_bias=False, dtype=self.dtype,
                        name='in_proj')(x)
         gate_b, gate_c, value = jnp.split(bcx, 3, axis=-1)
         taps = self.param('conv', nn.initializers.normal(
             1.0 / self.kernel ** 0.5), (self.kernel, d_model))
         with jax.named_scope('pt/lfm2_conv'):
-            u = gate_b * value
-            conv = u * taps[0].astype(self.dtype)
-            for k in range(1, self.kernel):
-                shifted = jnp.pad(u, ((0, 0), (k, 0), (0, 0)))[:, :length]
-                if segment_ids is not None:
-                    before = jnp.pad(segment_ids, ((0, 0), (k, 0)))[:, :length]
-                    same = (segment_ids == before) & (segment_ids != 0)
-                    shifted = jnp.where(same[:, :, None], shifted, 0)
-                conv = conv + shifted * taps[k].astype(self.dtype)
+            conv = causal_taps(gate_b * value, taps, segment_ids)
             gated = gate_c * conv
         return nn.Dense(d_model, use_bias=False, dtype=self.dtype,
                         name='out_proj')(gated)
+
+
+#: added to the sum of squares under the root of :class:`DeltaAttention`'s L2
+#: normalisation of q and k (the reference implementation's)
+L2_EPS = 1e-6
+
+
+class DeltaAttention(nn.Module):
+    """Kimi Delta Attention (KDA), the linear-attention mixer of Kimi-Linear:
+    ``q, k, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))`` of
+    ``heads x head_dim`` each (``conv``: :func:`causal_taps`, depthwise,
+    ``conv_kernel`` taps, no bias); q and k L2-normalised over a head, q
+    scaled by ``head_dim ** -0.5``; a log-decay for every key channel ``g =
+    -exp(A_log)[head] * softplus((h W_fa) W_fb + dt_bias)`` and a write
+    strength ``beta = sigmoid(h W_b)`` a head, both float32; the gated delta
+    rule ``ops.kda.kda_chunked`` (its ``head_dim x head_dim`` state restarts
+    at every document of a packed row); ``y = RMSNorm(o) * sigmoid((h W_ga)
+    W_gb + b_g)`` over a head (learned scale ``o_norm``; ``b_g`` is the
+    layer's one bias) and the output
+    ``concat(y) W_o``.  A recurrent state kept between calls (``decode``) is
+    serving, which is not built."""
+    num_heads: int
+    head_dim: int = 128
+    conv_kernel: int = 4
+    gate_rank: int = 128            # of the decay's and the output gate's pair
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    decode: bool = False
+    chunks_per_step: int = 2        # ``ops.kda.kda_chunked``
+
+    @nn.compact
+    def __call__(self, x, segment_ids=None):
+        if self.decode:
+            raise NotImplementedError(
+                'the delta-rule mixer has no decode path: a recurrent state '
+                'kept between calls is serving, which this model does not build')
+        from petastorm_tpu.ops.kda import kda_chunked
+        heads, hd = self.num_heads, self.head_dim
+        width = heads * hd
+        f32, highest = jnp.float32, jax.lax.Precision.HIGHEST
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype, name=name)
+        with jax.named_scope('pt/kda_project'):
+            projected = [dense(width, name)(x)
+                         for name in ('q_proj', 'k_proj', 'v_proj')]
+            # decays and write strengths in float32 at full precision: they
+            # are exponents, and a head forgets at the rate they say
+            x32 = x.astype(f32)
+            rank = nn.Dense(self.gate_rank, use_bias=False, dtype=f32,
+                            precision=highest, name='f_a')(x32)
+            step = nn.Dense(width, use_bias=False, dtype=f32, precision=highest,
+                            name='f_b')(rank)
+            dt_bias = self.param('dt_bias', nn.initializers.zeros, (width,))
+            a_log = self.param('A_log', nn.initializers.zeros, (heads,))
+            g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+                step + dt_bias).reshape(x.shape[:2] + (heads, hd))
+            beta = jax.nn.sigmoid(nn.Dense(
+                heads, use_bias=False, dtype=f32, precision=highest,
+                name='b_proj')(x32))
+            gate = nn.Dense(width, use_bias=True, dtype=self.dtype, name='g_b')(
+                dense(self.gate_rank, 'g_a')(x))
+        taps = [self.param(name, nn.initializers.normal(
+            1.0 / self.conv_kernel ** 0.5), (self.conv_kernel, width))
+            for name in ('q_conv', 'k_conv', 'v_conv')]
+
+        # the elementwise parts keep their inputs alone for the backward
+        # pass and are recomputed there: their float32 temporaries (five of
+        # [tokens, heads * head_dim]) would stand beside the whole layer's
+        @jax.checkpoint
+        def convolved(projected, taps):
+            with jax.named_scope('pt/kda_conv'):
+                q, k, v = (nn.silu(causal_taps(u, t, segment_ids)).reshape(
+                    x.shape[:2] + (heads, hd)) for u, t in zip(projected, taps))
+
+                def unit(y):
+                    y = y.astype(f32)
+                    return y * jax.lax.rsqrt(
+                        jnp.sum(jnp.square(y), -1, keepdims=True) + L2_EPS)
+                # normalised in float32, handed on in the compute dtype as v is
+                return ((unit(q) * hd ** -0.5).astype(self.dtype),
+                        unit(k).astype(self.dtype), v)
+
+        @jax.checkpoint
+        def gated(o, gate, scale):
+            with jax.named_scope('pt/kda_project'):
+                o = o.astype(f32)
+                var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                y = o * jax.lax.rsqrt(var + self.norm_eps) * scale
+                return (y * jax.nn.sigmoid(gate.astype(f32).reshape(o.shape))) \
+                    .astype(self.dtype).reshape(x.shape[:2] + (width,))
+        q, k, v = convolved(projected, taps)
+        with jax.named_scope('pt/kda_scan'):
+            o = kda_chunked(q, k, v, g, beta, segment_ids, dtype=self.dtype,
+                            chunks_per_step=self.chunks_per_step)
+        o_scale = self.param('o_norm', nn.initializers.ones, (hd,))
+        with jax.named_scope('pt/kda_project'):
+            return dense(x.shape[-1], 'o_proj')(gated(o, gate, o_scale))
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA) without a query latent and without
+    rotation (Kimi-Linear's ``mla_use_nope``): ``q = h W_q`` of ``heads x
+    (qk_nope_dim + qk_rope_dim)``; ``(c, k_b) = h W_kva`` of ``kv_rank +
+    qk_rope_dim``; ``(k_a, v) = RMSNorm(c) W_kvb`` of ``heads x (qk_nope_dim +
+    v_dim)``; the ONE ``k_b`` a token is shared by all heads; scores ``(q_a .
+    k_a + q_b . k_b) * (qk_nope_dim + qk_rope_dim) ** -0.5``; the output
+    ``concat(o) W_o`` from heads of ``v_dim``.  ``attn_fn`` gets q and k at
+    ``qk_nope_dim + qk_rope_dim`` and v at ``v_dim``, and the ``segment_ids``
+    of a packed row.  A latent cache (``decode``) is serving, which is not
+    built; a rotated ``k_b`` is not built either."""
+    num_heads: int
+    kv_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_dim: int
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    attn_fn: Callable = flash_attention
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, segment_ids=None):
+        if self.decode:
+            raise NotImplementedError(
+                'latent attention has no decode path: a latent cache is '
+                'serving, which this model does not build')
+        heads, nope, shared = self.num_heads, self.qk_nope_dim, self.qk_rope_dim
+        with jax.named_scope('pt/mla_project'):
+            q = nn.DenseGeneral((heads, nope + shared), axis=-1, use_bias=False,
+                                dtype=self.dtype, name='q')(x)
+            kv_a = nn.Dense(self.kv_rank + shared, use_bias=False,
+                            dtype=self.dtype, name='kv_a')(x)
+            latent = RMSNorm(self.norm_eps, name='kv_norm')(
+                kv_a[..., :self.kv_rank]).astype(self.dtype)
+            kv = nn.DenseGeneral((heads, nope + self.v_dim), axis=-1,
+                                 use_bias=False, dtype=self.dtype,
+                                 name='kv_b')(latent)
+            k_b = kv_a[:, :, None, self.kv_rank:]
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                k_b, k_b.shape[:2] + (heads, shared))], axis=-1)
+            v = kv[..., nope:]
+        packed = {} if segment_ids is None else {'segment_ids': segment_ids}
+        with jax.named_scope('pt/attention'):
+            out = self.attn_fn(q, k, v, causal=True, **packed)
+        with jax.named_scope('pt/mla_project'):
+            return nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
+                                   dtype=self.dtype, name='out')(out)
 
 
 class MoEShare(nn.Module):
@@ -273,13 +434,19 @@ class MoEShare(nn.Module):
     token, no capacity.  The selection bias is a buffer (collection
     ``buffers``, no gradient); what was routed where
     (``tokens_per_expert``, ``held_share``, ``over_budget``) is sown into
-    the collection ``diagnostics``."""
+    the collection ``diagnostics``.  ``d_shared`` > 0 adds the shared expert:
+    ONE dense SwiGLU of that width beside the routed share, which every chip
+    of an expert-parallel job computes alike and which counts once when the
+    shares are added."""
     num_experts: int
     top_k: int
     d_expert: int
     experts_held: Any = None        # global ids held here; None = all
     scale: float = 1.0
     dtype: Any = jnp.bfloat16
+    eps: float = 1e-6               # in the normaliser of the selected scores
+    d_shared: int = 0
+    budget_factor: int = 2          # ``models.moe.share_budget``
 
     @nn.compact
     def __call__(self, x):
@@ -295,10 +462,20 @@ class MoEShare(nn.Module):
                              (self.num_experts,), jnp.float32)
         y, stats = moe_share_apply(
             params, x.reshape(-1, d_model), held, self.top_k,
-            expert_bias=bias.value, scale=self.scale, dtype=self.dtype)
+            expert_bias=bias.value, scale=self.scale, eps=self.eps,
+            dtype=self.dtype, budget_factor=self.budget_factor)
         for name, value in stats.items():
             self.sow('diagnostics', name, value)
-        return y.reshape(x.shape)
+        y = y.reshape(x.shape)
+        if self.d_shared:
+            with jax.named_scope('pt/moe_shared'):
+                def dense(width, name):
+                    return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                                    name=name)
+                y = y + dense(d_model, 'shared_w2')(
+                    nn.silu(dense(self.d_shared, 'shared_w1')(x))
+                    * dense(self.d_shared, 'shared_w3')(x))
+        return y
 
 
 class Block(nn.Module):
@@ -311,8 +488,12 @@ class Block(nn.Module):
     max_decode_len: int = 2048
     num_kv_heads: Any = None
     pos_mode: Any = None
-    #: Token mixer: 'attention' or 'conv' (:class:`ShortConv`).
+    #: Token mixer: 'attention', 'conv' (:class:`ShortConv`), 'kda'
+    #: (:class:`DeltaAttention`, built from the ``kda`` dict of its sizes) or
+    #: 'mla' (:class:`LatentAttention`, from the ``mla`` dict).
     mixer: str = 'attention'
+    kda: Any = None
+    mla: Any = None
     #: Feed-forward: 'gelu' (two matrices with biases), 'swiglu'
     #: (``W2(silu(W1 h) * W3 h)``, no bias) or 'moe' (:class:`MoEShare`,
     #: built from the ``moe`` dict of its fields).
@@ -340,9 +521,17 @@ class Block(nn.Module):
         elif self.mixer == 'conv':
             x = x + ShortConv(self.conv_kernel, self.dtype,
                               name='conv')(h, segment_ids)
+        elif self.mixer == 'kda':
+            x = x + DeltaAttention(
+                dtype=self.dtype, decode=self.decode, norm_eps=self.norm_eps,
+                name='kda', **self.kda)(h, segment_ids)
+        elif self.mixer == 'mla':
+            x = x + LatentAttention(
+                dtype=self.dtype, attn_fn=self.attn_fn, decode=self.decode,
+                norm_eps=self.norm_eps, name='attn', **self.mla)(h, segment_ids)
         else:
-            raise ValueError("mixer must be 'attention' or 'conv', got %r"
-                             % (self.mixer,))
+            raise ValueError("mixer must be 'attention', 'conv', 'kda' or "
+                             "'mla', got %r" % (self.mixer,))
         h = RMSNorm(self.norm_eps, name='ln2')(x)
         if self.ffn == 'gelu':
             h = nn.Dense(self.d_ff, dtype=self.dtype, name='ffw_in')(h)
@@ -375,11 +564,19 @@ class TransformerLM(nn.Module):
     remat: bool = False  # jax.checkpoint each block: FLOPs for HBM
     decode: bool = False  # KV-cache incremental mode (models.decoding)
     num_kv_heads: Any = None  # GQA: KV heads < query heads (see Attention)
-    pos_embed: str = 'learned'  # 'learned' table | 'rope' rotary q/k
+    #: 'learned' table | 'rope' rotary q/k | 'none' (no positions at all: the
+    #: mixers 'kda' and 'mla' take none)
+    pos_embed: str = 'learned'
     #: The mixer of each layer, 'full_attention' or 'conv' (the names of
-    #: LFM2's ``layer_types``), ``num_layers`` long; None = attention
-    #: everywhere.
+    #: LFM2's ``layer_types``), 'kda' or 'mla' (built from the dicts ``kda``
+    #: and ``mla`` of :class:`DeltaAttention`'s and :class:`LatentAttention`'s
+    #: sizes), ``num_layers`` long; None = attention everywhere.
     layer_types: Any = None
+    kda: Any = None
+    mla: Any = None
+    #: False: an output head of its own (``lm_head``, no bias) in place of
+    #: the embedding's transpose.
+    tie_embedding: bool = True
     #: The feed-forward of the layers: 'gelu', 'swiglu', or 'moe' with the
     #: first ``num_dense_layers`` layers 'swiglu' of width ``d_ff`` and the
     #: others :class:`MoEShare` built from ``moe``.
@@ -397,11 +594,11 @@ class TransformerLM(nn.Module):
         """``positions`` overrides the default row-absolute ``arange``
         positions — pass ``packing.pack_*``'s per-segment ``positions`` so
         each packed document is embedded (or RoPE-rotated) as if it
-        started at 0.  ``segment_ids`` keeps both kinds of mixer inside each
+        started at 0.  ``segment_ids`` keeps every kind of mixer inside each
         packed document."""
-        if self.pos_embed not in ('learned', 'rope'):
-            raise ValueError("pos_embed must be 'learned' or 'rope', got %r"
-                             % (self.pos_embed,))
+        if self.pos_embed not in ('learned', 'rope', 'none'):
+            raise ValueError("pos_embed must be 'learned', 'rope' or 'none', "
+                             "got %r" % (self.pos_embed,))
         embed = nn.Embed(self.vocab_size, self.d_model, name='embed',
                          dtype=self.dtype)
         x = embed(tokens)
@@ -428,14 +625,18 @@ class TransformerLM(nn.Module):
                       decode=self.decode, max_decode_len=self.max_seq_len,
                       num_kv_heads=self.num_kv_heads, pos_mode=rope_mode,
                       mixer='attention' if kind == 'full_attention' else kind,
-                      ffn=ffn, moe=self.moe, rope_base=self.rope_base,
+                      ffn=ffn, moe=self.moe, kda=self.kda, mla=self.mla,
+                      rope_base=self.rope_base,
                       qk_norm=self.qk_norm, norm_eps=self.norm_eps,
                       use_bias=self.use_bias, conv_kernel=self.conv_kernel,
                       name='block_%d' % i)(x, positions, segment_ids)
         x = RMSNorm(self.norm_eps, name='ln_f')(x)
-        # Tied output head: attend() reuses the (vocab-sharded) embedding.
         with jax.named_scope('pt/lm_head_loss'):
-            return embed.attend(x.astype(self.dtype)).astype(jnp.float32)
+            if self.tie_embedding:
+                # attend() reuses the (vocab-sharded) embedding
+                return embed.attend(x.astype(self.dtype)).astype(jnp.float32)
+            return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
+                            name='lm_head')(x).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +671,36 @@ def _spec_for(path, model_axis):
         return P(None, model_axis) if leaf == 'kernel' else P(model_axis)
     if parent == 'ffw_out':
         return P(model_axis, None) if leaf == 'kernel' else P(None)
+    if parent == 'kv_b':
+        # latent -> heads: kernel [kv_rank, heads, nope + v_dim], shard heads
+        return P(None, model_axis, None)
+    if parent in _COLUMN_PARENTS:
+        # [d_model, width | heads * head_dim | vocab]: shard the outputs
+        return P(None, model_axis) if leaf == 'kernel' else P(model_axis)
+    if parent in _ROW_PARENTS:
+        return P(model_axis, None)             # [width, d_model]: the inputs
+    if parent == 'kda' and leaf in _KDA_CHANNEL_LEAVES:
+        # one entry a channel (heads * head_dim, heads for A_log), the last axis
+        return P(model_axis) if leaf in ('dt_bias', 'A_log') \
+            else P(None, model_axis)
     return P()                                 # norms & everything else: replicated
+
+
+#: The delta-rule mixer's projections to ``heads * head_dim`` channels (and
+#: ``b_proj`` to heads), the shared expert's two inputs and the untied head
+#: shard their outputs; ``o_proj`` and the shared expert's ``shared_w2``
+#: their inputs: the same sandwich, heads kept whole.
+_COLUMN_PARENTS = frozenset(['q_proj', 'k_proj', 'v_proj', 'f_b', 'g_b', 'b_proj',
+                             'shared_w1', 'shared_w3', 'lm_head'])
+_ROW_PARENTS = frozenset(['o_proj', 'shared_w2'])
+_KDA_CHANNEL_LEAVES = frozenset(['q_conv', 'k_conv', 'v_conv', 'dt_bias', 'A_log'])
+#: Replicated on purpose, by the parent's name: the latent projection
+#: ``kv_a`` (one latent a token, shared by all heads), the low-rank inputs
+#: ``f_a`` and ``g_a`` (their 128 outputs feed every head), every norm.
+REPLICATED_PARENTS = frozenset(['kv_a', 'f_a', 'g_a', 'kv_norm', 'ln1', 'ln2',
+                                'ln_f', 'q_norm', 'k_norm'])
+#: ... and by the leaf's: the delta-rule mixer's norm over a head's channels
+REPLICATED_LEAVES = frozenset(['o_norm'])
 
 
 def megatron_spec_fn(model_axis='model'):

@@ -47,6 +47,14 @@ sequence attention after the all-to-all) — the composition that makes long
 context cheap: Ulysses moves the data, this kernel keeps HBM traffic at
 O(seq · head_dim).
 
+**Two head sizes.**  q and k share one head size ``d`` and v, o, dO and dV
+another, ``d_v`` (a latent-attention layer has q/k of 128 + 64 = 192 and v of
+128): each array is laid in HBM and VMEM at its own size, the score products
+contract over ``d``, the value products over the tile, and the accumulators
+of o and dv are ``[block, d_v]``, those of dq and dk ``[block, d]``.  A call
+with ``d == d_v`` traces to what it traced to before there were two.  The
+default scale is ``d ** -0.5``, the q/k head size's.
+
 K and V are CHUNKED: each kernel call holds one ``kv_chunk`` (default sized
 from VMEM bytes, ``kv_chunk_default``) of K/V in VMEM, and chunks are folded at the XLA level with the same
 normalized-(output, lse) merge the ring fold uses — so a single device
@@ -197,7 +205,7 @@ def _fwd_kernel(*refs, scale, causal, seq_len, padded, block_q, block_k,
         q_ref, k_ref, v_ref, o_ref, lse_ref = refs
     qi = pl.program_id(1)
     q = q_ref[0]  # [block_q, d], fed to the MXU in the dtype it arrives in
-    d = q.shape[-1]
+    d_v = v_ref.shape[-1]    # v and o have a head size of their own
 
     # ``k_ref`` holds one K/V CHUNK starting at absolute position
     # ``k_start`` (k_start=0, kv_blocks=whole-sequence for the unchunked
@@ -252,7 +260,7 @@ def _fwd_kernel(*refs, scale, causal, seq_len, padded, block_q, block_k,
             preferred_element_type=jnp.float32)
         return o_new, l_new, m_new
 
-    o0 = jnp.zeros((block_q, d), jnp.float32)
+    o0 = jnp.zeros((block_q, d_v), jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
     m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
     o, l, m = jax.lax.fori_loop(first_kv, num_kv, body, (o0, l0, m0))
@@ -272,11 +280,11 @@ def _fwd(q3, k3, v3, seg3, seg3_k, kv_run, scale, causal, seq_len, block_q,
     (full length), ``seg3_k`` the k-side chunk slice, ``kv_run`` the flat
     ``(lo, hi)`` of ``_tile_bounds`` for each (batch row, q block)."""
     bh, seq_pad, d = q3.shape
-    kv_pad = k3.shape[1]
+    kv_pad, d_v = k3.shape[1], v3.shape[2]
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0)),
         pl.BlockSpec((1, kv_pad, d), lambda i, j, *_: (i, 0, 0)),
-        pl.BlockSpec((1, kv_pad, d), lambda i, j, *_: (i, 0, 0)),
+        pl.BlockSpec((1, kv_pad, d_v), lambda i, j, *_: (i, 0, 0)),
     ]
     args = [q3, k3, v3]
     if packed:
@@ -294,9 +302,9 @@ def _fwd(q3, k3, v3, seg3, seg3_k, kv_run, scale, causal, seq_len, block_q,
                           packed=packed, heads=heads, k_start=k_start,
                           kv_blocks=kv_pad // block_k),
         'pt_flash_fwd', (bh, seq_pad // block_q), in_specs,
-        [pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0)),
+        [pl.BlockSpec((1, block_q, d_v), lambda i, j, *_: (i, j, 0)),
          pl.BlockSpec((1, 1, block_q), lambda i, j, *_: (i, 0, j))],
-        [jax.ShapeDtypeStruct((bh, seq_pad, d), q3.dtype),
+        [jax.ShapeDtypeStruct((bh, seq_pad, d_v), q3.dtype),
          jax.ShapeDtypeStruct((bh, 1, seq_pad), jnp.float32)],
         kv_run, args, interpret)
 
@@ -430,8 +438,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, seq_len, padded, block_q, block_k,
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref = refs
     ki = pl.program_id(1)
     k = k_ref[0]  # [block_k, d]
-    v = v_ref[0]
-    d = k.shape[-1]
+    v = v_ref[0]  # [block_k, d_v]
 
     # ``q_ref``/``do_ref``/``lse_ref``/``delta_ref`` hold one Q chunk
     # (absolute start ``q_start``); k blocks are chunk-relative with
@@ -484,8 +491,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, seq_len, padded, block_q, block_k,
             preferred_element_type=jnp.float32)
         return dk, dv
 
-    zeros = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(q_begin, num_q, body, (zeros, zeros))
+    dk, dv = jax.lax.fori_loop(q_begin, num_q, body, (
+        jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -495,12 +502,12 @@ def _bwd_dq_call(q3, k_c, v_c, seg3, seg_k, kv_run, do3, lse, delta, scale,
                  k_start):
     """dQ contribution of one K/V chunk (full Q streamed block-by-block)."""
     bh, seq_pad, d = q3.shape
-    kv_pad = k_c.shape[1]
+    kv_pad, d_v = k_c.shape[1], v_c.shape[2]
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0)),
         pl.BlockSpec((1, kv_pad, d), lambda i, j, *_: (i, 0, 0)),
-        pl.BlockSpec((1, kv_pad, d), lambda i, j, *_: (i, 0, 0)),
-        pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0)),
+        pl.BlockSpec((1, kv_pad, d_v), lambda i, j, *_: (i, 0, 0)),
+        pl.BlockSpec((1, block_q, d_v), lambda i, j, *_: (i, j, 0)),
         pl.BlockSpec((1, 1, block_q), lambda i, j, *_: (i, 0, j)),
         pl.BlockSpec((1, 1, block_q), lambda i, j, *_: (i, 0, j)),
     ]
@@ -528,12 +535,12 @@ def _bwd_dkv_call(q_c, k_c, v_c, seg_q, seg_k, q_run, do_c, lse_c, delta_c,
                   interpret, q_start, k_start, seq_pad):
     """dK/dV contribution of one Q chunk against one K/V chunk."""
     bh, q_pad, d = q_c.shape
-    kv_pad = k_c.shape[1]
+    kv_pad, d_v = k_c.shape[1], v_c.shape[2]
     dkv_specs = [
         pl.BlockSpec((1, q_pad, d), lambda i, j, *_: (i, 0, 0)),
         pl.BlockSpec((1, block_k, d), lambda i, j, *_: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j, *_: (i, j, 0)),
-        pl.BlockSpec((1, q_pad, d), lambda i, j, *_: (i, 0, 0)),
+        pl.BlockSpec((1, block_k, d_v), lambda i, j, *_: (i, j, 0)),
+        pl.BlockSpec((1, q_pad, d_v), lambda i, j, *_: (i, 0, 0)),
         pl.BlockSpec((1, 1, q_pad), lambda i, j, *_: (i, 0, 0)),
         pl.BlockSpec((1, 1, q_pad), lambda i, j, *_: (i, 0, 0)),
     ]
@@ -553,9 +560,9 @@ def _bwd_dkv_call(q_c, k_c, v_c, seg_q, seg_k, q_run, do_c, lse_c, delta_c,
                           k_blocks_total=seq_pad // block_k),
         'pt_flash_bwd_dkv', (bh, kv_pad // block_k), dkv_specs,
         [pl.BlockSpec((1, block_k, d), lambda i, j, *_: (i, j, 0)),
-         pl.BlockSpec((1, block_k, d), lambda i, j, *_: (i, j, 0))],
+         pl.BlockSpec((1, block_k, d_v), lambda i, j, *_: (i, j, 0))],
         [jax.ShapeDtypeStruct((bh, kv_pad, d), k_c.dtype),
-         jax.ShapeDtypeStruct((bh, kv_pad, d), v_c.dtype)],
+         jax.ShapeDtypeStruct((bh, kv_pad, d_v), v_c.dtype)],
         q_run, dkv_args, interpret)
 
 
@@ -668,14 +675,20 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 KV_CHUNK_VMEM_BYTES = 8 << 20
 
 
-def kv_chunk_default(head_dim, dtype):
+def kv_chunk_default(head_dim, dtype, v_head_dim=None):
     """Rows of K/V resident per kernel call when ``kv_chunk`` is not given:
     the most that keeps double-buffered K and V inside
     ``KV_CHUNK_VMEM_BYTES`` (bf16 d=128: 8192 rows; f32 d=128: 4096).
     Sequences padded beyond this stream K/V in chunks of this many rows.
-    A row occupies whole 128-lane tiles in VMEM whatever ``head_dim`` is."""
-    lanes = -(-head_dim // 128) * 128
-    return KV_CHUNK_VMEM_BYTES // (2 * 2 * lanes * jnp.dtype(dtype).itemsize)
+    A row occupies whole 128-lane tiles in VMEM whatever ``head_dim`` is, and
+    K and V each their own where ``v_head_dim`` differs: q/k of 192 take 256
+    lanes and v of 128 takes 128, so bf16 at 192/128 holds 5461 rows (one
+    chunk for a row of 4096 tokens; 4096 rows if v were padded to 192).  The
+    dK/dV kernel's resident Q and dO chunk has the same two widths."""
+    def lanes(d):
+        return -(-d // 128) * 128
+    both = lanes(head_dim) + lanes(v_head_dim or head_dim)
+    return KV_CHUNK_VMEM_BYTES // (2 * both * jnp.dtype(dtype).itemsize)
 
 
 def block_default(seq_len):
@@ -702,7 +715,14 @@ def block_default(seq_len):
     Rectangles at the first shape: 256 x 512 54.4, 512 x 256 71.3, 512 x 1024
     52.5, 1024 x 512 55.3; 1024 x 1024 does not fit VMEM.  Neither
     ``head_dim`` nor the dtype nor packing moved the winner, so the rule
-    reads the length alone."""
+    reads the length alone.
+
+    Read again at two head sizes (PR 34; bf16 q/k ``[2, 8192, 32, 192]``, v
+    ``[2, 8192, 32, 128]``, packed documents, K/V in two chunks of 5,120 rows;
+    wall ms of a jitted forward and backward call, the copies around the
+    kernels included): 512 x 512 35.0, 256 x 512 34.4, 512 x 256 38.2, 256 x
+    256 37.1; 512 x 1024 and 1024 x 512 do not fit VMEM.  The winner is where
+    it was to 2 %."""
     for block in (512, 256, 128):
         if seq_len <= block or -seq_len % block * 8 <= seq_len:
             break
@@ -713,6 +733,10 @@ def block_default(seq_len):
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, segment_ids=None, kv_chunk=None):
     """Flash attention over ``[batch, seq, heads, head_dim]`` inputs.
+
+    ``v`` may have a head size of its own (``[batch, seq, heads, d_v]``; q
+    and k share theirs): the output then has ``d_v``, and nothing is padded
+    to the other's size in HBM.
 
     Drop-in for ``petastorm_tpu.parallel.full_attention`` (same signature and
     semantics, O(seq) memory).  Differentiable via the flash backward
@@ -742,10 +766,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if q.ndim != 4:
         raise ValueError('expected [batch, seq, heads, head_dim], got %r' % (q.shape,))
     b, seq_len, h, d = q.shape
-    kv_len = k.shape[1]
+    kv_len, d_v = k.shape[1], v.shape[3]
     if kv_len != seq_len:
         raise ValueError('flash_attention requires seq_q == seq_kv (got %d vs %d)'
                          % (seq_len, kv_len))
+    if k.shape != q.shape or v.shape[:3] != q.shape[:3]:
+        raise ValueError('q and k must have one shape and v their batch, seq '
+                         'and heads; got q %r, k %r, v %r'
+                         % (q.shape, k.shape, v.shape))
     packed = segment_ids is not None
     if packed and tuple(segment_ids.shape) != (b, seq_len):
         raise ValueError('segment_ids must be [batch, seq] = %r, got %r'
@@ -769,7 +797,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     seq_pad = -(-seq_len // lcm) * lcm
 
     def to3(x):
-        x = jnp.moveaxis(x, 2, 1).reshape(b * h, seq_len, d)
+        x = jnp.moveaxis(x, 2, 1).reshape(b * h, seq_len, x.shape[3])
         if seq_pad != seq_len:
             x = jnp.pad(x, ((0, 0), (0, seq_pad - seq_len), (0, 0)))
         return x
@@ -783,7 +811,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         seg3 = None
 
     if kv_chunk is None:
-        kv_chunk = kv_chunk_default(d, q.dtype)
+        kv_chunk = kv_chunk_default(d, q.dtype, d_v)
     if kv_chunk == 0:
         kv_chunk = None      # explicit 0: whole-K/V residency, no streaming
     else:
@@ -794,5 +822,5 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     out = _flash(to3(q), to3(k), to3(v), seg3, scale, causal, seq_len,
                  block_q, block_k, packed, h, kv_chunk)
-    out = out[:, :seq_len].reshape(b, h, seq_len, d)
+    out = out[:, :seq_len].reshape(b, h, seq_len, d_v)
     return jnp.moveaxis(out, 1, 2)

@@ -490,3 +490,54 @@ def test_32k_tokens_stream_through_chunks(rng):
     got = flash_attention(*qkv, kv_chunk=4096, **kw)
     want = flash_attention(*qkv, kv_chunk=0, **kw)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize('kv_chunk', [None, 32], ids=['whole', 'chunked'])
+def test_two_head_sizes_match_a_dense_softmax(rng, kv_chunk):
+    """q and k at one head size (24, as a latent-attention layer's 128 + 64)
+    and v, the output and their cotangents at another (16): forward and every
+    gradient against a dense masked softmax, packed and causal, K/V whole and
+    streamed in chunks.  Nothing is padded to the other's size."""
+    b, s, h, d, d_v = 2, 96, 2, 24, 16
+    q, k = (jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((b, s, h, d_v)), jnp.float32)
+    seg = np.zeros((b, s), np.int32)
+    seg[:, :40], seg[:, 40:43], seg[:, 43:80] = 1, 2, 3     # tail stays padding
+    seg = jnp.asarray(seg)
+    dout = jnp.asarray(rng.standard_normal((b, s, h, d_v)), jnp.float32)
+
+    def dense(q, k, v):
+        at = jnp.arange(s)
+        mask = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] != 0) \
+            & (at[None, :, None] >= at[None, None, :])
+        scores = jnp.einsum('bqhd,bkhd->bhqk', q, k, precision='highest') * d ** -0.5
+        scores = jnp.where(mask[:, None], scores, -jnp.inf)
+        scores = jnp.where(mask.any(-1)[:, None, :, None], scores, 0.0)
+        weights = jnp.where(mask[:, None], jax.nn.softmax(scores, -1), 0.0)
+        return jnp.einsum('bhqk,bkhd->bqhd', weights, v, precision='highest')
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, segment_ids=seg, block_q=32,
+                               block_k=32, kv_chunk=kv_chunk)
+    got = flash(q, k, v)
+    assert got.shape == (b, s, h, d_v)
+    np.testing.assert_allclose(got, dense(q, k, v), atol=2e-5, rtol=2e-5)
+    gg = jax.grad(lambda *t: (flash(*t) * dout).sum(), argnums=(0, 1, 2))(q, k, v)
+    gw = jax.grad(lambda *t: (dense(*t) * dout).sum(), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(gg, gw):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=3e-5, rtol=3e-5)
+
+
+def test_q_and_k_share_a_shape_and_v_their_rows(rng):
+    """One head size for q, k and v sizes the resident K/V chunk as it did
+    before there were two; a k of another head size than q's, or a v of
+    another length, is refused."""
+    from petastorm_tpu.ops.flash_attention import kv_chunk_default
+    q, k, v = _qkv(rng, s=64)
+    assert kv_chunk_default(128, jnp.bfloat16) == kv_chunk_default(128, jnp.bfloat16, 128)
+    with pytest.raises(ValueError, match='one shape'):
+        flash_attention(q, k[..., :8], v)
+    with pytest.raises(ValueError, match='one shape'):
+        flash_attention(q, k, v[:, :32])

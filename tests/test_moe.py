@@ -87,3 +87,25 @@ def test_indivisible_experts_rejected():
     mesh = make_mesh({'expert': 8})
     with pytest.raises(ValueError, match='divisible'):
         make_expert_parallel_moe(mesh, num_experts=6)
+
+
+def test_the_share_budgets_factor_defaults_to_twice_the_fair_share():
+    """``budget_factor`` at its default leaves the share's program what it
+    was (the lowered text of a call that names no factor and of one that names
+    2 are the same), and a larger factor only widens the buffer."""
+    from petastorm_tpu.models import moe
+    assert moe.share_budget(32768, 4, 8, 64) == moe.share_budget(32768, 4, 8, 64, 2) \
+        == 32768
+    assert moe.share_budget(16384, 8, 8, 256) == 8192
+    assert moe.share_budget(16384, 8, 8, 256, 3) == 12288
+    assert moe.share_budget(64, 2, 8, 8, 3) == 128          # never over all of them
+    held = (2, 3)
+    shapes = jax.eval_shape(lambda key: moe.moe_share_init(key, D, F, E, held),
+                            jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((1024, D), jnp.float32)
+
+    def lowered(**kw):
+        return jax.jit(lambda p, x: moe.moe_share_apply(p, x, held, 2, **kw)) \
+            .lower(shapes, x).as_text()
+    assert lowered() == lowered(budget_factor=2)
+    assert lowered() != lowered(budget_factor=3)

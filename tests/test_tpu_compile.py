@@ -260,6 +260,9 @@ def test_lfm2_step_compiles_at_its_published_sizes_inside_the_chips_memory(
     for kernel in ('pt_flash_fwd', 'pt_flash_bwd_dq', 'pt_flash_bwd_dkv',
                    'ragged-dot'):
         assert kernel in text, kernel
+    # no loop: ``kda_scan_ms`` reads a step's ``while`` operations as the delta
+    # rule's, and this step has none
+    assert ' while(' not in text
     m = compiled.memory_analysis()
     state = m.argument_size_in_bytes
     peak = state + m.output_size_in_bytes - m.alias_size_in_bytes \
@@ -267,3 +270,72 @@ def test_lfm2_step_compiles_at_its_published_sizes_inside_the_chips_memory(
     # parameters and Adam's two moments in float32: 12 B a parameter
     assert state == pytest.approx(12 * config.parameter_count(), rel=0.01)
     assert 0.6 * V5E_BYTES_LIMIT < peak < V5E_BYTES_LIMIT, peak
+
+
+#: ``kimilinear.packed``'s own call: 2 packed rows of 8,192 tokens, 32 heads,
+#: q and k of 128 + 64, v of 128.
+LATENT_SHAPES = ((2, 8192, 32, 192), (2, 8192, 32, 128))
+
+
+@pytest.mark.parametrize('mode', ['packed_fwd', 'packed'])
+def test_flash_attention_compiles_at_two_head_sizes(one_chip, flash, mode):
+    """q/k at 192 (one and a half lane tiles) and v at 128, neither padded to
+    the other: the three kernels lower through Mosaic at 512 x 512 tiles.  K
+    at 256 lanes and V at 128 hold 5,461 rows in the kernels' VMEM share, so
+    a row of 8,192 tokens streams them in two chunks."""
+    from petastorm_tpu.ops.flash_attention import kv_chunk_default
+    qk, v = (jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+             for shape in LATENT_SHAPES)
+    assert kv_chunk_default(192, 'bfloat16', 128) == 5461
+    assert kv_chunk_default(128, 'bfloat16') == kv_chunk_default(128, 'bfloat16', 128) \
+        == 8192
+    seg = jax.ShapeDtypeStruct(LATENT_SHAPES[0][:2], jnp.int32, sharding=one_chip)
+    compiled = jax.jit(_flash_program(flash, mode)).lower(qk, qk, v, seg).compile()
+    text = compiled.as_text()
+    assert 'pt_flash_fwd' in text
+    assert ('pt_flash_bwd_dkv' in text) == (mode == 'packed')
+
+
+def test_kimi_linear_step_compiles_at_its_published_sizes_inside_the_chips_memory(
+        one_chip, flash):
+    """``kimilinear.packed``'s step as the benchmark jits it (state donated, 2
+    packed rows of 8,192 tokens, published layers 1-5 at every published
+    width, recomputation a layer): the flash kernels at 192 / 128 and the
+    grouped expert products lower under the names the per-layer metrics read,
+    the delta rule's scans are the step's only loops (a forward and a backward
+    one for each pass of each of the four KDA layers), and parameters, Adam's
+    moments and the step's temporaries fit the chip."""
+    import json
+    import os
+    import re
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, 'benchmarks')
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import catalog
+    base = os.path.join(bench, 'configs', 'kimi-linear-48b-a3b')
+    with open(base + '.json') as f:
+        config = catalog._module(base + '.py').Config(json.load(f))
+    assert (config.batch, config.max_len, len(config.layers)) == (2, 8192, 5)
+    name, step, shapes, donated = config.rehearsal_programs(
+        jax.eval_shape(lambda: jax.random.key(0)))[0]
+    assert name == 'step'
+    placed = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    compiled = jax.jit(step, donate_argnums=donated).lower(*placed).compile()
+    text = compiled.as_text()
+    for kernel in ('pt_flash_fwd', 'pt_flash_bwd_dq', 'pt_flash_bwd_dkv',
+                   'ragged-dot'):
+        assert kernel in text, kernel
+    loops = re.findall(r'^\s*(?:ROOT )?%?(while[.\w]*) = .* while\(', text, re.M)
+    # forward, the layer's recomputed forward, and the backward: 3 x 4 layers
+    assert len(loops) == 12, loops
+    m = compiled.memory_analysis()
+    state = m.argument_size_in_bytes
+    peak = state + m.output_size_in_bytes - m.alias_size_in_bytes \
+        + m.temp_size_in_bytes
+    assert state == pytest.approx(12 * config.parameter_count(), rel=0.01)
+    # 14.54 GB with the flash kernels through Mosaic, as here and on the chip
+    # (``rehearse_compile.py`` interprets them and counts 14.20): a sixth layer
+    # (+1.65 GB of state and gradient) would not fit
+    assert 0.6 * V5E_BYTES_LIMIT < peak < 14.7e9, peak
