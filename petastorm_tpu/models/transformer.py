@@ -294,7 +294,8 @@ class DeltaAttention(nn.Module):
     -exp(A_log)[head] * softplus((h W_fa) W_fb + dt_bias)`` and a write
     strength ``beta = sigmoid(h W_b)`` a head, both float32; the gated delta
     rule ``ops.kda.kda_chunked`` (its ``head_dim x head_dim`` state restarts
-    at every document of a packed row); ``y = RMSNorm(o) * sigmoid((h W_ga)
+    at every document of a packed row; Pallas kernels where ``head_dim`` is a
+    multiple of 128, else the same chunk function under a scan); ``y = RMSNorm(o) * sigmoid((h W_ga)
     W_gb + b_g)`` over a head (learned scale ``o_norm``; ``b_g`` is the
     layer's one bias) and the output
     ``concat(y) W_o``.  A recurrent state kept between calls (``decode``) is
@@ -306,7 +307,7 @@ class DeltaAttention(nn.Module):
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     decode: bool = False
-    chunks_per_step: int = 2        # ``ops.kda.kda_chunked``
+    chunks_per_step: int = 2        # between two states kept: ``ops.kda.kda_chunked``
 
     @nn.compact
     def __call__(self, x, segment_ids=None):

@@ -1,5 +1,7 @@
 """Kimi-Linear's layers (``ops/kda.py``: the gated delta rule with per-channel
-decays, token by token and chunked; ``models/transformer.py``: the mixers
+decays, token by token and chunked, the chunked form as its Pallas kernels
+through the interpreter and as the plain ``vmap`` + ``lax.scan`` path;
+``models/transformer.py``: the mixers
 ``'kda'`` and ``'mla'``, the shared expert, the untied head) against the plain
 float32 reference that the benchmark keeps in
 ``benchmarks/configs/kimi-linear-48b-a3b.py``, at small sizes on the CPU with
@@ -233,13 +235,16 @@ LAYOUTS = {
 
 @pytest.mark.parametrize('layout', sorted(LAYOUTS))
 def test_the_chunked_delta_rule_is_the_token_by_token_one(layout):
-    """Values and every gradient (q, k, v, the decays, the write strengths)
-    under the assumed initialisation's strongest decays: ``A`` = 16 and steps
-    whose cumulative log-decay over a chunk passes -200, where ``exp`` of it
-    is 0 and of its negative is not a float32."""
+    """The kernels ``pt_kda_fwd`` / ``pt_kda_bwd`` (off a TPU ``kda_chunked``
+    runs them through the Pallas interpreter): values and every gradient (q,
+    k, v, the decays, the write strengths) under the assumed
+    initialisation's strongest decays: ``A`` = 16 and steps whose cumulative
+    log-decay over a chunk passes -200, where ``exp`` of it is 0 and of its
+    negative is not a float32."""
     from petastorm_tpu.ops.kda import kda_chunked, kda_recurrent
     seg, length = LAYOUTS[layout]
-    # one chunk a step of the scan, or two: the states cross both kinds of edge
+    # one chunk between two kept states, or two: the states cross both kinds
+    # of edge
     chunks_per_step = 1 + sorted(LAYOUTS).index(layout) % 2
     inputs = delta_rule_inputs(0, 2, length, 3, 32, 16)
     assert np.cumsum(inputs[3], axis=1)[:, 63].min() < -200
@@ -265,16 +270,190 @@ def test_the_chunked_delta_rule_is_the_token_by_token_one(layout):
 
 def test_the_chunked_delta_rule_stays_finite_where_the_naive_product_does_not():
     """``k * exp(G)`` against ``k * exp(-G)``, the chunked form without the
-    sub-chunks, overflows at these decays; the form here does not."""
+    sub-chunks, overflows at these decays; the form here does not, forward or
+    through the backward kernel (every gradient)."""
     from petastorm_tpu.ops.kda import kda_chunked
-    q, k, v, g, beta = delta_rule_inputs(1, 1, 128, 2, 32, 16, shift=1.0)
-    cumulative = np.cumsum(g[0, :64], axis=0)
+    inputs = delta_rule_inputs(1, 1, 128, 2, 32, 16, shift=1.0)
+    cumulative = np.cumsum(inputs[3][0, :64], axis=0)
     assert cumulative.min() < -100
     with np.errstate(over='ignore'):
         assert np.isinf(np.exp(-cumulative)).any()
     out, grads = jax.jit(jax.value_and_grad(
-        lambda g: jnp.sum(kda_chunked(q, k, v, g, beta))))(g)
-    assert np.isfinite(float(out)) and np.isfinite(np.asarray(grads)).all()
+        lambda *xs: jnp.sum(kda_chunked(*xs)), argnums=(0, 1, 2, 3, 4)))(*inputs)
+    assert np.isfinite(float(out))
+    assert all(np.isfinite(np.asarray(g)).all() and np.asarray(g).any() for g in grads)
+
+
+@pytest.fixture
+def plain_path(monkeypatch):
+    """``kda_chunked`` on the plain path (``vmap`` + ``lax.scan`` over the
+    same chunk function), which a TPU takes where the head size is no multiple
+    of 128: the tests' second reference."""
+    from petastorm_tpu.ops import kda
+
+    def chunked(*args, **kwargs):
+        with monkeypatch.context() as patch:
+            patch.setattr(kda, '_kernels', kda._plain)
+            return kda.kda_chunked(*args, **kwargs)
+    return chunked
+
+
+def values_and_gradients(form, inputs, probe):
+    def loss(*xs):
+        out = form(*xs)
+        return jnp.sum((out * probe).astype(jnp.float32)), out
+    grads, out = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*inputs)
+    return (out,) + tuple(grads)
+
+
+def test_the_plain_path_is_the_token_by_token_one(plain_path):
+    """The chunk function under ``vmap`` + ``lax.scan`` with ``jax.grad``'s
+    own backward pass: values and every gradient, two chunks a step."""
+    from petastorm_tpu.ops.kda import kda_recurrent
+    seg, length = LAYOUTS['documents_that_start_inside_a_chunk']
+    inputs = delta_rule_inputs(2, 2, length, 2, 32, 16)
+    probe = np.cos(np.arange(2 * length * 2 * 16)).reshape(2, length, 2, 16)
+    got = values_and_gradients(lambda *xs: plain_path(*xs, seg), inputs, probe)
+    want = values_and_gradients(lambda *xs: kda_recurrent(*xs, seg), inputs, probe)
+    for g, w in zip(got, want):
+        close(g, w, rtol=1e-5)
+
+
+def test_the_kernels_are_the_plain_path_at_the_cells_head_size(plain_path):
+    """Head size 128 in bfloat16, as ``kimilinear.packed`` runs it: a document
+    that ends inside a sub-chunk of 16, one that ends at a chunk's edge, a
+    single token, and a row that ends in padding.  Both forms round their
+    products to bfloat16 at the same places and add them in another order."""
+    from petastorm_tpu.ops.kda import kda_chunked
+    seg = segments(192, [37, 27, 1, 70, 57], [100, 50])
+    assert seg[0, 63] != seg[0, 64] and not seg[1, -1]
+    bf16 = jnp.bfloat16
+    q, k, v, g, beta = delta_rule_inputs(3, 2, 192, 2, 128, 128, a=1.0)
+    inputs = (q.astype(bf16), k.astype(bf16), v.astype(bf16), g, beta)
+    probe = jnp.asarray(np.cos(np.arange(2 * 192 * 2 * 128)).reshape(2, 192, 2, 128), bf16)
+    got = values_and_gradients(lambda *xs: kda_chunked(*xs, seg), inputs, probe)
+    want = values_and_gradients(lambda *xs: plain_path(*xs, seg), inputs, probe)
+    assert got[0].dtype == bf16 and not np.asarray(got[0], np.float32)[seg == 0].any()
+    for name, g_, w_ in zip(('o', 'dq', 'dk', 'dv', 'dg', 'dbeta'), got, want):
+        assert g_.dtype == w_.dtype, name
+        # bfloat16 keeps 8 bits and each form rounds to it at the end: an
+        # entry near the largest may differ by two of its last places
+        close(np.asarray(g_, np.float32), np.asarray(w_, np.float32), rtol=2 ** -6)
+
+
+def test_the_states_kept_do_not_change_the_gradients():
+    """``chunks_per_step`` says how many chunks lie between two states kept
+    for the backward pass, not what is computed: 1 and 2 agree."""
+    from petastorm_tpu.ops.kda import kda_chunked
+    seg, length = LAYOUTS['padding_at_a_rows_end']
+    inputs = delta_rule_inputs(4, 2, length, 2, 32, 16)
+    probe = np.cos(np.arange(2 * length * 2 * 16)).reshape(2, length, 2, 16)
+    one, two = (values_and_gradients(
+        lambda *xs: kda_chunked(*xs, seg, chunks_per_step=n), inputs, probe)
+        for n in (1, 2))
+    for a, b in zip(one, two):
+        close(a, b, rtol=1e-6)
+
+
+def test_two_inverses_side_by_side_are_each_ones_own():
+    """``(I + L)^-1`` of two strictly lower-triangular matrices laid side by
+    side, as the kernels hand two heads' over: random ones, and the hardest
+    for a series in ``L``'s powers (every entry 0.9: they reach 1e17 before
+    they vanish, the inverse's entries stay under 1)."""
+    from petastorm_tpu.ops.kda import CHUNK, _unit_lower_inverse
+    rng = np.random.default_rng(0)
+    lower = np.tril(rng.normal(size=(2, CHUNK, CHUNK)), -1).astype(np.float32)
+    lower[1] = np.tril(np.full((CHUNK, CHUNK), 0.9, np.float32), -1)
+    got = np.asarray(_unit_lower_inverse(jnp.concatenate(list(lower), axis=1), 2))
+    for n in range(2):
+        want = np.linalg.inv(np.eye(CHUNK) + lower[n].astype(np.float64))
+        close(got[:, n * CHUNK:(n + 1) * CHUNK], want, rtol=2e-5)
+    alone = np.asarray(_unit_lower_inverse(jnp.asarray(lower[0])))
+    assert np.array_equal(alone, got[:, :CHUNK])
+
+
+def test_decayed_products_and_what_they_hand_back():
+    """The pairwise decayed products of a chunk under the sub-chunk rule, and
+    their cotangents, against the sum as written (decays mild enough for it:
+    no ``exp`` overflows), two documents in the chunk."""
+    from petastorm_tpu.ops.kda import CHUNK, SUB_CHUNK, _decayed_products, \
+        _decayed_products_back
+    rng = np.random.default_rng(1)
+    d = 32
+    q, k, dp_kk, dp_qk = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                          for shape in [(CHUNK, d)] * 2 + [(CHUNK, CHUNK)] * 2)
+    tag = np.where(np.arange(CHUNK) < 21, 1, 2)
+    at = np.arange(CHUNK)
+    allowed = jnp.asarray((tag[:, None] == tag[None, :]) & (at[:, None] >= at[None, :]))
+    g = -0.3 * rng.random(size=(CHUNK, d)).astype(np.float32)
+
+    def cumulative(g):                      # from a document's start
+        return jnp.where(allowed, 1.0, 0.0) @ g
+
+    def as_written(q, k, g):
+        c = cumulative(g)
+        pair = jnp.exp(jnp.where(allowed[..., None], c[:, None] - c[None, :], -jnp.inf))
+        return tuple(jnp.where(allowed, jnp.sum(x[:, None] * k[None, :] * pair, -1), 0.0)
+                     for x in (k, q))
+    want, pull = jax.vjp(as_written, q, k, g)
+    c = cumulative(g)
+    got, kept = _decayed_products((k, q), k, c, allowed, SUB_CHUNK)
+    for a, b in zip(got, want):
+        close(a, b, rtol=1e-5)
+    cotangents = tuple(jnp.where(allowed, x, 0.0) for x in (dp_kk, dp_qk))
+    (dk_left, dq), dk_right, dc = _decayed_products_back(cotangents, (k, q), k, c,
+                                                         kept, SUB_CHUNK)
+    want_dq, want_dk, want_dg = pull(cotangents)
+    close(dq, want_dq, rtol=1e-5)
+    close(dk_left + dk_right, want_dk, rtol=1e-5)
+    close(jnp.where(allowed, 1.0, 0.0).T @ dc, want_dg, rtol=1e-5)
+
+
+def pallas_calls(jaxpr, in_loop=False):
+    """(name, inside a ``scan`` / ``while``) of every kernel call of a jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'pallas_call':
+            yield eqn.params['name'], in_loop
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from pallas_calls(inner, in_loop or eqn.primitive.name in ('scan', 'while'))
+
+
+def test_the_delta_rules_kernels_lie_in_no_loop(kimi, monkeypatch):
+    """``kda_scan_ms`` sums a step's ``while*`` events and its ``pt_kda_*``
+    events: a kernel inside a loop would be counted twice.  The tiny
+    configuration's step calls each of the four KDA layers' kernels (forward,
+    the layer's recomputed forward, backward) outside any loop; and at head
+    size 128, lowered for a TPU, the layer is those kernels and no loop."""
+    from petastorm_tpu.ops import kda
+    config = config_of(kimi)
+    assert [kind for kind, _ in config.layers].count('kda') == 4
+    batch = {name: jax.ShapeDtypeStruct((config.batch, config.max_len), jnp.int32)
+             for name in ('tokens', 'positions', 'segment_ids')}
+    step = jax.make_jaxpr(config.train_step())(
+        jax.eval_shape(config.init_state, jax.random.PRNGKey(0)), batch)
+    calls = [c for c in pallas_calls(step.jaxpr) if c[0].startswith('pt_kda_')]
+    assert sorted(calls) == [('pt_kda_bwd', False)] * 4 + [('pt_kda_fwd', False)] * 8
+
+    monkeypatch.setattr(kda._flash, '_auto_interpret', lambda: False)   # as on a TPU
+    shape = (1, 256, 4, 128)
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    g = jax.ShapeDtypeStruct(shape, jnp.float32)
+    beta, seg = (jax.ShapeDtypeStruct(shape[:n], dtype)
+                 for n, dtype in ((3, jnp.float32), (2, jnp.int32)))
+
+    def layer(q, k, v, g, beta, seg):
+        return jax.grad(lambda *xs: jnp.sum(kda.kda_chunked(*xs, seg).astype(jnp.float32)),
+                        argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    text = jax.jit(layer).trace(q, q, q, g, beta, seg).lower(
+        lowering_platforms=('tpu',)).as_text()
+    assert 'kernel_name = "pt_kda_fwd"' in text and 'kernel_name = "pt_kda_bwd"' in text
+    assert 'stablehlo.while' not in text
+    # any other head size takes the plain path there: its scan, no kernel
+    small = jax.ShapeDtypeStruct((1, 256, 4, 64), jnp.bfloat16)
+    text = jax.jit(kda.kda_chunked).trace(
+        small, small, small, jax.ShapeDtypeStruct(small.shape, jnp.float32), beta,
+        seg).lower(lowering_platforms=('tpu',)).as_text()
+    assert 'stablehlo.while' in text and 'pt_kda_' not in text
 
 
 @pytest.mark.parametrize('kind', ['kda', 'conv', 'mla'])
