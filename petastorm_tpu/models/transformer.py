@@ -54,6 +54,14 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from petastorm_tpu.ops import flash_attention
+from petastorm_tpu.ops.flash_attention import KEPT as _FLASH_KEPT
+
+#: How a recomputed block (``nn.remat(Block, policy=REMAT_POLICY)``) runs its
+#: backward pass: from its input, keeping only what a flash forward call
+#: names (``ops.flash_attention.KEPT``: its ``out`` and ``lse``), so that the
+#: recomputation calls no forward kernel.  A block without such a call keeps
+#: its input alone.
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(*_FLASH_KEPT)
 
 
 def rope_cos_sin(positions, head_dim, base=10000.0):
@@ -772,7 +780,12 @@ class TransformerLM(nn.Module):
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
     attn_fn: Callable = flash_attention
-    remat: bool = False  # jax.checkpoint each block: FLOPs for HBM
+    #: jax.checkpoint each block (FLOPs for HBM) under ``REMAT_POLICY``: a
+    #: block keeps its input and, of an attention layer that calls the flash
+    #: kernels, the forward's ``out`` (``batch x seq x heads x d_v`` in the
+    #: compute dtype) and ``lse`` (``batch x heads x seq`` float32), so that
+    #: its recomputation calls no forward kernel; everything else is recomputed.
+    remat: bool = False
     decode: bool = False  # KV-cache incremental mode (models.decoding)
     num_kv_heads: Any = None  # GQA: KV heads < query heads (see Attention)
     #: 'learned' table | 'rope' rotary q/k | 'none' (no positions at all: the
@@ -834,9 +847,7 @@ class TransformerLM(nn.Module):
             pos = nn.Embed(self.max_seq_len, self.d_model, name='pos_embed',
                            dtype=self.dtype)(positions)
             x = x + pos
-        block = Block
-        if self.remat:
-            block = nn.remat(Block)
+        block = nn.remat(Block, policy=REMAT_POLICY) if self.remat else Block
         rope_mode = 'rope' if self.pos_embed == 'rope' else None
         layer_types = self.layer_types or ('full_attention',) * self.num_layers
         if len(layer_types) != self.num_layers:

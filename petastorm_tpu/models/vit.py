@@ -22,7 +22,7 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax.numpy as jnp
 
-from petastorm_tpu.models.transformer import (Block, RMSNorm,
+from petastorm_tpu.models.transformer import (REMAT_POLICY, Block, RMSNorm,
                                               megatron_spec_fn,
                                               param_shardings)
 from petastorm_tpu.ops import flash_attention
@@ -77,7 +77,7 @@ class ViT(nn.Module):
                          (1, n, self.d_model))
         x = x + pos.astype(x.dtype)
 
-        block = nn.remat(Block) if self.remat else Block
+        block = nn.remat(Block, policy=REMAT_POLICY) if self.remat else Block
         for i in range(self.num_layers):
             x = block(self.num_heads, self.d_ff, self.dtype, self.attn_fn,
                       causal=False, name='block_%d' % i)(x)

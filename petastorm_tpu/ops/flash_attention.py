@@ -64,6 +64,16 @@ same way: dQ accumulates over K/V chunks, dK/dV over Q chunks, all against
 the global lse/delta.  A chunked call takes the same runs of tiles, clipped
 to its chunk.
 
+**Kept across recomputation.**  The forward's ``out`` and ``lse`` (after the
+fold, on the chunked path) carry the checkpoint names of ``KEPT``.  A
+``jax.checkpoint`` whose policy saves those names (``save_only_these_names``,
+as ``models.transformer.REMAT_POLICY`` does for every recomputed block) keeps
+them from the forward to the backward pass, so its recomputed forward calls
+no forward kernel: a layer keeps ``batch x seq x heads x d_v`` in the
+compute dtype and ``batch x heads x seq`` float32 for it.  Under Ulysses,
+whose local attention is this kernel, each device keeps its local share of
+them the same way.  Outside such a checkpoint the names change nothing.
+
 No reference equivalent (the reference has no compute kernels at all,
 SURVEY.md §2.6).
 """
@@ -74,11 +84,16 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # Shared with ring attention so masked-softmax semantics never diverge.
 from petastorm_tpu.parallel.ring_attention import NEG_INF
+
+#: The checkpoint names of a forward call's ``out`` and ``lse``: what a
+#: recomputed block keeps so that it calls no forward kernel again.
+KEPT = ('pt_flash_out', 'pt_flash_lse')
 
 
 def _auto_interpret():
@@ -682,6 +697,7 @@ def _flash_fwd(q3, k3, v3, seg3, scale, causal, seq_len, block_q, block_k,
                                 seq_len, block_q, block_k, packed, heads,
                                 interpret=_auto_interpret(),
                                 kv_chunk=kv_chunk, window=window)
+    out, lse = checkpoint_name(out, KEPT[0]), checkpoint_name(lse, KEPT[1])
     return out, (q3, k3, v3, seg3, out, lse)
 
 

@@ -276,3 +276,112 @@ def test_rope_rejects_bad_mode_and_odd_head_dim():
         TransformerLM(vocab_size=10, d_model=6, num_heads=2, num_layers=1,
                       d_ff=16, max_seq_len=8, pos_embed='rope').init(
             jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+
+
+def _pallas_names(jaxpr, out=None):
+    """The names of the Pallas calls in ``jaxpr`` and every jaxpr inside it,
+    in order."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'pallas_call':
+            out.append(eqn.params['name'])
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                if isinstance(sub, (ClosedJaxpr, Jaxpr)):
+                    _pallas_names(getattr(sub, 'jaxpr', sub), out)
+    return out
+
+
+#: Tiny recomputed models of the packed cells' attention layers, each with the
+#: forward kernel calls its step makes once: ``lfm2.packed``'s grouped-query
+#: layer (RoPE, RMS-normalised q and k) beside a convolution; latent attention
+#: at q/k 12 and v 8 whose K/V stream in two chunks (``kimilinear.packed``);
+#: differential attention in a window, in full with its K and V handed on, and
+#: across over them (``phi4flash.packed``).  The flash kernels run through the
+#: interpreter at 8 x 8 tiles.
+_KEPT_CASES = {
+    'gqa': (dict(layer_types=('conv', 'full_attention'), num_kv_heads=2,
+                 pos_embed='rope', qk_norm=True, use_bias=False, ffn='swiglu'),
+            {}, 1),
+    'mla_chunked': (dict(layer_types=('mla',), pos_embed='none',
+                         mla=dict(num_heads=4, kv_rank=16, qk_nope_dim=8,
+                                  qk_rope_dim=4, v_dim=8)),
+                    dict(kv_chunk=16), 2),
+    'differential': (dict(layer_types=('sliding_attention', 'full_attention',
+                                       'cross_attention'),
+                          num_kv_heads=2, diff_attn=True, window=12,
+                          pos_embed='none', norm='layer', ffn='swiglu'),
+                     {}, 3),
+}
+
+
+def _kept_model(kw, flash_kw):
+    import functools
+    from petastorm_tpu.ops import flash_attention
+    return TransformerLM(
+        vocab_size=VOCAB, d_model=D_MODEL, num_heads=HEADS,
+        num_layers=len(kw['layer_types']), d_ff=D_FF, max_seq_len=SEQ,
+        dtype=jnp.float32, remat=True,
+        attn_fn=functools.partial(flash_attention, block_q=8, block_k=8,
+                                  **flash_kw), **kw)
+
+
+def _packed_batch():
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, SEQ), 0, VOCAB, jnp.int32)
+    seg = np.zeros((2, SEQ), np.int32)
+    seg[0, :20], seg[0, 20:30] = 1, 2
+    seg[1, :7], seg[1, 7:] = 1, 2
+    return tokens, jnp.asarray(seg)
+
+
+@pytest.mark.parametrize('case', sorted(_KEPT_CASES))
+def test_recomputed_block_keeps_the_flash_forward(monkeypatch, case):
+    """``remat=True`` keeps a flash forward call's ``out`` and ``lse`` across
+    the block's recomputation: the gradient's program holds one forward
+    kernel call for each attention layer and K/V chunk, where recomputing
+    everything (the policy ``None``) held two, and loss and gradients are
+    those of recomputing everything, to the bit."""
+    import optax
+    from petastorm_tpu.models import transformer
+    kw, flash_kw, calls = _KEPT_CASES[case]
+    model = _kept_model(kw, flash_kw)
+    tokens, seg = _packed_batch()
+    params = model.init(jax.random.PRNGKey(0), tokens, segment_ids=seg)['params']
+
+    def loss(p):
+        logits = model.apply({'params': p}, tokens, segment_ids=seg)
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, jnp.roll(tokens, -1, axis=1)).mean()
+
+    def program():
+        names = _pallas_names(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+        return names.count('pt_flash_fwd'), names.count('pt_flash_bwd_dq')
+    kept, got = program(), jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(transformer, 'REMAT_POLICY', None)
+    assert program() == (2 * calls, calls)
+    assert kept == (calls, calls)
+    want = jax.jit(jax.value_and_grad(loss))(params)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_recomputed_block_without_flash_is_unchanged(monkeypatch):
+    """A model of convolutions and delta-rule layers names nothing that
+    ``REMAT_POLICY`` keeps: its gradient lowers to the program that
+    recomputing everything gives."""
+    from petastorm_tpu.models import transformer
+    model = TransformerLM(
+        vocab_size=VOCAB, d_model=D_MODEL, num_heads=HEADS, num_layers=2,
+        d_ff=D_FF, max_seq_len=SEQ, dtype=jnp.float32, remat=True,
+        layer_types=('conv', 'kda'), pos_embed='none',
+        kda=dict(num_heads=HEADS, head_dim=8, gate_rank=8))
+    tokens, seg = _packed_batch()
+    params = model.init(jax.random.PRNGKey(0), tokens, segment_ids=seg)
+
+    def lowered():
+        return jax.jit(jax.grad(lambda p: model.apply(
+            p, tokens, segment_ids=seg).sum())).lower(params).as_text()
+    kept = lowered()
+    monkeypatch.setattr(transformer, 'REMAT_POLICY', None)
+    assert kept == lowered()
