@@ -704,7 +704,8 @@ class Block(nn.Module):
         """``state``: what earlier layers handed on (``memory``,
         ``shared_kv``); given, the block returns ``(x, state)`` with what this
         layer ``keeps`` added."""
-        h = norm_of(self.norm)(self.norm_eps, name='ln1')(x)
+        with jax.named_scope('pt/norm'):
+            h = norm_of(self.norm)(self.norm_eps, name='ln1')(x)
         if self.mixer in ('attention', 'cross_attention'):
             with jax.named_scope('pt/attention'):
                 attn = Attention(
@@ -749,18 +750,21 @@ class Block(nn.Module):
             raise ValueError("mixer must be 'attention', 'conv', 'kda', 'mla', "
                              "'mamba', 'gmu' or 'cross_attention', got %r"
                              % (self.mixer,))
-        h = norm_of(self.norm)(self.norm_eps, name='ln2')(x)
+        with jax.named_scope('pt/norm'):
+            h = norm_of(self.norm)(self.norm_eps, name='ln2')(x)
         if self.ffn == 'gelu':
-            h = nn.Dense(self.d_ff, dtype=self.dtype, name='ffw_in')(h)
-            x = x + nn.Dense(x.shape[-1], dtype=self.dtype,
-                             name='ffw_out')(nn.gelu(h))
+            with jax.named_scope('pt/ffn'):
+                h = nn.Dense(self.d_ff, dtype=self.dtype, name='ffw_in')(h)
+                x = x + nn.Dense(x.shape[-1], dtype=self.dtype,
+                                 name='ffw_out')(nn.gelu(h))
         elif self.ffn == 'swiglu':
-            gate = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
-                            name='w1')(h)
-            up = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
-                          name='w3')(h)
-            x = x + nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
-                             name='w2')(nn.silu(gate) * up)
+            with jax.named_scope('pt/ffn'):
+                gate = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
+                                name='w1')(h)
+                up = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype,
+                              name='w3')(h)
+                x = x + nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
+                                 name='w2')(nn.silu(gate) * up)
         elif self.ffn == 'moe':
             x = x + MoEShare(dtype=self.dtype, name='moe', **self.moe)(h)
         else:
@@ -839,14 +843,15 @@ class TransformerLM(nn.Module):
                              "got %r" % (self.pos_embed,))
         embed = nn.Embed(self.vocab_size, self.d_model, name='embed',
                          dtype=self.dtype)
-        x = embed(tokens)
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]),
-                                         tokens.shape)
-        if self.pos_embed == 'learned':
-            pos = nn.Embed(self.max_seq_len, self.d_model, name='pos_embed',
-                           dtype=self.dtype)(positions)
-            x = x + pos
+        with jax.named_scope('pt/embed'):
+            x = embed(tokens)
+            if positions is None:
+                positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]),
+                                             tokens.shape)
+            if self.pos_embed == 'learned':
+                pos = nn.Embed(self.max_seq_len, self.d_model, name='pos_embed',
+                               dtype=self.dtype)(positions)
+                x = x + pos
         block = nn.remat(Block, policy=REMAT_POLICY) if self.remat else Block
         rope_mode = 'rope' if self.pos_embed == 'rope' else None
         layer_types = self.layer_types or ('full_attention',) * self.num_layers
@@ -877,7 +882,8 @@ class TransformerLM(nn.Module):
                 x = layer(x, positions, segment_ids)
             else:
                 x, state = layer(x, positions, segment_ids, state)
-        x = norm_of(self.norm)(self.norm_eps, name='ln_f')(x)
+        with jax.named_scope('pt/norm'):
+            x = norm_of(self.norm)(self.norm_eps, name='ln_f')(x)
         with jax.named_scope('pt/lm_head_loss'):
             if self.tie_embedding:
                 # attend() reuses the (vocab-sharded) embedding
