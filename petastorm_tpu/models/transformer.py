@@ -459,17 +459,14 @@ class ShortConv(nn.Module):
                         name='out_proj')(gated)
 
 
-#: added to the sum of squares under the root of :class:`DeltaAttention`'s L2
-#: normalisation of q and k (the reference implementation's)
-L2_EPS = 1e-6
-
-
 class DeltaAttention(nn.Module):
     """Kimi Delta Attention (KDA), the linear-attention mixer of Kimi-Linear:
     ``q, k, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))`` of
-    ``heads x head_dim`` each (``conv``: :func:`causal_taps`, depthwise,
-    ``conv_kernel`` taps, no bias); q and k L2-normalised over a head, q
-    scaled by ``head_dim ** -0.5``; a log-decay for every key channel ``g =
+    ``heads x head_dim`` each (``conv``: depthwise, ``conv_kernel`` taps, no
+    bias, :func:`causal_taps`' segment rule); q and k L2-normalised over a
+    head, q scaled by ``head_dim ** -0.5`` (the three together one op,
+    ``ops.qkv_conv.qkv_conv``: Pallas kernels where ``head_dim`` is a
+    multiple of 128); a log-decay for every key channel ``g =
     -exp(A_log)[head] * softplus((h W_fa) W_fb + dt_bias)`` and a write
     strength ``beta = sigmoid(h W_b)`` a head, both float32; the gated delta
     rule ``ops.kda.kda_chunked`` (its ``head_dim x head_dim`` state restarts
@@ -495,6 +492,7 @@ class DeltaAttention(nn.Module):
                 'the delta-rule mixer has no decode path: a recurrent state '
                 'kept between calls is serving, which this model does not build')
         from petastorm_tpu.ops.kda import kda_chunked
+        from petastorm_tpu.ops.qkv_conv import qkv_conv
         heads, hd = self.num_heads, self.head_dim
         width = heads * hd
         f32, highest = jnp.float32, jax.lax.Precision.HIGHEST
@@ -524,23 +522,6 @@ class DeltaAttention(nn.Module):
             1.0 / self.conv_kernel ** 0.5), (self.conv_kernel, width))
             for name in ('q_conv', 'k_conv', 'v_conv')]
 
-        # the elementwise parts keep their inputs alone for the backward
-        # pass and are recomputed there: their float32 temporaries (five of
-        # [tokens, heads * head_dim]) would stand beside the whole layer's
-        @jax.checkpoint
-        def convolved(projected, taps):
-            with jax.named_scope('pt/kda_conv'):
-                q, k, v = (nn.silu(causal_taps(u, t, segment_ids)).reshape(
-                    x.shape[:2] + (heads, hd)) for u, t in zip(projected, taps))
-
-                def unit(y):
-                    y = y.astype(f32)
-                    return y * jax.lax.rsqrt(
-                        jnp.sum(jnp.square(y), -1, keepdims=True) + L2_EPS)
-                # normalised in float32, handed on in the compute dtype as v is
-                return ((unit(q) * hd ** -0.5).astype(self.dtype),
-                        unit(k).astype(self.dtype), v)
-
         @jax.checkpoint
         def gated(o, gate, scale):
             with jax.named_scope('pt/kda_project'):
@@ -549,7 +530,9 @@ class DeltaAttention(nn.Module):
                 y = o * jax.lax.rsqrt(var + self.norm_eps) * scale
                 return (y * jax.nn.sigmoid(gate.astype(f32).reshape(o.shape))) \
                     .astype(self.dtype).reshape(x.shape[:2] + (width,))
-        q, k, v = convolved(projected, taps)
+        with jax.named_scope('pt/kda_conv'):
+            q, k, v = (y.reshape(x.shape[:2] + (heads, hd)) for y in qkv_conv(
+                projected, taps, segment_ids, head_dim=hd))
         with jax.named_scope('pt/kda_scan'):
             o = kda_chunked(q, k, v, g, beta, segment_ids, dtype=self.dtype,
                             chunks_per_step=self.chunks_per_step)

@@ -409,6 +409,75 @@ def test_decayed_products_and_what_they_hand_back():
     close(jnp.where(allowed, 1.0, 0.0).T @ dc, want_dg, rtol=1e-5)
 
 
+#: name: (segment ids or None, length), for tiles of 32 tokens (halos of 16)
+CONV_LAYOUTS = {
+    'one_document_a_row': (None, 64),
+    'a_document_starting_at_a_tiles_first_row': (segments(64, [32, 20, 12], [16, 48]), 64),
+    'documents_starting_at_rows_1_to_3_of_a_tile': (
+        segments(64, [33, 1, 1, 29], [17, 17, 17, 13]), 64),
+    'a_document_spanning_a_tile_boundary': (segments(64, [10, 40, 14], [64]), 64),
+    'padding_at_a_rows_end': (segments(64, [30, 20], [5]), 64),
+    'a_length_that_is_no_multiple_of_the_tile': (segments(77, [3, 60, 14], [40]), 77),
+}
+
+
+def composed_convolutions(projected, taps, seg, head_dim):
+    """q, k, v as the layer composed them before they were one op:
+    ``causal_taps``, SiLU, each head divided by its L2 norm, q scaled."""
+    from petastorm_tpu.models.transformer import causal_taps
+    out = []
+    for n, (u, t) in enumerate(zip(projected, taps)):
+        y = jax.nn.silu(causal_taps(u, t, seg))
+        if n < 2:
+            y = y.reshape(y.shape[:2] + (-1, head_dim))
+            y = y * jax.lax.rsqrt(jnp.sum(jnp.square(y), -1, keepdims=True) + 1e-6)
+            y = (y * head_dim ** -0.5 if n == 0 else y).reshape(u.shape)
+        out.append(y)
+    return tuple(out)
+
+
+@pytest.mark.parametrize('layout,form', [(layout, 'kernels') for layout in sorted(CONV_LAYOUTS)]
+                         + [('documents_starting_at_rows_1_to_3_of_a_tile', 'plain')])
+def test_the_fused_convolutions_are_the_composed_ones(layout, form, monkeypatch):
+    """``qkv_conv`` (the kernels ``pt_taps_fwd`` / ``pt_taps_bwd`` through the
+    Pallas interpreter, at tiles of 32 tokens and one head of 16 lanes so
+    that a row is several tiles each way; or ``_plain``, the path a TPU
+    takes at other head sizes) against the composition it replaced: q, k, v
+    and the gradients for the three projections and the three filters, where
+    a document starts on a tile's first row, one to three rows into a tile,
+    reaches over a tile's edge into the halo, and where a row ends in
+    padding."""
+    from petastorm_tpu.ops import qkv_conv
+    monkeypatch.setattr(qkv_conv, 'ROWS', 32)
+    monkeypatch.setattr(qkv_conv, 'LANES', 16)
+    seg, length = CONV_LAYOUTS[layout]
+    head_dim, width = 16, 32
+    rng = np.random.default_rng(sorted(CONV_LAYOUTS).index(layout))
+    projected = tuple(jnp.asarray(rng.normal(size=(2, length, width)), jnp.float32)
+                      for _ in range(3))
+    taps = tuple(jnp.asarray(rng.normal(size=(4, width)) * 0.5, jnp.float32)
+                 for _ in range(3))
+    probes = tuple(jnp.asarray(rng.normal(size=(2, length, width)), jnp.float32)
+                   for _ in range(3))
+    seg = None if seg is None else jnp.asarray(seg)
+
+    def fused(projected, taps):
+        if form == 'plain':
+            bits = qkv_conv.reach_bits(seg, (2, length), 4)
+            return qkv_conv._plain(projected, taps, bits, head_dim)
+        return qkv_conv.qkv_conv(projected, taps, seg, head_dim=head_dim)
+
+    def composed(projected, taps):
+        return composed_convolutions(projected, taps, seg, head_dim)
+    got, want = jax.jit(fused)(projected, taps), jax.jit(composed)(projected, taps)
+    for g, w in zip(got, want):
+        close(g, w, rtol=1e-5)
+    grads = [jax.jit(jax.grad(lambda p, t: sum(jnp.sum(o * probe) for o, probe in zip(
+        f(p, t), probes)), argnums=(0, 1)))(projected, taps) for f in (fused, composed)]
+    for g, w in zip(*(jax.tree_util.tree_leaves(x) for x in grads)):
+        close(g, w, rtol=1e-5)
+
+
 def pallas_calls(jaxpr, in_loop=False):
     """(name, inside a ``scan`` / ``while``) of every kernel call of a jaxpr."""
     for eqn in jaxpr.eqns:
@@ -433,6 +502,10 @@ def test_the_delta_rules_kernels_lie_in_no_loop(kimi, monkeypatch):
         jax.eval_shape(config.init_state, jax.random.PRNGKey(0)), batch)
     calls = [c for c in pallas_calls(step.jaxpr) if c[0].startswith('pt_kda_')]
     assert sorted(calls) == [('pt_kda_bwd', False)] * 4 + [('pt_kda_fwd', False)] * 8
+    # the layers' convolutions likewise, under names that no delta-rule
+    # metric reads
+    calls = [c for c in pallas_calls(step.jaxpr) if c[0].startswith('pt_taps_')]
+    assert sorted(calls) == [('pt_taps_bwd', False)] * 4 + [('pt_taps_fwd', False)] * 8
 
     monkeypatch.setattr(kda._flash, '_auto_interpret', lambda: False)   # as on a TPU
     shape = (1, 256, 4, 128)

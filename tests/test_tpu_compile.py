@@ -316,6 +316,53 @@ def test_flash_attention_compiles_at_two_head_sizes(one_chip, flash, mode):
     assert ('pt_flash_bwd_dkv' in text) == (mode == 'packed')
 
 
+#: ``kimilinear.packed``'s delta-rule convolutions: 2 packed rows of 8,192
+#: tokens, 32 heads of 128.
+CONV_SHAPE = (2, 8192, 32 * 128)
+
+
+@pytest.mark.parametrize('mode', ['fwd', 'grad'])
+def test_the_delta_rule_convolutions_compile_at_the_cells_sizes(one_chip, flash, mode):
+    """``qkv_conv``'s kernels through Mosaic at ``kimilinear.packed``'s
+    sizes: the tiles with their halos, the rolls and the lane reductions of
+    a head lower, and nothing of float32 at the projections' size is left
+    to XLA under the op's scope."""
+    from petastorm_tpu.ops.qkv_conv import qkv_conv
+    u = jax.ShapeDtypeStruct(CONV_SHAPE, jnp.bfloat16, sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((4, CONV_SHAPE[2]), jnp.float32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct(CONV_SHAPE[:2], jnp.int32, sharding=one_chip)
+
+    def forward(u, taps, seg):
+        with jax.named_scope('pt/kda_conv'):
+            return qkv_conv(u, taps, seg)
+
+    def grad(u, taps, seg):
+        return jax.grad(lambda u, taps: sum(jnp.sum(jnp.square(y.astype(jnp.float32)))
+                                            for y in forward(u, taps, seg)),
+                        argnums=(0, 1))(u, taps)
+    compiled = jax.jit(forward if mode == 'fwd' else grad).lower(
+        (u,) * 3, (taps,) * 3, seg).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, 'taps') == (
+        ['pt_taps_fwd'] if mode == 'fwd' else ['pt_taps_bwd', 'pt_taps_fwd'])
+    assert not _wide_float32_under(text, 'pt/kda_conv')
+
+
+def _wide_float32_under(text, scope):
+    """Instructions under ``scope`` whose result holds a float32 ``[2, 8192,
+    4096]``."""
+    import re
+    from petastorm_tpu.telemetry import device_scopes
+    table = device_scopes.scope_table(text)
+    found = []
+    for name, rest in re.findall(r'^\s+(?:ROOT\s+)?%?([^\s=]+)\s+=\s+(.*)$', text, re.M):
+        entry = table.get(name)
+        if entry and entry[0] == scope and 'f32[2,8192,4096]' in rest[:rest.find(
+                entry[2] + '(')]:
+            found.append(name)
+    return found
+
+
 def test_kimi_linear_step_compiles_at_its_published_sizes_inside_the_chips_memory(
         one_chip, flash):
     """``kimilinear.packed``'s step as the benchmark jits it (state donated, 2
@@ -325,7 +372,8 @@ def test_kimi_linear_step_compiles_at_its_published_sizes_inside_the_chips_memor
     the MLA layer's forward kernel runs once for each of its two K/V chunks
     (the recomputation keeps the folded ``out`` and ``lse``), the delta rule
     runs as its kernels (two forward calls and a backward one
-    for each of the four KDA layers) and the step holds no loop, so that
+    for each of the four KDA layers; so do its convolutions, with no float32
+    of the projections' size under their scope) and the step holds no loop, so that
     ``kda_scan_ms`` counts no kernel twice, and parameters, Adam's moments and
     the step's temporaries fit the chip."""
     import json
@@ -352,6 +400,11 @@ def test_kimi_linear_step_compiles_at_its_published_sizes_inside_the_chips_memor
     calls = _kernel_calls(text, 'kda')
     # forward, the layer's recomputed forward, and the backward: 3 x 4 layers
     assert calls == ['pt_kda_bwd'] * 4 + ['pt_kda_fwd'] * 8, calls
+    # the layers' convolutions likewise, as their own kernels, and no float32
+    # of the projections' size under their scope
+    calls = _kernel_calls(text, 'taps')
+    assert calls == ['pt_taps_bwd'] * 4 + ['pt_taps_fwd'] * 8, calls
+    assert not _wide_float32_under(text, 'pt/kda_conv')
     # the MLA layer's forward once for each of its two K/V chunks: the
     # recomputation keeps the folded result
     calls = _kernel_calls(text, 'flash')
@@ -360,10 +413,12 @@ def test_kimi_linear_step_compiles_at_its_published_sizes_inside_the_chips_memor
     assert ' while(' not in text
     state, peak = compiled.memory_analysis().argument_size_in_bytes, _peak(compiled)
     assert state == pytest.approx(12 * config.parameter_count(), rel=0.01)
-    # 14.16 GB with the kernels through Mosaic, as here and on the chip (14.54
-    # while the delta rule was a scan; the same to the byte with and without
-    # the flash forward's results kept across the recomputation): a sixth
-    # layer (+1.65 GB of state and gradient) would not fit
+    # 14.57 GB with the kernels through Mosaic (14.16 while the convolutions
+    # were XLA's fusions, 14.54 while the delta rule was a scan; the same to
+    # the byte with and without the flash forward's results kept across the
+    # recomputation): the peak lies in the last block's expert layer, where
+    # as many bytes are live as before and the temporaries pack less tightly.
+    # A sixth layer (+1.65 GB of state and gradient) would not fit
     assert 0.6 * V5E_BYTES_LIMIT < peak < 14.7e9, peak
 
 
